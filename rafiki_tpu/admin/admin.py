@@ -160,11 +160,16 @@ class Admin:
         if placement is not None:
             self.placement = placement
         elif process_mode:
-            from rafiki_tpu.placement.process import ProcessPlacementManager
+            from rafiki_tpu.placement.manager import ChipAllocator
+            from rafiki_tpu.placement.process import (
+                ProcessPlacementManager, host_chip_inventory)
 
             local = ProcessPlacementManager(
                 db=self.db,
                 broker=self.broker,
+                # an explicit inventory: this process starts the workers
+                # that hold the chips, so it must never open them itself
+                allocator=ChipAllocator(host_chip_inventory()),
                 on_status=self._on_service_status,
                 # admin-embedded engine: TRAIN children outlive an admin
                 # crash so boot reconciliation can adopt them by pid
@@ -821,7 +826,7 @@ class Admin:
             "app_version": job["app_version"],
             "task": job["task"],
             "status": job["status"],
-            # trial fault taxonomy: why an ERRORED job errored (e.g.
+            # trial fault classification: why an ERRORED job errored (e.g.
             # fail-fast on a broken template) — None for healthy jobs
             "fault_kind": job.get("fault_kind"),
             "error_reason": job.get("error_reason"),
@@ -948,7 +953,7 @@ class Admin:
             "knobs": trial["knobs"],
             "score": trial["score"],
             "status": trial["status"],
-            # fault taxonomy (worker/faults.py): how many infra-class
+            # fault classification (worker/faults.py): how many infra-class
             # re-runs the trial absorbed, plus the typed kind +
             # truncated traceback of its LAST fault (terminal for
             # ERRORED trials; the absorbed transient for COMPLETED ones
@@ -1596,7 +1601,7 @@ class Admin:
                 # kill — just fold the exit into job status.
                 self.services.refresh_train_job_status(payload["train_job_id"])
             elif name == EVENT_TRIAL_FAULT_LIMIT:
-                # Job fail-fast (trial fault taxonomy): a worker hit
+                # Job fail-fast (trial fault classification): a worker hit
                 # RAFIKI_TRIAL_FAULT_LIMIT consecutive user-class trial
                 # faults — the template is broken, so the job errors NOW
                 # with the typed reason instead of grinding its budget.

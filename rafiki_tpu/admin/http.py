@@ -350,7 +350,7 @@ class AdminServer:
                         m["aid"],
                         [(_field(i, "knobs"), _field(i, "score"))
                          for i in _list_field(b, "items")])}),
-            # scoreless-failure signal (trial fault taxonomy): the GP
+            # scoreless-failure signal (trial fault classification): the GP
             # steers away from the region; trial_id lets the session's
             # ASHA scheduler forget the dead trial's rung records
             r("POST", r"/advisors/(?P<aid>[^/]+)/infeasible", _ANY,

@@ -591,7 +591,7 @@ class ControlPlaneRecovery:
                 if t["status"] == TrialStatus.COMPLETED
                 and t["score"] is not None
             ]
-            # poison faults ride the replay too (trial fault taxonomy):
+            # poison faults ride the replay too (trial fault classification):
             # the rebuilt GP must also remember which regions crash,
             # not just which scored
             infeasible = [

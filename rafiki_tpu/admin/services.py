@@ -126,7 +126,11 @@ class ServicesManager:
             # and the deploy rolls back — never silently share devices with
             # a running job.
             total_chips = min(total_chips, avail.total_chips)
-        chips_per_sub = total_chips // len(sub_jobs) if sub_jobs else 0
+        # Even split; chips left over go one each to the first sub-jobs
+        # (the job's model order) rather than idling: on a one-chip host a
+        # two-model job must not strand the only chip while both workers
+        # run without one (a local chip cannot be shared across processes)
+        even, spare = divmod(total_chips, max(len(sub_jobs), 1))
         # CHIPS_PER_TRIAL > 1 gives each trial executor its own multi-chip
         # mesh (the executor's device grant IS its mesh — see
         # worker/train.py set_device_grant -> parallel.get_default_mesh), so
@@ -150,7 +154,8 @@ class ServicesManager:
 
         created: List[str] = []
         try:
-            for sub in sub_jobs:
+            for i, sub in enumerate(sub_jobs):
+                chips_per_sub = even + (1 if i < spare else 0)
                 if chips_per_sub == 0:
                     # 0-chip fallback executor (shared devices)
                     workers = [0]

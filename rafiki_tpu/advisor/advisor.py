@@ -80,7 +80,7 @@ class BaseAdvisor:
     def feedback_infeasible(self, knobs: Dict[str, Any],
                             kind: str = "USER") -> None:
         """The trial at ``knobs`` failed WITHOUT a usable score (trial
-        fault taxonomy: USER crash, TIMEOUT, INVALID_SCORE). Optional
+        fault classification: USER crash, TIMEOUT, INVALID_SCORE). Optional
         signal — the base implementation ignores it, so advisor types
         that can't use it stay valid; advisors that can (the GP) steer
         their proposal distribution away from the region."""
@@ -264,7 +264,7 @@ class AdvisorStore:
         kind: str = "USER",
         trial_id: Optional[str] = None,
     ) -> int:
-        """Record a scoreless failure at ``knobs`` (trial fault taxonomy
+        """Record a scoreless failure at ``knobs`` (trial fault classification
         USER/TIMEOUT/INVALID_SCORE): the advisor steers its proposals
         away, and — when ``trial_id`` is given — the session's ASHA
         scheduler forgets the trial's rung records so a crashed trial's

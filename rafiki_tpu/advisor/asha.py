@@ -97,7 +97,7 @@ class AshaScheduler:
                     else value >= threshold)
 
     def forget(self, trial_id: str) -> None:
-        """Erase a trial's rung records (trial fault taxonomy: the trial
+        """Erase a trial's rung records (trial fault classification: the trial
         ERRORED after reporting — a USER crash or invalid score). Its
         recorded values may be garbage from a template already failing,
         and a dead trial must not occupy top-1/eta slots that kill

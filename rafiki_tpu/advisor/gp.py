@@ -113,7 +113,7 @@ class BayesOpt:
         self.observed_y: List[float] = []
         self.pending_X: List[np.ndarray] = []
         # points that FAILED without a score (crashing template, timeout,
-        # NaN evaluate — the trial fault taxonomy's infeasible kinds):
+        # NaN evaluate — the trial fault classification's infeasible kinds):
         # fantasized below the observed minimum so EI steers away from
         # the region instead of re-proposing it (Vizier-style infeasible
         # handling, Golovin et al. 2017). DEDUPLICATED on a quantized

@@ -174,7 +174,7 @@ class RemoteAdvisorStore:
     def feedback_infeasible(self, advisor_id: str, knobs: Dict[str, Any],
                             kind: str = "USER",
                             trial_id: Optional[str] = None) -> int:
-        """Scoreless-failure signal (trial fault taxonomy) over the
+        """Scoreless-failure signal (trial fault classification) over the
         admin API — same ride-out semantics as feedback: re-applying on
         a lost response adds one duplicate penalty point, which the GP
         tolerates."""

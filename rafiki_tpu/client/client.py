@@ -800,7 +800,7 @@ class Client:
         trial_id: Optional[str] = None,
     ) -> int:
         """Tell the advisor the trial at ``knobs`` failed without a
-        usable score (fault taxonomy kind USER/TIMEOUT/INVALID_SCORE);
+        usable score (fault classification kind USER/TIMEOUT/INVALID_SCORE);
         proposals steer away. Returns the session's infeasible count."""
         return int(self._call(
             "POST",

@@ -410,10 +410,11 @@ _DYNAMIC_PATHS = {
     #   RAFIKI_COMPILE_CACHE=1          0 disables the persistent compile
     #                                   cache everywhere (workers still
     #                                   warm up, every boot is cold)
-    #   RAFIKI_COMPILE_CACHE_DIR=       shared executable-cache root
-    #                                   (default WORKDIR/xla_cache); keyed
-    #                                   per topology underneath — see
-    #                                   sdk/compile_cache.py
+    #   RAFIKI_COMPILE_CACHE_DIR=       shared executable-cache dir
+    #                                   (default <checkout>/xla_cache);
+    #                                   JAX_COMPILATION_CACHE_DIR, where
+    #                                   set, wins and is used as it is —
+    #                                   see sdk/compile_cache.py
     #   RAFIKI_COMPILE_CACHE_CPU=1      opt the CPU backend in (entries
     #                                   are machine-feature-tied; safe on
     #                                   one box, default off)
@@ -620,8 +621,7 @@ ENV_KNOBS = (
     "RAFIKI_TRAINER_CACHE_CAP", "RAFIKI_SCAN_EPOCH",
     "RAFIKI_SCAN_EPOCH_MAX_BYTES", "RAFIKI_FLASH_THRESHOLD_BYTES",
     "RAFIKI_NATIVE_CACHE", "RAFIKI_VISIBLE_DEVICES",
-    "RAFIKI_BACKEND_PROBE_TIMEOUT_S", "RAFIKI_BACKEND_PROBE_LOCK",
-    "RAFIKI_BACKEND_PROBE_STALE_S",
+    "RAFIKI_BACKEND_PROBE_TIMEOUT_S",
     # sandbox
     "RAFIKI_SANDBOX", "RAFIKI_SANDBOX_UID", "RAFIKI_SANDBOX_UID_BASE",
     "RAFIKI_SANDBOX_UID_RANGE", "RAFIKI_SANDBOX_GID",

@@ -396,7 +396,7 @@ class Database:
         # index backing the recovery scan's status predicate
         "ALTER TABLE service ADD COLUMN pid INTEGER",
         "CREATE INDEX IF NOT EXISTS idx_service_status ON service(status)",
-        # r7 (trial fault taxonomy): why a trial/job failed, queryable —
+        # r7 (trial fault classification): why a trial/job failed, queryable —
         # attempt counts infra-class re-runs under the same trial id
         "ALTER TABLE trial ADD COLUMN attempt INTEGER NOT NULL DEFAULT 0",
         "ALTER TABLE trial ADD COLUMN fault_kind TEXT",
@@ -911,7 +911,7 @@ class Database:
         error_reason: Optional[str] = None,
     ) -> None:
         """Error a job with a typed, recorded reason (trial fault
-        taxonomy): ``fault_kind`` is the dominant trial fault class that
+        classification): ``fault_kind`` is the dominant trial fault class that
         killed it (e.g. USER for a poison template failing fast) and
         ``error_reason`` the operator-readable sentence. Both are None
         for legacy callers — the guarded transition is unchanged."""
@@ -1136,7 +1136,7 @@ class Database:
         fault_kind: Optional[str] = None,
         fault_detail: Optional[str] = None,
     ) -> None:
-        """Terminal failure with its taxonomy kind and truncated
+        """Terminal failure with its classification kind and truncated
         traceback recorded on the row — diagnosing a failed trial must
         not require scraping worker logs (worker/faults.py)."""
         self._exec(

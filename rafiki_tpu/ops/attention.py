@@ -109,6 +109,8 @@ def multi_head_attention(params: Params, x: jax.Array,
     elif use_flash or (use_flash is None
                        and jax.default_backend() == "tpu"
                        and scores_bytes > FLASH_SCORES_BYTES):
+        # compiled (Mosaic) or it raises: an explicit use_flash=True off
+        # the TPU is a caller error, never a silent trip to the interpreter
         o = flash_attention(q, k, v, causal=causal)
     else:
         o = mha_reference(q, k, v, causal=causal)

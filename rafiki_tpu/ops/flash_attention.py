@@ -40,10 +40,6 @@ NEG_INF = -1e30
 LANES = 128  # TPU lane width: minor dim of any Mosaic-lowered block tile
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _band_mask(q_start, j, block_q, block_k, kv_len, causal, causal_off):
     """(block_q, block_k) validity mask for kv block j against q block at
     q_start. Causal is end-aligned, matching mha_reference's
@@ -142,7 +138,7 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 
 def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
                    sm_scale: Optional[float], block_q: int, block_k: int,
-                   save_lse: bool):
+                   save_lse: bool, interpret: bool):
     """q,k,v: (B, H, S, Dh) -> out (B, H, Sq, Dh), lse (B*H, Sq_padded) or
     None. lse is only computed (and written to HBM) under the VJP."""
     b, h, sq, dh = q.shape
@@ -188,7 +184,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qf, kf, vf)
     if save_lse:
         out, lse = res
@@ -275,7 +271,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k):
+def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
+                    interpret):
     b, h, sq, dh = q.shape
     skv = k.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(dh)
@@ -313,7 +310,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k):
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qf, kf, vf, gf, lse, delta)
 
     # dk/dv: kv blocks are the parallel axis, q blocks stream innermost
@@ -332,7 +329,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k):
                    jax.ShapeDtypeStruct(vf.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
                         pltpu.VMEM((block_k, dh), jnp.float32)],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qf, kf, vf, gf, lse, delta)
 
     dq = dq[:, :sq, :].reshape(b, h, sq, dh)
@@ -345,27 +342,31 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k):
 # public op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K) -> jax.Array:
-    """Flash attention over (B, H, S, Dh) tensors."""
+                    block_k: int = DEFAULT_BLOCK_K,
+                    interpret: bool = False) -> jax.Array:
+    """Flash attention over (B, H, S, Dh) tensors. The kernels compile
+    for the TPU (Mosaic) or raise; ``interpret=True`` is for a caller that
+    WANTS the Pallas interpreter (CPU tests, a CPU rehearsal) — the kernel
+    never picks it by itself."""
     out, _ = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                            save_lse=False)
+                            save_lse=False, interpret=interpret)
     return out
 
 
-def _fwd(q, k, v, causal, sm_scale, block_q, block_k):
+def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              save_lse=True)
+                              save_lse=True, interpret=interpret)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, sm_scale, block_q, block_k, res, g):
+def _bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                           block_q, block_k)
+                           block_q, block_k, interpret)
 
 
 flash_attention.defvjp(_fwd, _bwd)

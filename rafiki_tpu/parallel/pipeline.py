@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from rafiki_tpu.parallel.mesh import DATA_AXIS, PIPELINE_AXIS
-from rafiki_tpu.parallel.sharding import axis_size, shard_map
 
 
 def _make_stage_apply(params_local: Any, block_fn):
@@ -53,7 +52,7 @@ def _stage_local_streamed(params_local: Any, x_local: jax.Array, *, block_fn,
     entries into stage 0, whose outputs can never reach the last stage
     before the M + n - 1 tick schedule ends, so they are never observed.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     m = n_microbatches
 
@@ -113,7 +112,7 @@ def gpipe_apply(block_fn: Callable[[Any, jax.Array], jax.Array],
     if dp is not None and (b // n_microbatches) % mesh.shape[dp] != 0:
         dp = None
     param_specs = jax.tree.map(lambda _: P(pipe_axis), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_stage_local_streamed, block_fn=block_fn,
                 axis_name=pipe_axis, n_microbatches=n_microbatches),
         mesh=mesh,

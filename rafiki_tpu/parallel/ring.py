@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from rafiki_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-from rafiki_tpu.parallel.sharding import axis_size, shard_map
 
 NEG_INF = -1e30
 
@@ -32,7 +31,7 @@ NEG_INF = -1e30
 def _ring_local(q: jax.Array, k: jax.Array, v: jax.Array, *, axis_name: str,
                 causal: bool, sm_scale: Optional[float]) -> jax.Array:
     """Per-shard body (inside shard_map): q,k,v are (B, H, S_local, Dh)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     s_local = q.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
@@ -78,7 +77,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     """Exact attention over (B, H, S, Dh) with S sharded over ``seq_axis``
     and B over ``data_axis`` of `mesh`. S must divide by the seq axis size."""
     spec = P(data_axis, None, seq_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_local, axis_name=seq_axis, causal=causal,
                 sm_scale=sm_scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

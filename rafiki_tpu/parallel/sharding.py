@@ -28,51 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 LossFn = Callable[[Any, Any, jax.Array], Tuple[jax.Array, Dict[str, jax.Array]]]
 
 
-def shard_map(f: Callable, mesh: Mesh, in_specs: Any, out_specs: Any,
-              check_vma: bool = True) -> Callable:
-    """Version-compat resolver for ``jax.shard_map``.
-
-    JAX promoted shard_map out of ``jax.experimental`` and renamed its
-    replication-check kwarg (``check_rep`` -> ``check_vma``) across
-    releases; this one helper pins the call sites (parallel/pipeline.py,
-    parallel/ring.py, and any GspmdTrainer user composing manual
-    collectives over this module's meshes) to a single resolution order:
-
-    1. ``jax.shard_map(..., check_vma=...)`` — current API;
-    2. ``jax.shard_map(..., check_rep=...)`` — the transitional top-level
-       export that still used the old kwarg name;
-    3. ``jax.experimental.shard_map.shard_map(..., check_rep=...)`` —
-       the pre-promotion home (installed JAX 0.4.x).
-    """
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        try:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            try:
-                return top(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=check_vma)
-            except TypeError:
-                return top(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(axis_name: str) -> int:
-    """Compat twin of :func:`shard_map` for ``jax.lax.axis_size`` (absent
-    pre-promotion): inside a shard_map body, ``psum(1, axis)`` of a Python
-    literal constant-folds to the concrete axis size, so schedule loops
-    (ring hop counts, pipeline ticks) stay Python ints either way."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def filter_pspec(spec: P, mesh: Mesh) -> P:
     """Drop mesh-axis names the mesh doesn't define (so ``model``-sharded
     specs degrade to replicated on a pure-DP mesh, etc.)."""

@@ -43,6 +43,10 @@ class ChipAllocator:
     reference docker_swarm.py:153-169)."""
 
     def __init__(self, device_indices: Optional[List[int]] = None):
+        """``device_indices=None`` asks ``jax.devices()`` and so
+        initialises the backend HERE: for in-process (thread) placement
+        only. A parent of worker processes passes an explicit inventory
+        (placement/process.py ``host_chip_inventory``)."""
         if device_indices is None:
             import jax
 
@@ -112,7 +116,8 @@ class ServiceContext:
 
     def devices(self) -> List[Any]:
         """The granted jax devices (all visible devices if the grant is
-        empty — the CPU-fallback analogue of the reference's no-GPU path)."""
+        empty — the shared-devices executor, the analogue of the
+        reference's no-GPU path)."""
         import jax
 
         from rafiki_tpu.parallel.mesh import visible_devices

@@ -6,8 +6,12 @@ rafiki/container/docker_swarm.py:122-148, scripts/start_worker.py:15-25).
 `ProcessPlacementManager` is the TPU-host analogue: each service is a child
 **process** launched on `python -m rafiki_tpu.worker.bootstrap` with
 
-- its chip grant in ``RAFIKI_CHIP_GRANT`` (indices into jax.devices() — the
-  analogue of ``CUDA_VISIBLE_DEVICES``, reference docker_swarm.py:122-126),
+- its chip grant as libtpu's visible-chips variables (the analogue of
+  ``CUDA_VISIBLE_DEVICES``, reference docker_swarm.py:122-126): a chip
+  belongs to one process, so the child opens ONLY the chips it was granted
+  and numbers them 0..n-1; ``RAFIKI_CHIP_GRANT`` carries the host's
+  numbering for the record. The parent never initialises a JAX backend —
+  it would hold the chips its children need (:func:`host_chip_inventory`),
 - its payload ids (`sub_train_job_id` / `inference_job_id`+`trial_id`) in
   env, the way the reference forwarded ``RAFIKI_SERVICE_ID`` etc.
   (reference services_manager.py:307-318),
@@ -55,6 +59,43 @@ logger = logging.getLogger(__name__)
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+
+# libtpu's per-process chip bounds for a grant of n chips of one host
+_TPU_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def host_chip_inventory(
+        setting: str = "RAFIKI_VISIBLE_DEVICES") -> List[int]:
+    """This host's chip indices, for a parent that must stay off JAX: the
+    comma list in ``setting`` where set, else a count read by a
+    short-lived child (utils/backend_probe.py) that has exited — and
+    freed the chip — before any worker starts."""
+    spec = os.environ.get(setting, "").strip()
+    if spec:
+        return [int(s) for s in spec.split(",") if s.strip()]
+    from rafiki_tpu.utils.backend_probe import probe_device_count
+
+    n, err = probe_device_count()
+    if not n:
+        raise RuntimeError(
+            f"could not count this host's chips ({err}); set "
+            f"{setting} to the chip indices to use")
+    return list(range(n))
+
+
+def grant_env(chips: List[int]) -> Dict[str, str]:
+    """Environment that pins a worker child to its chip grant: libtpu
+    shows the child only those chips (which it then sees as devices
+    0..n-1). A child with no grant is kept off the accelerator — it
+    would otherwise open every chip of the host."""
+    if not chips:
+        return {"JAX_PLATFORMS": "cpu"}
+    env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips)}
+    bounds = _TPU_CHIP_BOUNDS.get(len(chips))
+    if bounds:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return env
 
 
 class _ProcRunner:
@@ -322,7 +363,13 @@ class ProcessPlacementManager(PlacementManager):
         self.db = db
         self.broker = broker
         self.admin_addr = admin_addr
-        self.allocator = allocator or ChipAllocator()
+        if allocator is None:
+            # never ChipAllocator(None) here: that asks jax.devices() in
+            # THIS process, which then holds the chips every child needs
+            raise TypeError(
+                "ProcessPlacementManager needs an explicit inventory: "
+                "allocator=ChipAllocator(host_chip_inventory())")
+        self.allocator = allocator
         self.on_status = on_status
         self.max_restarts = max_restarts
         self.stop_grace_s = stop_grace_s
@@ -491,6 +538,7 @@ class ProcessPlacementManager(PlacementManager):
             db_ref if "://" in db_ref else os.path.abspath(db_ref))
         env["RAFIKI_WORKDIR"] = config.WORKDIR
         env["RAFIKI_CHIP_GRANT"] = ",".join(str(c) for c in ctx.chips)
+        env.update(grant_env(ctx.chips))
         # the process-wide fallback must not fight the explicit grant
         env.pop("RAFIKI_VISIBLE_DEVICES", None)
         if self.admin_addr is not None:

@@ -9,7 +9,6 @@ from rafiki_tpu.sdk.jax_backend import (  # noqa: F401
     DataParallelTrainer,
     cached_trainer,
     classification_accuracy,
-    enable_persistent_compile_cache,
     softmax_classifier_loss,
     trainer_ensemble_stack,
     tunable_optimizer,

@@ -9,12 +9,14 @@ read instead of an XLA compile.
 
 Contract (the artifact-frame contract applied to XLA executables):
 
-- **Keyed per topology.** Entries live under
-  ``RAFIKI_COMPILE_CACHE_DIR/<topology key>`` where the key folds in the
-  backend, device kind, device count, and the jax/jaxlib versions — an
-  executable compiled for one topology or library version is never
-  offered to another (the version-mismatch half of the contract; JAX's
-  own cache key covers the program itself).
+- **Placed from outside.** Where ``JAX_COMPILATION_CACHE_DIR`` is set
+  the cache lives in exactly that directory: JAX reads the variable
+  itself, and nothing here sets another. Otherwise the directory is
+  ``RAFIKI_COMPILE_CACHE_DIR`` if given, else ``<checkout>/xla_cache`` —
+  a fixed path (no pid, no time, not the workdir), because a cache that
+  moves never hits. JAX's own key covers the program, the device kind
+  and topology, and the jax/jaxlib versions, so one directory serves
+  every process and backend.
 - **Typed degrade, never a crash.** An unusable cache dir (missing,
   unwritable, probe failure) disables the cache for this process and
   records *why* (``stats()["reason"]``, surfaced by the doctor); the
@@ -39,11 +41,12 @@ import os
 import threading
 from typing import Any, Dict, Optional
 
+import jax
+
 logger = logging.getLogger(__name__)
 
-#: bump when the layout/meaning of the per-topology subdirs changes —
-#: old entries are simply never read again (no in-place migration)
-_SCHEMA = 1
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _lock = threading.Lock()
 #: process-wide cache state (guarded-by _lock): the active dir, or the
@@ -56,77 +59,41 @@ _listeners_installed = False
 _hit_count = 0
 
 
-def topology_key() -> str:
-    """The cache-partition key: same string <=> executables are
-    interchangeable. Folds backend + device kind + device count +
-    jax/jaxlib versions, so a TPU v4-8's entries never reach a v5e-4,
-    and a jax upgrade starts a fresh partition instead of feeding
-    incompatible serializations to the loader."""
-    import jax
+def cache_dir() -> str:
+    """The one place every compiling process of the stack caches in
+    (and the doctor inspects): ``JAX_COMPILATION_CACHE_DIR`` as it is,
+    else ``RAFIKI_COMPILE_CACHE_DIR``, else ``<checkout>/xla_cache``."""
+    from rafiki_tpu import config
 
-    backend = jax.default_backend()
-    try:
-        devs = jax.devices()
-        kind = devs[0].device_kind.replace(" ", "_") if devs else "none"
-        n = len(devs)
-    # lint: absorb(an unprobeable backend still gets a usable — just coarser — partition key)
-    except Exception:
-        kind, n = "unknown", 0
-    try:
-        import jaxlib
-
-        jaxlib_ver = getattr(jaxlib, "__version__", "0")
-    # lint: absorb(jaxlib ships with jax; a missing version just coarsens the partition key)
-    except Exception:  # pragma: no cover
-        jaxlib_ver = "0"
-    return (f"{backend}-{kind}-n{n}-jax{jax.__version__}"
-            f"-jaxlib{jaxlib_ver}-v{_SCHEMA}")
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or config.COMPILE_CACHE_DIR
+            or os.path.join(_CHECKOUT, "xla_cache"))
 
 
 def _install_listeners() -> None:
-    """Count persistent-cache hits via JAX's monitoring events (best
-    effort: the registration API is private; absence just means the
-    warm-up chokepoint falls back to its compile-time heuristic)."""
+    """Count persistent-cache hits via JAX's monitoring events."""
     global _listeners_installed
     if _listeners_installed:
         return
     _listeners_installed = True
-    try:
-        from jax._src import monitoring as _mon
 
-        def _on_event(event: str, **kw: Any) -> None:
-            if event.endswith("/compilation_cache/cache_hits"):
-                global _hit_count
-                _hit_count += 1
-                from rafiki_tpu.utils.metrics import REGISTRY
+    def _on_event(event: str, **kw: Any) -> None:
+        if event.endswith("/compilation_cache/cache_hits"):
+            global _hit_count
+            _hit_count += 1
+            from rafiki_tpu.utils.metrics import REGISTRY
 
-                REGISTRY.counter(
-                    "rafiki_compile_cache_hits_total",
-                    "persistent compile-cache hits in this process",
-                ).inc()
+            REGISTRY.counter(
+                "rafiki_compile_cache_hits_total",
+                "persistent compile-cache hits in this process",
+            ).inc()
 
-        _mon.register_event_listener(_on_event)
-    # lint: absorb(hit telemetry is best-effort: without the private listener API the warm heuristic still works)
-    except Exception:
-        logger.debug("jax monitoring listeners unavailable; compile-cache"
-                     " hit counting disabled", exc_info=True)
+    jax.monitoring.register_event_listener(_on_event)
 
 
 def hit_count() -> int:
-    """Persistent-cache hits recorded in this process so far (0 when the
-    listener API is unavailable)."""
+    """Persistent-cache hits recorded in this process so far."""
     return _hit_count
-
-
-def events_available() -> bool:
-    """Whether the JAX hit-event listener could be installed."""
-    try:
-        from jax._src import monitoring as _mon  # noqa: F401
-
-        return True
-    # lint: absorb(private API probe: unavailable just means the warm heuristic is used)
-    except Exception:  # pragma: no cover
-        return False
 
 
 def record_misses(n: int, seconds: float = 0.0) -> None:
@@ -149,14 +116,12 @@ def record_misses(n: int, seconds: float = 0.0) -> None:
         ).observe(seconds)
 
 
-def enable(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at the shared,
-    topology-keyed directory. Idempotent; returns the active dir, or
-    None with a typed reason in ``stats()`` when the cache is off
-    (disabled, CPU without the opt-in, or an unusable directory — the
-    degrade path: the process compiles fresh, it never crashes)."""
-    import jax
-
+def enable() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache in :func:`cache_dir`.
+    Idempotent; returns the active dir, or None with a typed reason in
+    ``stats()`` when the cache is off (disabled, CPU without the opt-in,
+    or an unusable directory — the degrade path: the process compiles
+    fresh, it never crashes)."""
     from rafiki_tpu import config
 
     with _lock:
@@ -170,9 +135,7 @@ def enable(cache_dir: Optional[str] = None) -> Optional[str]:
                                 "tied; set RAFIKI_COMPILE_CACHE_CPU=1 to "
                                 "opt in)")
             return None
-        root = (cache_dir or config.COMPILE_CACHE_DIR
-                or os.path.join(config.WORKDIR, "xla_cache"))
-        path = os.path.join(root, topology_key())
+        path = cache_dir()
         try:
             os.makedirs(path, exist_ok=True)
             # a write probe up front: an unwritable dir must degrade HERE,
@@ -181,16 +144,19 @@ def enable(cache_dir: Optional[str] = None) -> Optional[str]:
             with open(probe, "w", encoding="utf-8") as f:
                 f.write("ok")
             os.unlink(probe)
-            jax.config.update("jax_compilation_cache_dir", path)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(config.COMPILE_CACHE_MIN_COMPILE_S))
-            _state.update(enabled=True, dir=path, reason=None)
-        except Exception as e:
+        except OSError as e:
             logger.warning(
                 "persistent compile cache unavailable at %s (%s: %s); "
                 "compiling fresh", path, type(e).__name__, e)
             _state["reason"] = f"unusable dir {path}: {type(e).__name__}: {e}"
             return None
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            # placed from outside -> JAX read the variable itself and
+            # no directory is set in code
+            jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          float(config.COMPILE_CACHE_MIN_COMPILE_S))
+        _state.update(enabled=True, dir=path, reason=None)
     _install_listeners()
     logger.info("persistent compile cache at %s", path)
     return path
@@ -262,13 +228,9 @@ def reset_for_tests() -> None:
     cache object lazily from the configured dir and then keeps it — a
     config update alone would keep serving the previous directory."""
     global _hit_count
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+
     with _lock:
         _state.update(enabled=False, dir=None, reason=None)
         _hit_count = 0
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    # lint: absorb(private API, best effort: without it only same-process dir re-pointing is affected)
-    except Exception:
-        pass
+    _cc.reset_cache()

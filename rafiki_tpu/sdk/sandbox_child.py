@@ -85,7 +85,7 @@ def _unshare_netns() -> None:
     RAFIKI_SANDBOX_NETNS=1): the child keeps only a down loopback, so it
     cannot reach the admin/agent control plane or dial out at all. Must
     run before the uid drop (needs CAP_SYS_ADMIN); incompatible with
-    trials that use the TPU tunnel (which needs sockets)."""
+    trials that need sockets (e.g. a dataset fetched over HTTP)."""
     import ctypes
 
     CLONE_NEWNET = 0x40000000
@@ -200,7 +200,7 @@ def main() -> int:
     # lint: absorb(the err frame carries the failure to the parent for fault classification)
     except Exception as e:
         # error_type lets the parent map the failure into the fault
-        # taxonomy (MemoryError -> MEM, everything else -> USER)
+        # classification (MemoryError -> MEM, everything else -> USER)
         # without parsing the message
         _emit({"t": "err", "error": f"{type(e).__name__}: {e}",
                "where": "model", "error_type": type(e).__name__,

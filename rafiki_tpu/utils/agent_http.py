@@ -283,7 +283,7 @@ def call_agent(
             if breaker is not None:
                 breaker.record_failure()
                 if attempt + 1 < attempts and not breaker.allow():
-                    break  # retries must not tunnel through an open circuit
+                    break  # retries must not slip through an open circuit
             if attempt + 1 < attempts:
                 logger.info("agent %s transport failure (%s); retry %d/%d",
                             addr, e, attempt + 1, attempts - 1)

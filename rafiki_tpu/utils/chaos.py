@@ -26,7 +26,7 @@ Fields:
              decode — corruption drills), ``db`` (metadata-store
              statements — transient store-failure drills for
              control-plane recovery), ``trial`` (the trial-run
-             chokepoint in the train worker — fault-taxonomy drills),
+             chokepoint in the train worker — fault-classification drills),
              ``cache`` (the prediction result cache's lookup/fill/join
              operations — degraded-cache drills: a broken cache must
              degrade to miss-path serving, never fail a request),
@@ -153,7 +153,7 @@ SITE_CACHE = "cache"
 SITE_DRIFT = "drift"
 # trial-run chokepoint (worker/train.py _execute_trial): one ask per
 # trial ATTEMPT, target "{sub_train_job_id} {trial_id}". `error` raises
-# a typed transient fault the taxonomy classifies INFRA (the
+# a typed transient fault the classification classifies INFRA (the
 # bounded-retry drill: the trial re-runs under the same id without
 # burning a budget slot), `oom` raises MemoryError (classified MEM),
 # `delay` models a slow trial start — docs/failure-model.md

@@ -1,4 +1,4 @@
-"""Trial fault taxonomy for the training plane.
+"""Trial fault classification for the training plane.
 
 Before this module, every trial failure looked the same: the worker
 caught ``Exception``, logged a traceback nobody could query, marked the
@@ -39,8 +39,8 @@ Fault kinds and their contracts (docs/failure-model.md,
     draw is too expensive for this budget.
 ``STALL``
     The sandbox child went mute before producing its FIRST frame for
-    ``RAFIKI_TRIAL_STALL_S`` (wedged import, deadlocked setup, a dead
-    TPU tunnel) and was killed by the no-frame watchdog. Retried like
+    ``RAFIKI_TRIAL_STALL_S`` (wedged import, deadlocked setup, a backend
+    that never comes up) and was killed by the no-frame watchdog. Retried like
     INFRA — stalls are overwhelmingly environmental.
 ``INVALID_SCORE``
     ``evaluate()`` returned NaN/inf/non-float. Terminal + infeasible:
@@ -98,7 +98,7 @@ FAULT_DETAIL_MAX = 2000
 
 
 class TrialFault(Exception):
-    """Base for typed trial failures; carries its taxonomy kind."""
+    """Base for typed trial failures; carries its classification kind."""
 
     kind = FaultKind.INFRA
 
@@ -314,7 +314,7 @@ def record_fault(sub_id: str, kind: str, retried: bool = False) -> None:
     else:
         REGISTRY.counter(
             "rafiki_training_faults_total",
-            "terminal trial faults by taxonomy kind", ("kind",)
+            "terminal trial faults by classification kind", ("kind",)
         ).labels(kind).inc()
 
 

@@ -36,7 +36,6 @@ from rafiki_tpu.db.database import Database
 from rafiki_tpu.parallel.mesh import set_device_grant
 from rafiki_tpu.placement.manager import ServiceContext
 from rafiki_tpu.sdk import compile_cache
-from rafiki_tpu.sdk.jax_backend import enable_persistent_compile_cache
 from rafiki_tpu.sdk.artifact import write_artifact
 from rafiki_tpu.sdk.log import ModelLogger, StopTrialEarly
 from rafiki_tpu.sdk.model import load_model_class, population_capability
@@ -105,7 +104,7 @@ class TrainWorker:
         # on-disk XLA executable reuse across trials AND worker processes —
         # the TPU-native answer to the reference's per-trial container boot
         # cost (reference scripts/start_worker.py:6-9)
-        enable_persistent_compile_cache()
+        compile_cache.enable()
         try:
             self._loop(ctx)
         finally:
@@ -326,7 +325,7 @@ class TrainWorker:
         is exiting its loop — stopping (trial TERMINATED) or job
         fail-fast (RAFIKI_TRIAL_FAULT_LIMIT tripped).
 
-        Failures run through the fault taxonomy (worker/faults.py):
+        Failures run through the fault classification (worker/faults.py):
         infra-class kinds (INFRA/MEM/STALL) re-run under the SAME trial
         id with jittered backoff up to RAFIKI_TRIAL_RETRY_MAX — no extra
         budget slot is consumed (the row is reused), and a template that
@@ -617,7 +616,7 @@ class TrainWorker:
         only (never a batch abort), and ASHA rungs are reported per
         member. A batch-LEVEL failure (template crash, OOM, chaos)
         falls back to scalar execution of every member, so the full
-        fault taxonomy — same-id infra retries included — applies
+        fault classification — same-id infra retries included — applies
         exactly as if the batch had never been tried. Returns False
         when the worker is exiting its loop."""
         lead_id = members[0][0]
@@ -641,7 +640,7 @@ class TrainWorker:
                 return False
             logger.warning(
                 "population batch %s failed; re-running its %d members "
-                "as scalar trials (same ids, full fault taxonomy):\n%s",
+                "as scalar trials (same ids, full fault classification):\n%s",
                 lead_id, len(members), traceback.format_exc())
             self._cleanup_ckpt(lead_id)
             for idx, (tid, knobs) in enumerate(members):
@@ -675,7 +674,7 @@ class TrainWorker:
                 # a platform fault on one member (params persist I/O)
                 # is not a verdict on its knobs OR its siblings:
                 # re-run just this member scalar under the same trial
-                # id — the full taxonomy applies (same-id infra
+                # id — the full classification applies (same-id infra
                 # retries, no budget burn)
                 logger.warning(
                     "population member %s hit retryable %s fault; "
@@ -750,7 +749,7 @@ class TrainWorker:
             if raw_scores is None or len(raw_scores) != len(members):
                 # a template answering the wrong number of scores broke the
                 # population contract: fail the BATCH (caller falls back
-                # to scalar, where the taxonomy judges each member alone)
+                # to scalar, where the classification judges each member alone)
                 raise faults.TrialFault(
                     f"evaluate_population returned "
                     f"{0 if raw_scores is None else len(raw_scores)} "

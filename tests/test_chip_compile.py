@@ -1,0 +1,76 @@
+"""The Pallas kernels of the main path compile for the real chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (a ``v5e:2x2`` topology). Nothing runs — this says
+nothing about results or times — but what Mosaic refuses on the chip it
+refuses here, at no chip time: a slice not aligned to the tiling, too much
+VMEM, a scratch shape it cannot lay out.
+
+The topology is described inside a module-scoped fixture, never at import
+(only one process may load libtpu; every xdist worker imports this file),
+and these tests stay in this ONE file so they land on one worker.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from rafiki_tpu.ops import flash_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled.as_text()
+
+
+# ViT-B/16's sequence (197 with a class token; padded to the 128 block),
+# the smoke's comparison shape, and the long shape ops/attention.py quotes
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("seq", [197, 2048, 8192])
+def test_flash_attention_compiles_for_v5e(one_chip, seq, causal, dtype):
+    """Head width 64 as the minor dimension, (block_q, 1) f32 scratch,
+    k.T inside the kernels: forward alone is one Mosaic kernel, forward +
+    backward three (fwd with logsumexp, dQ, dK/dV)."""
+    shape = jax.ShapeDtypeStruct((4, 12, seq, 64), dtype, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal)  # interpret=False: Mosaic
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, causal)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    assert _compile(fwd, shape, shape, shape).count("tpu_custom_call") == 1
+    assert _compile(fwd_bwd, shape, shape, shape).count(
+        "tpu_custom_call") == 3
+
+
+def test_flash_attention_cross_length_compiles_for_v5e(one_chip):
+    """Decode-shaped call: a short query block against a long key range
+    (the end-aligned causal mask)."""
+    q = jax.ShapeDtypeStruct((4, 12, 128, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 12, 2048, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    text = _compile(lambda q, k, v: flash_attention(q, k, v, True), q, kv, kv)
+    assert text.count("tpu_custom_call") == 1

@@ -10,6 +10,7 @@ Tier-1, CPU-only: the cache drills opt the CPU backend in
 (RAFIKI_COMPILE_CACHE_CPU=1) with the min-compile-time floor at 0 so
 every jit program round-trips the on-disk cache deterministically."""
 
+import os
 import threading
 import time
 
@@ -54,6 +55,7 @@ def cpu_cache(tmp_path, monkeypatch):
     monkeypatch.setenv("RAFIKI_COMPILE_CACHE", "1")
     monkeypatch.setenv("RAFIKI_COMPILE_CACHE_CPU", "1")
     monkeypatch.setenv("RAFIKI_COMPILE_CACHE_MIN_COMPILE_S", "0")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("RAFIKI_COMPILE_CACHE_DIR", str(tmp_path / "xc"))
     return str(tmp_path / "xc")
 
@@ -116,14 +118,79 @@ def test_second_boot_is_warm_from_persistent_cache(cpu_cache, monkeypatch):
     assert warmup.stats_row_fields("svc-nobody") == {}
 
 
-def test_cache_partition_key_folds_topology_and_versions(cpu_cache):
-    import jax
+def test_cache_dir_is_used_as_it_is(cpu_cache):
+    """No topology sub-directory: JAX's own key covers device kind and
+    versions, so entries land directly in the configured directory."""
+    import os
 
-    key = compile_cache.topology_key()
-    assert jax.default_backend() in key
-    assert f"jax{jax.__version__}" in key
-    compile_cache.enable()
-    assert compile_cache.active_dir().endswith(key)
+    assert compile_cache.enable() == cpu_cache
+    assert compile_cache.stats()["dir"] == cpu_cache
+    _boot("svc-flat")
+    entries = [n for n in os.listdir(cpu_cache)]
+    assert entries and all(
+        os.path.isfile(os.path.join(cpu_cache, n)) for n in entries)
+
+
+def test_jax_compilation_cache_dir_wins_and_is_not_set_in_code(
+        tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set the cache is that directory,
+    as it is, and no code sets another: JAX reads the variable itself.
+    Checked in a child interpreter, where JAX really does."""
+    import json
+    import subprocess
+    import sys
+
+    placed = tmp_path / "placed"
+    other = tmp_path / "rafiki-root"
+    code = (
+        "import json, jax\n"
+        "calls = []\n"
+        "real = jax.config.update\n"
+        "def spy(name, value):\n"
+        "    calls.append(name)\n"
+        "    return real(name, value)\n"
+        "jax.config.update = spy\n"
+        "from rafiki_tpu.sdk import compile_cache\n"
+        "d = compile_cache.enable()\n"
+        "jax.jit(lambda x: x * 2 + 1)(jax.numpy.ones((64, 64)))"
+        ".block_until_ready()\n"
+        "print(json.dumps({'dir': d, 'stats': compile_cache.stats()['dir'],"
+        " 'jax': jax.config.jax_compilation_cache_dir, 'calls': calls}))\n"
+    )
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(placed),
+               RAFIKI_COMPILE_CACHE_DIR=str(other),
+               RAFIKI_COMPILE_CACHE="1", RAFIKI_COMPILE_CACHE_CPU="1",
+               RAFIKI_COMPILE_CACHE_MIN_COMPILE_S="0",
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    out = subprocess.run([sys.executable, "-c", code], env=env, text=True,
+                         capture_output=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["dir"] == rec["stats"] == rec["jax"] == str(placed)
+    assert "jax_compilation_cache_dir" not in rec["calls"]
+    assert os.listdir(placed), "the placed directory received the entries"
+    assert not other.exists()
+
+
+def test_default_cache_dir_is_fixed_in_the_checkout(monkeypatch, tmp_path):
+    """Unset, the cache is <checkout>/xla_cache: nothing in the path
+    depends on the pid, the time, the cwd or RAFIKI_WORKDIR."""
+    import os
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("RAFIKI_COMPILE_CACHE_DIR", raising=False)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, "xla_cache")
+    assert compile_cache.cache_dir() == want
+    monkeypatch.setenv("RAFIKI_WORKDIR", str(tmp_path))
+    monkeypatch.chdir(tmp_path)
+    assert compile_cache.cache_dir() == want
+    assert str(os.getpid()) not in want
+    monkeypatch.setenv("RAFIKI_COMPILE_CACHE_DIR", str(tmp_path / "r"))
+    assert compile_cache.cache_dir() == str(tmp_path / "r")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "j"))
+    assert compile_cache.cache_dir() == str(tmp_path / "j")
 
 
 # -- typed degrade paths ----------------------------------------------------

@@ -1,5 +1,5 @@
 """Deployment doctor (rafiki_tpu/doctor.py): bounded health checks that
-never hang on a wedged accelerator tunnel."""
+never hang on an accelerator that does not come up."""
 
 import json
 import os

@@ -63,4 +63,4 @@ def test_dryrun_multichip_in_process():
     """The full driver dryrun on the test env's 8 virtual devices."""
     import __graft_entry__
 
-    __graft_entry__._dryrun_impl(8)
+    __graft_entry__.dryrun_multichip(8)

@@ -39,7 +39,7 @@ def test_fused_qkv_matches_unfused():
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_reference(causal):
     q, k, v = _qkv(0)
-    out = flash_attention(q, k, v, causal, None, 16, 16)
+    out = flash_attention(q, k, v, causal, None, 16, 16, True)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -48,7 +48,7 @@ def test_flash_matches_reference(causal):
 def test_flash_padded_seq():
     # S=40 not a multiple of the 16-block: exercises the kv_len mask
     q, k, v = _qkv(1, s=40)
-    out = flash_attention(q, k, v, False, None, 16, 16)
+    out = flash_attention(q, k, v, False, None, 16, 16, True)
     ref = mha_reference(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -61,7 +61,7 @@ def test_flash_causal_cross_length():
     q = jax.random.normal(ks[0], (1, 2, 4, 16))
     k = jax.random.normal(ks[1], (1, 2, 32, 16))
     v = jax.random.normal(ks[2], (1, 2, 32, 16))
-    out = flash_attention(q, k, v, True, None, 16, 16)
+    out = flash_attention(q, k, v, True, None, 16, 16, True)
     ref = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -71,7 +71,7 @@ def test_flash_gradients_match_reference():
     q, k, v = _qkv(2, b=1, h=1, s=32, dh=8)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, None, 16, 16) ** 2)
+        return jnp.sum(flash_attention(q, k, v, True, None, 16, 16, True) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
@@ -90,13 +90,13 @@ def test_flash_causal_dead_rows():
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 32, 16))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 16, 16))
     v = jax.random.normal(jax.random.PRNGKey(2), (1, 2, 16, 16))
-    out = flash_attention(q, k, v, True, None, 32, 16)
+    out = flash_attention(q, k, v, True, None, 32, 16, True)
     ref = mha_reference(q, k, v, causal=True)
     # rows 0..15 see no keys (end-aligned causal): ours are exactly zero
     assert float(jnp.abs(out[:, :, :16]).max()) == 0.0
     assert float(jnp.abs(out[:, :, 16:] - ref[:, :, 16:]).max()) < 2e-2
     g = jax.grad(lambda a, b, c: flash_attention(
-        a, b, c, True, None, 32, 16)[:, :, 16:].sum())(q, k, v)
+        a, b, c, True, None, 32, 16, True)[:, :, 16:].sum())(q, k, v)
     gr = jax.grad(lambda a, b, c: mha_reference(
         a, b, c, causal=True)[:, :, 16:].sum())(q, k, v)
     for x, y in zip(g, gr):

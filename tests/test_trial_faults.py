@@ -1,5 +1,5 @@
 """Training-plane trial fault tolerance (worker/faults.py +
-docs/failure-model.md "Training-plane faults"): the taxonomy drills.
+docs/failure-model.md "Training-plane faults"): the classification drills.
 
 The acceptance contract, exercised here on CPU in tier-1:
 

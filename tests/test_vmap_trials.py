@@ -350,7 +350,7 @@ def test_one_member_fault_is_isolated(pop_admin, tmp_path, monkeypatch):
     completed = [t for t in trials if t["status"] == TrialStatus.COMPLETED]
     errored = [t for t in trials if t["status"] == TrialStatus.ERRORED]
     # budget contract: 4 rows total; the faulted member burned its slot
-    # (INVALID_SCORE is terminal, exactly like the scalar taxonomy)
+    # (INVALID_SCORE is terminal, exactly like the scalar classification)
     assert len(trials) == 4
     assert len(errored) == 1 and len(completed) == 3
     assert errored[0]["fault_kind"] == "INVALID_SCORE"
