@@ -1442,7 +1442,8 @@ class Admin:
                 continue
             g = generation.setdefault(job, {
                 "workers": 0, "slots_busy": 0, "tokens": 0,
-                "kv_blocks_used": 0, "kv_pool_blocks": 0,
+                "kv_blocks_used": 0, "kv_blocks_live": 0,
+                "kv_pool_blocks": 0,
                 "prefix_hits": 0, "prefix_misses": 0,
                 "prefix_hit_tokens": 0,
                 "spec_workers": 0, "spec_proposed": 0,
@@ -1454,6 +1455,7 @@ class Admin:
             g["resident_streams"] += int(s.get("gen_resident_streams", 0))
             g["tokens"] += int(s.get("gen_tokens", 0))
             g["kv_blocks_used"] += int(s.get("gen_kv_blocks_used", 0))
+            g["kv_blocks_live"] += int(s.get("gen_kv_blocks_live", 0))
             g["kv_pool_blocks"] += int(s.get("gen_kv_pool_blocks", 0))
             g["prefix_hits"] += int(s.get("gen_prefix_hits", 0))
             g["prefix_misses"] += int(s.get("gen_prefix_misses", 0))
@@ -1640,6 +1642,7 @@ class Admin:
                            for k in ("queue_depth", "expired", "shed",
                                      "gen_slots_busy", "gen_slots_max",
                                      "gen_kv_blocks_used",
+                                     "gen_kv_blocks_live",
                                      "gen_kv_pool_blocks",
                                      "gen_kv_block_tokens",
                                      "gen_prefix_hits",

@@ -505,8 +505,12 @@ class PredictorServer:
             binary = self._accepts_wire(handler)
             REGISTRY.histogram(
                 "rafiki_gen_door_ttft_seconds",
-                "admission-to-first-token latency at the streaming door "
-                "(includes queue wait and prefill)").observe(
+                "wait of a generation request at the streaming door, "
+                "from the door's admission until a worker slot admitted "
+                "it and handed the stream back: queueing behind busy "
+                "slots, then the first prefill chunk (a one-chunk greedy "
+                "prompt's first token). rafiki_gen_ttft_seconds starts "
+                "at the slot's admission, inside this").observe(
                     time.monotonic() - t0)
             n_tokens = self._stream_deltas(handler, stream, binary)
             self.admission.observe(time.monotonic() - t0,

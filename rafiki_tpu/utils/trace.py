@@ -10,11 +10,21 @@ metric stream. This module is the first-class upgrade:
   lines under LOGS_DIR. The train worker wraps each trial phase (propose /
   train / evaluate / persist) so every trial ships a breakdown of where its
   time went; the REST layer serves it back (`GET /trials/<id>/trace`).
-- **XLA profiles**: `jax_profile(dir)` wraps `jax.profiler.trace` to
-  capture a TensorBoard-loadable xplane trace of the device — opt-in via
-  the RAFIKI_PROFILE env var because capture is not free. This is the
-  TPU-side story the reference could never have (its compute was opaque
-  inside user TF1 graphs).
+- **One clock with the device**: every span also enters a
+  `jax.profiler.TraceAnnotation` of its name (:func:`annotation`), so
+  while a profiler session is open the program's spans lie in the same
+  `.xplane.pb` as the device's operations, on the profiler's clock; with
+  no session open an annotation is a flag test. Code with no per-unit
+  `Tracer` (the generation worker's serve loop) uses the module-level
+  :func:`span`: the annotation plus one observation of
+  `rafiki_worker_phase_seconds{phase=name}`.
+- **XLA profiles**: `jax_profile(dir)` opens such a session
+  (a TensorBoard-loadable xplane trace of the device with the spans
+  above on it) — opt-in via the RAFIKI_PROFILE env var because capture
+  is not free. The train worker opens one around each whole trial
+  (train, evaluate, persist): the operator's way to a device trace from
+  a worker in a child process. This is the TPU-side story the reference
+  could never have (its compute was opaque inside user TF1 graphs).
 - **Request traces** (the serving-plane half): a :class:`TraceContext`
   (trace id + sampling bit, rate ``RAFIKI_TRACE_SAMPLE``) enters at the
   predictor door as the ``X-Rafiki-Trace`` header, rides queue entries,
@@ -35,6 +45,7 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -43,6 +54,74 @@ from typing import Any, Dict, Iterator, List, Optional
 from rafiki_tpu import config
 
 logger = logging.getLogger(__name__)
+
+# ---------------------------------------------------------------------------
+# The profiler's clock
+
+_NO_ANNOTATION = contextlib.nullcontext()
+#: jax.profiler.TraceAnnotation once looked up; False where this jax has none
+_annotation_cls: Any = None
+
+
+def annotation(name: str):
+    """A context manager that puts ``name`` into the open `jax.profiler`
+    session's trace for its duration. The class is looked up on first use
+    in a process that has imported jax, never at import of this module
+    and never by importing jax from here: a process without jax (the
+    admin in process placement) has no session to write into and must not
+    pay the import. With no session open it costs a flag test."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = False
+        _annotation_cls = cls
+    return cls(name) if cls else _NO_ANNOTATION
+
+
+_phase_hist = None
+
+
+def phase_histogram():
+    """`rafiki_worker_phase_seconds{phase}`: the one histogram of worker
+    phases. Its `_count` is the count at the phase's boundary, its `_sum`
+    the time busy in it."""
+    global _phase_hist
+    if _phase_hist is None:
+        from rafiki_tpu.utils.metrics import REGISTRY
+
+        _phase_hist = REGISTRY.histogram(
+            "rafiki_worker_phase_seconds",
+            "worker-side phase latency: per served batch (batch_assembly, "
+            "model_forward) and per phase of the generation worker's "
+            "serve loop (gen.*)", ("phase",))
+    return _phase_hist
+
+
+class span:
+    """``with trace.span("gen.decode.device"):`` — a span for code that
+    has no per-unit :class:`Tracer`: an :func:`annotation` of the name
+    plus one observation of `rafiki_worker_phase_seconds{phase=name}`."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+
+    def __enter__(self) -> "span":
+        self._ann = annotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        phase_histogram().labels(self.name).observe(dt)
 
 
 @dataclass
@@ -90,10 +169,13 @@ class Tracer:
         with self._lock:
             depth = self._depth.get(tid, 0)
             self._depth[tid] = depth + 1
+        ann = annotation(name)
         s = Span(name=name, start=time.time(), depth=depth, attrs=attrs)
+        ann.__enter__()
         try:
             yield s
         finally:
+            ann.__exit__(None, None, None)
             s.end = time.time()
             with self._lock:
                 if depth == 0:
@@ -368,27 +450,31 @@ def profiling_enabled() -> bool:
 @contextlib.contextmanager
 def jax_profile(out_dir: Optional[str] = None,
                 force: bool = False) -> Iterator[Optional[str]]:
-    """Capture an XLA device profile (xplane, TensorBoard-loadable) around
-    the body. No-op unless RAFIKI_PROFILE is set (or force=True) — capture
-    adds overhead and output is large."""
+    """Open a `jax.profiler` session around the body: an xplane trace
+    (TensorBoard-loadable) of the device with the program's spans on it
+    (:func:`annotation`). No-op unless RAFIKI_PROFILE is set (or
+    force=True) — capture adds overhead and output is large. One session
+    may be open in a process: where another already is (a benchmark's
+    trace window, a sibling trial on another chip of a thread-placed
+    worker) this logs a line and yields None, and the spans land in the
+    session that is open."""
     if not (force or profiling_enabled()):
         yield None
         return
     out_dir = out_dir or os.path.join(config.LOGS_DIR, "profiles")
-    os.makedirs(out_dir, exist_ok=True)
     import jax
 
     try:
         jax.profiler.start_trace(out_dir)
-        started = True
-    except Exception:  # already tracing, or backend without profiler support
-        logger.exception("jax profiler failed to start")
-        started = False
+    except Exception as e:  # a session is open, or no profiler support
+        logger.info("no profile under %s: %s", out_dir, e)
+        yield None
+        return
+    os.makedirs(out_dir, exist_ok=True)
     try:
-        yield out_dir if started else None
+        yield out_dir
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                logger.exception("jax profiler failed to stop")
+        try:
+            jax.profiler.stop_trace()
+        except Exception:
+            logger.exception("jax profiler failed to stop")

@@ -93,7 +93,7 @@ from rafiki_tpu.sdk.model import (
     sampling_capability,
     spec_verify_capability,
 )
-from rafiki_tpu.utils import chaos
+from rafiki_tpu.utils import chaos, trace
 from rafiki_tpu.worker.inference import (
     InferenceWorker,
     SERVING_STATS,
@@ -127,9 +127,10 @@ def _metrics():
         _M = {
             "ttft": REGISTRY.histogram(
                 "rafiki_gen_ttft_seconds",
-                "prefill-to-first-token latency of admitted generation "
-                "requests (worker side; the door-side histogram adds "
-                "queue wait)"),
+                "admission-to-first-token latency of generation "
+                "requests: the clock starts when a slot ADMITS the request "
+                "(worker side), not when it arrived; the wait before "
+                "admission is inside rafiki_gen_door_ttft_seconds"),
             "intertoken": REGISTRY.histogram(
                 "rafiki_gen_intertoken_seconds",
                 "latency between consecutive decode rounds of a live "
@@ -146,7 +147,14 @@ def _metrics():
                 ("reason",)),
             "kv_used": REGISTRY.gauge(
                 "rafiki_gen_kv_blocks_used",
-                "paged-KV pool blocks currently allocated", ("service",)),
+                "paged-KV pool blocks currently allocated (pool less free: "
+                "counts blocks that only the prefix cache keeps)",
+                ("service",)),
+            "kv_live": REGISTRY.gauge(
+                "rafiki_gen_kv_blocks_live",
+                "paged-KV pool blocks held by a slot's block table (used "
+                "less the blocks only the prefix cache keeps)",
+                ("service",)),
             "kv_pool": REGISTRY.gauge(
                 "rafiki_gen_kv_pool_blocks",
                 "paged-KV pool size in blocks", ("service",)),
@@ -374,36 +382,43 @@ class GenerationWorker(InferenceWorker):
                         break
                 n_active = sum(1 for s in slots if s is not None)
                 free = [i for i, s in enumerate(slots) if s is None]
+                # The iteration is tiled by spans on the profiler's clock
+                # (utils/trace.py span; docs/observability.md "Spans"):
+                # gen.admit, gen.prefill_chunk, gen.bookkeep, then the
+                # round's gen.decode.build / .device / .post. A
+                # speculative round has no span yet.
                 # -- admit: resumes first, then queued requests -----------
-                if free and self._pending:
-                    cache = self._readmit(model, spec, cache, slots, free,
-                                          ctx.service_id)
-                if free and (n_active == 0 or queue.depth() > 0) \
-                        and self._room_for_new():
-                    batch = queue.take_batch(
-                        max_size=len(free), deadline_s=0.0,
-                        wait_timeout_s=(0.25 if n_active == 0
-                                        and not self._pending else 0.0))
-                    if batch is None:
-                        logger.info("query queue closed; generation "
-                                    "worker %s exiting", ctx.service_id)
-                        break
-                    for fut, query in batch:
-                        cache = self._admit(
-                            model, spec, cache, slots, free, fut, query,
-                            ctx.service_id)
-                    _record_queue(ctx.service_id, queue)
+                with trace.span("gen.admit"):
+                    if free and self._pending:
+                        cache = self._readmit(model, spec, cache, slots,
+                                              free, ctx.service_id)
+                    if free and (n_active == 0 or queue.depth() > 0) \
+                            and self._room_for_new():
+                        batch = queue.take_batch(
+                            max_size=len(free), deadline_s=0.0,
+                            wait_timeout_s=(0.25 if n_active == 0
+                                            and not self._pending else 0.0))
+                        if batch is None:
+                            logger.info("query queue closed; generation "
+                                        "worker %s exiting", ctx.service_id)
+                            break
+                        for fut, query in batch:
+                            cache = self._admit(
+                                model, spec, cache, slots, free, fut, query,
+                                ctx.service_id)
+                        _record_queue(ctx.service_id, queue)
                 # -- chunked prefill: one chunk per prefilling slot -------
                 if self._alloc is not None:
                     cache = self._prefill_round(model, spec, cache, slots,
                                                 ctx)
-                n_active = sum(1 for s in slots if s is not None)
-                m["slots"].labels(ctx.service_id).set(
-                    sum(1 for s in slots
-                        if s is not None and s.pending_from is None))
-                self._mirror_alloc(ctx.service_id, m)
-                occupancy_ring.record(self._occupancy(slots, max_slots))
-                self._stats_row(ctx.service_id, slots, max_slots)
+                with trace.span("gen.bookkeep"):
+                    n_active = sum(1 for s in slots if s is not None)
+                    m["slots"].labels(ctx.service_id).set(
+                        sum(1 for s in slots
+                            if s is not None and s.pending_from is None))
+                    self._mirror_alloc(ctx.service_id, m)
+                    occupancy_ring.record(self._occupancy(slots, max_slots))
+                    self._stats_row(ctx.service_id, slots, max_slots)
                 if n_active == 0 and not self._pending:
                     continue
                 # -- decode: one token for every resident sequence (or a
@@ -848,9 +863,17 @@ class GenerationWorker(InferenceWorker):
             if copies:
                 cache = self._apply_copies(model, cache, copies)
         chunk_tokens = slot.prompt[start:end]
-        tok, cache = model.paged_prefill(
-            cache, self._alloc.table_row(slot_ix), list(chunk_tokens),
-            int(start))
+        # The span holds the chunk's device time only where the token is
+        # fetched: the final chunk of a greedy stream. Any other chunk (not
+        # final, or a sampled stream's, whose token is dropped) is an
+        # asynchronous dispatch, and its device time lands under the next
+        # gen.decode.device.
+        with trace.span("gen.prefill_chunk"):
+            tok, cache = model.paged_prefill(
+                cache, self._alloc.table_row(slot_ix), list(chunk_tokens),
+                int(start))
+            if end == n and slot.temperature <= 0.0:
+                tok = int(tok)
         slot.pending_from = end
         slot.position = end
         if end < n:
@@ -867,7 +890,6 @@ class GenerationWorker(InferenceWorker):
             self._alloc.publish(slot_ix, slot.prompt)
             return True, cache
         # final chunk: first generated token
-        tok = int(tok)
         slot.pending_from = None
         slot.last_id = tok
         slot.produced += 1
@@ -1039,134 +1061,148 @@ class GenerationWorker(InferenceWorker):
         burst-capacity demotion) without re-stepping the participants."""
         n = len(slots)
         paged = self._alloc is not None
-        if paged:
-            # growth + COW barriers for this round's writes
-            for i, s in enumerate(slots):
-                if s is None or s.pending_from is not None:
-                    continue
-                if only is not None and i not in only:
-                    continue
-                if not self._make_capacity(slots, i, s.position):
-                    if slots[i] is s:
-                        self._preempt(slots, i)
-                    continue
-                copies = self._alloc.ensure_writable(i, s.position)
-                if copies is None:
-                    if not self._preempt_youngest(slots, exclude=i):
-                        s.stream.fail(
-                            "KV pool exhausted and no sibling stream "
-                            "left to preempt — raise "
-                            "RAFIKI_GEN_KV_POOL_BLOCKS")
-                        self._evict_slot(slots, i, "kv_pool")
+        with trace.span("gen.decode.build"):
+            if paged:
+                # growth + COW barriers for this round's writes
+                for i, s in enumerate(slots):
+                    if s is None or s.pending_from is not None:
+                        continue
+                    if only is not None and i not in only:
+                        continue
+                    if not self._make_capacity(slots, i, s.position):
+                        if slots[i] is s:
+                            self._preempt(slots, i)
                         continue
                     copies = self._alloc.ensure_writable(i, s.position)
                     if copies is None:
-                        s.stream.fail("KV pool exhausted")
-                        self._evict_slot(slots, i, "kv_pool")
-                        continue
-                if copies:
-                    cache = self._apply_copies(model, cache, copies)
-        active = [(i, s) for i, s in enumerate(slots)
-                  if s is not None and s.pending_from is None
-                  and (only is None or i in only)]
-        if not active:
-            return cache
-        ids = np.zeros(n, np.int32)
-        positions = np.zeros(n, np.int32)
-        for i, s in active:
-            ids[i] = s.last_id
-            positions[i] = s.position
-        # one sampled slot puts the whole batch through the sampled step
-        # (greedy rows are bit-identical there: their modified dist is
-        # the argmax one-hot) — the program count stays at one per shape
-        sampled = (getattr(self, "_sampling_cap", None) is not None
-                   and any(s.temperature > 0.0 for _, s in active))
-        live = set(i for i, _ in active)
-        try:
-            if paged:
+                        if not self._preempt_youngest(slots, exclude=i):
+                            s.stream.fail(
+                                "KV pool exhausted and no sibling stream "
+                                "left to preempt — raise "
+                                "RAFIKI_GEN_KV_POOL_BLOCKS")
+                            self._evict_slot(slots, i, "kv_pool")
+                            continue
+                        copies = self._alloc.ensure_writable(i, s.position)
+                        if copies is None:
+                            s.stream.fail("KV pool exhausted")
+                            self._evict_slot(slots, i, "kv_pool")
+                            continue
+                    if copies:
+                        cache = self._apply_copies(model, cache, copies)
+            active = [(i, s) for i, s in enumerate(slots)
+                      if s is not None and s.pending_from is None
+                      and (only is None or i in only)]
+            if not active:
+                return cache
+            ids = np.zeros(n, np.int32)
+            positions = np.zeros(n, np.int32)
+            for i, s in active:
+                ids[i] = s.last_id
+                positions[i] = s.position
+            # one sampled slot puts the whole batch through the sampled step
+            # (greedy rows are bit-identical there: their modified dist is
+            # the argmax one-hot) — the program count stays at one per shape
+            sampled = (getattr(self, "_sampling_cap", None) is not None
+                       and any(s.temperature > 0.0 for _, s in active))
+            live = set(i for i, _ in active)
+            try:
                 tables = np.stack([
                     self._alloc.table_row(i) if i in live
                     else self._alloc.idle_row()
-                    for i in range(n)])
-                if sampled:
+                    for i in range(n)]) if paged else None
+            # lint: absorb(_fail_round logs it and fails the streams typed)
+            except Exception:
+                return self._fail_round(slots, ctx, cache)
+        try:
+            # the model call through the fetch of its tokens: the one span
+            # under which JAX's own host events nest
+            with trace.span("gen.decode.device"):
+                if paged and sampled:
                     next_ids, _probs, cache = \
                         model.paged_decode_step_sampled(
                             cache, ids, positions, tables,
                             self._sampling_arrays(slots, ROLE_TARGET,
                                                   only=live))
-                else:
+                elif paged:
                     next_ids, cache = model.paged_decode_step(
                         cache, ids, positions, tables)
-            elif sampled:
-                next_ids, _probs, cache = model.decode_step_sampled(
-                    cache, ids, positions,
-                    self._sampling_arrays(slots, ROLE_TARGET, only=live))
-            else:
-                next_ids, cache = model.decode_step(cache, ids, positions)
-            next_ids = np.asarray(next_ids)
+                elif sampled:
+                    next_ids, _probs, cache = model.decode_step_sampled(
+                        cache, ids, positions,
+                        self._sampling_arrays(slots, ROLE_TARGET,
+                                              only=live))
+                else:
+                    next_ids, cache = model.decode_step(cache, ids,
+                                                        positions)
+                next_ids = np.asarray(next_ids)
+        # lint: absorb(_fail_round logs it and fails the streams typed)
         except Exception:
-            # a decode_step crash poisons the whole table (the cache may
-            # be half-written): fail every resident stream TYPED and
-            # clear the table — the worker keeps serving new requests
-            logger.error("decode_step failed in generation worker %s:\n%s",
-                         ctx.service_id, traceback.format_exc())
-            for i, s in enumerate(slots):
-                if s is not None:
-                    s.stream.fail("decode step failed on the serving "
-                                  "worker")
-                    self._evict_slot(slots, i, "error")
-            return cache
-        now = time.monotonic()
-        m = _metrics()
-        for i, slot in enumerate(slots):
-            if slot is None or slot.pending_from is not None:
-                continue
-            if i not in live:
-                continue
-            rule = chaos.hit(
-                chaos.SITE_GENERATE,
-                f"{self._job_id}/{ctx.service_id}/slot{i}/"
-                f"{slot.stream.seq_id}")
-            if rule is not None:
-                if rule.action == chaos.ACTION_DELAY:
-                    chaos.sleep_for(rule)
-                elif rule.action == chaos.ACTION_DROP:
-                    # stalled decode: the slot stays resident but its
-                    # deltas stop — the door's inter-token timeout owns
-                    # recovery (typed error frame + cancel)
-                    logger.warning(
-                        "chaos: muting generation slot %d (%s)", i,
-                        slot.stream.seq_id)
-                    slot.muted = True
-                else:  # ACTION_ERROR: mid-stream fault on THIS stream
-                    slot.stream.fail(
-                        "chaos-injected mid-stream generation fault")
-                    self._evict_slot(slots, i, "error")
+            return self._fail_round(slots, ctx, cache)
+        with trace.span("gen.decode.post"):
+            now = time.monotonic()
+            m = _metrics()
+            for i, slot in enumerate(slots):
+                if slot is None or slot.pending_from is not None:
                     continue
-            if slot.stream.cancelled:
-                self._evict_slot(slots, i, "cancelled")
-                continue
-            token = int(next_ids[i])
-            slot.position += 1
-            slot.last_id = token
-            slot.produced += 1
-            slot.tokens.append(token)
-            m["intertoken"].observe(now - slot.last_step_t)
-            slot.last_step_t = now
-            m["tokens"].inc()
-            self._tokens_emitted += 1
-            if slot.t0 is not None:
-                # a sampled stream's first token commits HERE (admission
-                # rewound past prefill's greedy pick)
-                m["ttft"].observe(now - slot.t0)
-                slot.t0 = None
-            finished, reason = self._finish_reason(slot, spec, token)
-            if slot.deadline is not None and now >= slot.deadline:
-                finished, reason = True, "deadline"
-            if not slot.muted:
-                slot.stream.push([token], finished=finished, reason=reason)
-            if finished:
-                self._evict_slot(slots, i, reason)
+                if i not in live:
+                    continue
+                rule = chaos.hit(
+                    chaos.SITE_GENERATE,
+                    f"{self._job_id}/{ctx.service_id}/slot{i}/"
+                    f"{slot.stream.seq_id}")
+                if rule is not None:
+                    if rule.action == chaos.ACTION_DELAY:
+                        chaos.sleep_for(rule)
+                    elif rule.action == chaos.ACTION_DROP:
+                        # stalled decode: the slot stays resident but its
+                        # deltas stop — the door's inter-token timeout owns
+                        # recovery (typed error frame + cancel)
+                        logger.warning(
+                            "chaos: muting generation slot %d (%s)", i,
+                            slot.stream.seq_id)
+                        slot.muted = True
+                    else:  # ACTION_ERROR: mid-stream fault on THIS stream
+                        slot.stream.fail(
+                            "chaos-injected mid-stream generation fault")
+                        self._evict_slot(slots, i, "error")
+                        continue
+                if slot.stream.cancelled:
+                    self._evict_slot(slots, i, "cancelled")
+                    continue
+                token = int(next_ids[i])
+                slot.position += 1
+                slot.last_id = token
+                slot.produced += 1
+                slot.tokens.append(token)
+                m["intertoken"].observe(now - slot.last_step_t)
+                slot.last_step_t = now
+                m["tokens"].inc()
+                self._tokens_emitted += 1
+                if slot.t0 is not None:
+                    # a sampled stream's first token commits HERE (admission
+                    # rewound past prefill's greedy pick)
+                    m["ttft"].observe(now - slot.t0)
+                    slot.t0 = None
+                finished, reason = self._finish_reason(slot, spec, token)
+                if slot.deadline is not None and now >= slot.deadline:
+                    finished, reason = True, "deadline"
+                if not slot.muted:
+                    slot.stream.push([token], finished=finished, reason=reason)
+                if finished:
+                    self._evict_slot(slots, i, reason)
+        return cache
+
+    def _fail_round(self, slots, ctx, cache):
+        """A decode_step crash poisons the whole table (the cache may be
+        half-written): fail every resident stream TYPED and clear the
+        table — the worker keeps serving new requests. Called from the
+        handler of the exception."""
+        logger.error("decode_step failed in generation worker %s:\n%s",
+                     ctx.service_id, traceback.format_exc())
+        for i, s in enumerate(slots):
+            if s is not None:
+                s.stream.fail("decode step failed on the serving worker")
+                self._evict_slot(slots, i, "error")
         return cache
 
     # -- the speculative round -----------------------------------------------
@@ -1526,6 +1562,7 @@ class GenerationWorker(InferenceWorker):
                 m[counter].inc(delta)
         self._last_alloc_stats = st
         m["kv_used"].labels(service_id).set(st["used_blocks"])
+        m["kv_live"].labels(service_id).set(st["live_blocks"])
         m["kv_pool"].labels(service_id).set(st["pool_blocks"])
 
     def _stats_row(self, service_id: str, slots, max_slots: int) -> None:
@@ -1562,6 +1599,7 @@ class GenerationWorker(InferenceWorker):
             if self._alloc is not None:
                 st = self._last_alloc_stats or self._alloc.stats()
                 s["gen_kv_blocks_used"] = st["used_blocks"]
+                s["gen_kv_blocks_live"] = st["live_blocks"]
                 s["gen_kv_pool_blocks"] = st["pool_blocks"]
                 s["gen_kv_block_tokens"] = st["block_tokens"]
                 s["gen_prefix_hits"] = st["prefix_hits"]

@@ -52,6 +52,7 @@ def _metrics():
     global _M
     if _M is None:
         from rafiki_tpu.utils.metrics import REGISTRY
+        from rafiki_tpu.utils.trace import phase_histogram
 
         _M = {
             "batches": REGISTRY.counter(
@@ -67,9 +68,7 @@ def _metrics():
             "depth": REGISTRY.gauge(
                 "rafiki_queue_depth",
                 "current worker-queue depth", ("service",)),
-            "phase": REGISTRY.histogram(
-                "rafiki_worker_phase_seconds",
-                "worker-side phase latency per served batch", ("phase",)),
+            "phase": phase_histogram(),
         }
     return _M
 

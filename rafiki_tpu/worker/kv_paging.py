@@ -369,13 +369,17 @@ class PagedKVAllocator:
         return freed
 
     def stats(self) -> Dict[str, int]:
+        used, evictable = self.used_blocks(), self.evictable_blocks()
         return {
             "pool_blocks": self.pool_blocks,
             "block_tokens": self.block_tokens,
-            "used_blocks": self.used_blocks(),
+            "used_blocks": used,
+            # what the slots' tables hold: `used` also counts blocks that
+            # only the prefix cache keeps, and fills with them
+            "live_blocks": used - evictable,
             "free_blocks": self.free_blocks(),
             "cache_entries": len(self._entries),
-            "evictable_blocks": self.evictable_blocks(),
+            "evictable_blocks": evictable,
             "prefix_hits": self.hits,
             "prefix_misses": self.misses,
             "prefix_hit_tokens": self.hit_tokens,

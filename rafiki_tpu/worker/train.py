@@ -350,8 +350,9 @@ class TrainWorker:
                 self._chaos_trial(trial_id)
                 t_trial = time.monotonic()
                 hits_before = compile_cache.hit_count()
-                score, params_path = self._run_trial(
-                    clazz, knobs, job, trial_id, trial_logger, tracer)
+                with self._trial_profile(trial_id):
+                    score, params_path = self._run_trial(
+                        clazz, knobs, job, trial_id, trial_logger, tracer)
                 # the boot's FIRST completed trial carries the cold-start
                 # verdict: cache hits mean its jit programs loaded from
                 # the persistent cache instead of compiling (the r5
@@ -419,6 +420,17 @@ class TrainWorker:
                             streak=kind in faults.INFEASIBLE_KINDS):
                         return False  # job fail-fast: exit the loop
                 return True
+
+    @staticmethod
+    def _trial_profile(trial_id: str):
+        """RAFIKI_PROFILE: one `jax.profiler` session around the WHOLE
+        trial (train, evaluate, persist — the idle that evaluate and
+        persist cause is in it), under LOGS_DIR/profiles/<trial id>, with
+        the trial's spans on it (utils/trace.py annotation). The one way
+        to a device trace from a worker in a child process; a no-op
+        without the variable."""
+        return jax_profile(
+            os.path.join(config.LOGS_DIR, "profiles", trial_id))
 
     def _chaos_trial(self, trial_id: str) -> None:
         """RAFIKI_CHAOS site=trial: the drillable fault chokepoint —
@@ -630,8 +642,9 @@ class TrainWorker:
                                             [tid for tid, _ in members])
         try:
             self._chaos_trial(lead_id)
-            results = self._run_population_trial(
-                clazz, members, job, trial_logger, tracer)
+            with self._trial_profile(lead_id):
+                results = self._run_population_trial(
+                    clazz, members, job, trial_logger, tracer)
         except Exception:
             if ctx.stopping:
                 for tid, _ in members:
@@ -736,7 +749,7 @@ class TrainWorker:
             self._params_dir, f"{lead_id}.ckpt")
         try:
             try:
-                with jax_profile(), tracer.span("train"):
+                with tracer.span("train"):
                     model.train_population(job["train_dataset_uri"],
                                            member_knobs)
             except StopTrialEarly:
@@ -777,9 +790,13 @@ class TrainWorker:
                         # member scalar (same id, no budget burn),
                         # user-class kinds error it with infeasible
                         # feedback.
-                        params_bytes = dump_params(
-                            model.dump_member_parameters(i))
-                        write_artifact(params_path, params_bytes)
+                        with tracer.span("persist.dump"):
+                            params = model.dump_member_parameters(i)
+                        with tracer.span("persist.serialize"):
+                            params_bytes = dump_params(params)
+                            del params
+                        with tracer.span("persist.write"):
+                            write_artifact(params_path, params_bytes)
                     except OSError as e:
                         results.append((tid, knobs, None, None,
                                         faults.TrialFault(
@@ -1021,7 +1038,10 @@ class TrainWorker:
                 # or later bit rot surfaces as a typed ArtifactCorruptError
                 # at download/deploy, never a deserialize traceback
                 try:
-                    write_artifact(params_path, params_bytes, mode=0o600)
+                    # the child dumped and serialized: only the write is here
+                    with tracer.span("persist.write"):
+                        write_artifact(params_path, params_bytes,
+                                       mode=0o600)
                 except OSError as e:
                     # trusted-side I/O (full disk, yanked volume) — the
                     # platform's fault, never the template's knobs
@@ -1088,7 +1108,7 @@ class TrainWorker:
             self._params_dir, f"{trial_id}.ckpt")
         try:
             try:
-                with jax_profile(), tracer.span("train"):
+                with tracer.span("train"):
                     model.train(job["train_dataset_uri"])
             except StopTrialEarly:
                 # templates with hand-rolled train loops surface the ASHA
@@ -1108,9 +1128,14 @@ class TrainWorker:
                 # atomic + checksummed (sdk/artifact.py) — see the
                 # sandboxed persist path for the rationale; trusted-side
                 # I/O failures (full disk) are typed INFRA, not USER
-                params_bytes = dump_params(model.dump_parameters())
+                with tracer.span("persist.dump"):
+                    params = model.dump_parameters()
+                with tracer.span("persist.serialize"):
+                    params_bytes = dump_params(params)
+                    del params
                 try:
-                    write_artifact(params_path, params_bytes)
+                    with tracer.span("persist.write"):
+                        write_artifact(params_path, params_bytes)
                 except OSError as e:
                     raise faults.TrialFault(
                         f"params persist failed: {e}",

@@ -504,7 +504,13 @@ export APP_SECRET="${APP_SECRET:-rafiki-tpu-dev-secret}"
 #                                       (admin/agent boot, doctor); the
 #                                       child is killed at the timeout.
 #                                       It takes the chip while it runs
-#   RAFIKI_PROFILE=1                    per-phase profile spans in logs
+#   RAFIKI_PROFILE=1                    a jax.profiler session around every
+#                                       whole trial (train, evaluate,
+#                                       persist): a device trace with the
+#                                       program's spans on it, under
+#                                       LOGS_DIR/profiles/<trial id>. The
+#                                       way to a trace from a worker in a
+#                                       child process. Costly: off in service
 
 # Control-plane HA (docs/failure-model.md "Control-plane HA"): leased
 # leadership + epoch-fenced writes + hot-standby promotion + client

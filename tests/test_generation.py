@@ -792,6 +792,105 @@ def test_door_refused_generate_does_not_leak_admission_slot(monkeypatch):
         t.join(timeout=5)
 
 
+def test_door_ttft_one_sample_a_stream_and_holds_the_wait_for_a_slot(
+        monkeypatch):
+    """`rafiki_gen_door_ttft_seconds` takes one sample for each stream the
+    door hands back and none for a refused request; with the one slot
+    taken, the next request's sample holds its wait for the slot (which
+    `rafiki_gen_ttft_seconds`, started at the slot's admission, leaves
+    out)."""
+    import requests
+
+    from rafiki_tpu.predictor.predictor import Predictor
+    from rafiki_tpu.predictor.server import PredictorServer
+    from rafiki_tpu.utils.metrics import REGISTRY
+
+    monkeypatch.setenv("RAFIKI_GEN_MAX_SLOTS", "1")
+
+    class _Slow(_Scripted):
+        def decode_step(self, cache, ids, positions):
+            time.sleep(0.02)
+            return np.asarray(ids) + 1, cache
+
+    def snap(name):
+        metric = REGISTRY.get(name)
+        return (metric.labels().snapshot() if metric is not None
+                else {"count": 0, "sum": 0.0})
+
+    def post(max_tokens):
+        with requests.post(
+                f"http://127.0.0.1:{server.port}/generate",
+                json={"prompt_ids": [1], "max_tokens": max_tokens},
+                stream=True, timeout=30) as resp:
+            assert resp.status_code == 200
+            return sum(1 for raw in resp.iter_lines() if raw)
+
+    broker = InProcessBroker()
+    ctx, t = _start_worker(broker, _Slow(), job="waitjob")
+    predictor = Predictor("waitjob", broker, task=None)
+    server = PredictorServer(predictor, "waitapp", auth=False).start()
+    try:
+        door0 = snap("rafiki_gen_door_ttft_seconds")
+        worker0 = snap("rafiki_gen_ttft_seconds")
+        holder = threading.Thread(target=post, args=(30,), daemon=True)
+        holder.start()  # holds the one slot for 30 rounds of 20 ms
+        deadline = time.monotonic() + 10
+        while snap("rafiki_gen_door_ttft_seconds")["count"] \
+                == door0["count"] and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert post(2) >= 1  # waits for the holder's last token
+        holder.join(timeout=10)
+        r = requests.post(f"http://127.0.0.1:{server.port}/generate",
+                          json={"prompt_ids": [1], "max_tokens": "zap"},
+                          timeout=10)
+        assert r.status_code == 400
+        door1 = snap("rafiki_gen_door_ttft_seconds")
+        worker1 = snap("rafiki_gen_ttft_seconds")
+        assert door1["count"] - door0["count"] == 2
+        assert worker1["count"] - worker0["count"] == 2
+        assert door1["sum"] - door0["sum"] >= 0.3
+        assert worker1["sum"] - worker0["sum"] < 0.1
+    finally:
+        server.stop(drain_timeout_s=0.0)
+        ctx.stopping = True
+        t.join(timeout=5)
+
+
+def test_decode_crash_fails_resident_streams_typed_and_keeps_serving(
+        monkeypatch):
+    """A decode_step that raises fails every resident stream with a typed
+    error and clears the table; the worker serves the next request."""
+    monkeypatch.setenv("RAFIKI_GEN_MAX_SLOTS", "2")
+
+    class _CrashOnce(_Scripted):
+        crashed = False
+
+        def decode_step(self, cache, ids, positions):
+            time.sleep(0.005)
+            if not self.crashed and np.count_nonzero(ids) == 2:
+                self.crashed = True  # with both streams resident
+                raise RuntimeError("device fell over")
+            return np.asarray(ids) + 1, cache
+
+    broker = InProcessBroker()
+    ctx, t = _start_worker(broker, _CrashOnce(), job="crashjob")
+    try:
+        a = _submit(broker, "crashjob", {"prompt_ids": [9],
+                                         "max_tokens": 80})
+        b = _submit(broker, "crashjob", {"prompt_ids": [3],
+                                         "max_tokens": 80})
+        for stream in (a, b):
+            with pytest.raises(GenerationError, match="decode step failed"):
+                for _ in range(100):
+                    stream.next_delta(2.0)
+        c = _submit(broker, "crashjob", {"prompt_ids": [40],
+                                         "max_tokens": 3})
+        assert _drain(c) == ([41, 42, 43], "max_tokens")
+    finally:
+        ctx.stopping = True
+        t.join(timeout=5)
+
+
 def test_remote_worker_stats_relay_feeds_occupancy_ring(admin):
     """Review regression: a PROCESS-placed generation worker's slot
     occupancy reaches the admin-side autoscaler through the

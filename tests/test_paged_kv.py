@@ -684,3 +684,67 @@ def test_doctor_warns_disabled_prefix_cache_under_shareable_traffic(
     REGISTRY.counter("rafiki_gen_prefix_shareable_total").inc(5)
     _, status, detail = check_generative_serving()
     assert status == "WARN" and "RAFIKI_GEN_PREFIX_CACHE" in detail
+
+
+# -- PR 24: what the slots hold, and the serve loop's spans -------------------
+
+def test_live_blocks_leave_out_what_only_the_cache_keeps():
+    """`used_blocks` is pool less free and fills with blocks that only the
+    prefix cache keeps; `live_blocks` is what the slots' tables hold."""
+    a = PagedKVAllocator(pool_blocks=8, block_tokens=4, table_blocks=4)
+    prompt = list(range(5))
+    a.open_slot("A", prompt)
+    assert a.ensure_capacity("A", 4)
+    a.publish("A", prompt)
+    st = a.stats()
+    assert st["used_blocks"] == st["live_blocks"] == 2  # A holds both
+    a.close_slot("A")
+    st = a.stats()
+    assert st["used_blocks"] == 2 and st["evictable_blocks"] == 2
+    assert st["live_blocks"] == 0
+
+
+def _phase_counts():
+    from rafiki_tpu.utils import trace
+
+    return {key[0]: child.snapshot()["count"]
+            for key, child in trace.phase_histogram().children().items()}
+
+
+def test_serve_loop_phases_are_counted(monkeypatch):
+    """Streams served through GenerationWorker leave a count for each of
+    the serve loop's six phases; a decode round is one build, one device
+    call and one post, so the three counts rise together; the row and the
+    gauge carry the blocks the slots hold."""
+    from rafiki_tpu.cache.queue import InProcessBroker
+    from rafiki_tpu.utils.metrics import REGISTRY
+    from rafiki_tpu.worker.inference import serving_stats
+
+    monkeypatch.setenv("RAFIKI_GEN_MAX_SLOTS", "2")
+    monkeypatch.setenv("RAFIKI_GEN_KV_BLOCK_TOKENS", "8")
+    monkeypatch.setenv("RAFIKI_GEN_PREFILL_CHUNK", "8")
+    monkeypatch.setenv("RAFIKI_GEN_KV_PAGED", "1")
+    before = _phase_counts()
+    broker = InProcessBroker()
+    worker, ctx, t = _start_worker(broker, _tiny_model(), job="spanjob")
+    q = list(broker.get_worker_queues("spanjob").values())[0]
+    try:
+        long_prompt = list(range(1, 21))  # three chunks of 8
+        streams = [_stream(q, long_prompt, 6), _stream(q, [7, 7, 7], 4)]
+        assert [len(_drain(s)[0]) for s in streams] == [6, 4]
+        row = serving_stats()[ctx.service_id]
+        assert row["gen_kv_blocks_live"] <= row["gen_kv_blocks_used"]
+        live = REGISTRY.get("rafiki_gen_kv_blocks_live")
+        assert live.value(ctx.service_id) == row["gen_kv_blocks_live"]
+    finally:
+        ctx.stopping = True
+        t.join(timeout=10)
+    rose = {k: v - before.get(k, 0) for k, v in _phase_counts().items()}
+    for phase in ("gen.admit", "gen.prefill_chunk", "gen.bookkeep",
+                  "gen.decode.build", "gen.decode.device",
+                  "gen.decode.post"):
+        assert rose.get(phase, 0) > 0, (phase, rose)
+    assert rose["gen.decode.build"] == rose["gen.decode.device"] \
+        == rose["gen.decode.post"] >= 5  # six tokens: a prefill's and five
+    assert rose["gen.prefill_chunk"] == 4  # three chunks and one
+    assert rose["gen.admit"] >= rose["gen.bookkeep"] > 0
