@@ -135,6 +135,51 @@ def cache_max_slots(cache: Cache) -> int:
     return int(cache["k"].shape[1])
 
 
+def _embed_tokens(params: Params, ids, positions, dtype) -> jax.Array:
+    x = core.embedding(params["embed"], ids, dtype=dtype)
+    pos_table = params["pos"][0].astype(dtype)          # (max_len, D)
+    return x + jnp.take(pos_table, positions, axis=0)   # (B, T, D)
+
+
+def _cached_block(p: Params, x: jax.Array, lk: jax.Array, lv: jax.Array,
+                  positions: jax.Array, heads: int):
+    """One dense block over cache views ``lk``/``lv`` (B, L, H, Dh), for the
+    ring and the paged forward alike: sets the new tokens' K/V at
+    ``positions``, attends up to each query's own position. Returns
+    (x, lk, lv), the views updated."""
+    b, length = lk.shape[:2]
+    batch_ix = jnp.arange(b)[:, None]                   # (B, 1)
+    # (B, T, L): query token at positions[b, i] attends cache slots <= it
+    mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(x.shape[-1] // heads, jnp.float32))
+    h = core.layernorm(p["ln1"], x)
+    q = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wq"].astype(x.dtype))
+    k = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wk"].astype(x.dtype))
+    v = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wv"].astype(x.dtype))
+    lk = lk.at[batch_ix, positions].set(k.astype(lk.dtype))
+    lv = lv.at[batch_ix, positions].set(v.astype(lv.dtype))
+    s = jnp.einsum("bthk,blhk->bthl", q, lk.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask[:, :, None, :], s, -1e30)  # broadcast over H
+    a = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    o = jnp.einsum("bthl,blhk->bthk", a, lv.astype(q.dtype))
+    attn_out = jnp.einsum(
+        "bthk,hkd->btd", o, p["attn"]["wo"].astype(x.dtype))
+    x = x + attn_out + p["attn"]["bo"].astype(x.dtype)
+    h = core.layernorm(p["ln2"], x)
+    h = core.dense(p["mlp"]["w1"], h)
+    h = jax.nn.gelu(h)
+    h = core.dense(p["mlp"]["w2"], h)
+    return x + h, lk, lv
+
+
+def _lm_head(params: Params, x: jax.Array) -> jax.Array:
+    x = core.layernorm(params["ln_f"], x)
+    logits = jnp.einsum("btd,vd->btv", x,
+                        params["embed"]["table"].astype(x.dtype))
+    return logits.astype(jnp.float32)
+
+
 def _cached_forward(params: Params, ck: jax.Array, cv: jax.Array,
                     ids: jax.Array, positions: jax.Array, cfg: LMConfig
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -148,45 +193,16 @@ def _cached_forward(params: Params, ck: jax.Array, cv: jax.Array,
     Same math as :func:`apply` for dense blocks (reference attention,
     f32 softmax statistics), so a prefilled-then-decoded sequence tracks
     the full-sequence forward."""
-    enc = cfg.encoder
-    b, t = ids.shape
-    length = ck.shape[2]
-    compute_dtype = ck.dtype
-    x = core.embedding(params["embed"], ids, dtype=compute_dtype)
-    pos_table = params["pos"][0].astype(compute_dtype)  # (max_len, D)
-    x = x + jnp.take(pos_table, positions, axis=0)      # (B, T, D)
-    batch_ix = jnp.arange(b)[:, None]                   # (B, 1)
-    # (B, T, L): query token at positions[b, i] attends cache slots <= it
-    mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(enc.dim // enc.heads, jnp.float32))
+    x = _embed_tokens(params, ids, positions, ck.dtype)
 
     def body(x, layer):
         p, lk, lv = layer  # block params, (B, L, H, Dh) cache planes
-        h = core.layernorm(p["ln1"], x)
-        q = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wq"].astype(x.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wk"].astype(x.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wv"].astype(x.dtype))
-        lk = lk.at[batch_ix, positions].set(k.astype(lk.dtype))
-        lv = lv.at[batch_ix, positions].set(v.astype(lv.dtype))
-        s = jnp.einsum("bthk,blhk->bthl", q, lk.astype(q.dtype),
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask[:, :, None, :], s, -1e30)  # broadcast over H
-        a = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bthl,blhk->bthk", a, lv.astype(q.dtype))
-        attn_out = jnp.einsum(
-            "bthk,hkd->btd", o, p["attn"]["wo"].astype(x.dtype))
-        x = x + attn_out + p["attn"]["bo"].astype(x.dtype)
-        h = core.layernorm(p["ln2"], x)
-        h = core.dense(p["mlp"]["w1"], h)
-        h = jax.nn.gelu(h)
-        h = core.dense(p["mlp"]["w2"], h)
-        return x + h, (lk, lv)
+        x, lk, lv = _cached_block(p, x, lk, lv, positions,
+                                  cfg.encoder.heads)
+        return x, (lk, lv)
 
     x, (new_ck, new_cv) = jax.lax.scan(body, x, (params["blocks"], ck, cv))
-    x = core.layernorm(params["ln_f"], x)
-    logits = jnp.einsum("btd,vd->btv", x,
-                        params["embed"]["table"].astype(x.dtype))
-    return logits.astype(jnp.float32), new_ck, new_cv
+    return _lm_head(params, x), new_ck, new_cv
 
 
 def prefill(params: Params, cache: Cache, slot: jax.Array, ids: jax.Array,
@@ -238,11 +254,13 @@ def decode_step(params: Params, cache: Cache, ids: jax.Array,
 # once (the worker-side allocator, worker/kv_paging.py, owns refcounts and
 # copy-on-write; this layer is pure array math).
 #
-# Shapes stay fixed: every forward gathers the slot's logical view
-# ``(depth, B, table_blocks*block_tokens, H, Dh)`` from the pool through
-# the table, runs the SAME ``_cached_forward`` as the ring path (so paged
-# outputs are bit-identical given the same logical contents), then scatters
-# ONLY the newly-written rows back. Sentinel table entries (>= pool size)
+# Shapes stay fixed, and the pool is read and written in place
+# (``_paged_forward``): inside the layer scan, layer l gathers its own
+# blocks through the table into a ``(B, table_blocks*block_tokens, H, Dh)``
+# view, runs the SAME ``_cached_block`` as the ring path on it (so paged
+# outputs are bit-identical given the same logical contents), and writes
+# ONLY the new rows into the pool; no view of all layers, and no copy of
+# the donated pool, is ever made. Sentinel table entries (>= pool size)
 # gather clipped garbage that the causal mask keeps out of every real
 # query, and their writes are dropped (`mode="drop"`), so idle slots and
 # bucket padding never touch a live block.
@@ -250,17 +268,17 @@ def decode_step(params: Params, cache: Cache, ids: jax.Array,
 def init_paged_kv_cache(cfg: LMConfig, pool_blocks: int, block_tokens: int,
                         dtype=jnp.float32) -> Cache:
     """Preallocate the paged decode pool: per-layer K/V of shape
-    ``(depth, pool_blocks, block_tokens, heads, head_dim)``. Same MoE
-    refusal as the ring cache — the fixed-shape decode program cannot
-    carry per-token dispatch state."""
+    ``(depth, pool_blocks, block_tokens, heads * head_dim)``: a row's heads
+    side by side, so that the minor dimension fills the device's lanes and
+    a block is one contiguous run. Same MoE refusal as the ring cache — the
+    fixed-shape decode program cannot carry per-token dispatch state."""
     if cfg.encoder.moe_experts > 0:
         raise ValueError(
             "KV-cached decode supports dense blocks only (moe_experts=0): "
             "MoE top-k routing is per-token and the fixed-shape decode "
             "program cannot carry its dispatch state in the cache")
     enc = cfg.encoder
-    shape = (enc.depth, int(pool_blocks), int(block_tokens), enc.heads,
-             enc.dim // enc.heads)
+    shape = (enc.depth, int(pool_blocks), int(block_tokens), enc.dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -277,41 +295,49 @@ def paged_pool_bytes(cache: Cache) -> int:
     return int(cache["k"].nbytes + cache["v"].nbytes)
 
 
-def _paged_view(plane: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Gather logical per-slot views from the pool: ``plane`` is
-    (depth, NBpool, BT, H, Dh), ``block_tables`` (B, NB) int32 ->
-    (depth, B, NB*BT, H, Dh). Out-of-range (sentinel) entries clip to the
-    last pool block — finite garbage the mask excludes."""
-    depth = plane.shape[0]
+def _paged_forward(params: Params, cache: Cache, ids: jax.Array,
+                   positions: jax.Array, block_tables: jax.Array,
+                   cfg: LMConfig) -> Tuple[jax.Array, Cache]:
+    """The paged prefill/decode/verify forward: ``ids``/``positions``
+    (B, T) int32, ``block_tables`` (B, NB) int32 -> (logits (B, T, V) f32,
+    cache). The pool planes ride the layer scan as its carry and are
+    touched one layer at a time, in place: layer ``l`` gathers its own
+    blocks through the table into a (B, NB*BT, H, Dh) view (sentinel
+    entries clip to the last pool block: finite garbage the mask
+    excludes), runs :func:`_cached_block` on it as the ring path does, and
+    writes only the B x T new rows into the pool at (l, block, offset).
+    Rows that map through a sentinel entry or past the table are dropped,
+    never clamped onto a live block."""
+    pk, pv = cache["k"], cache["v"]
+    nbpool, bt = pk.shape[1], pk.shape[2]
+    block_tables = jnp.asarray(block_tables, jnp.int32)
     b, nb = block_tables.shape
-    bt, h, dh = plane.shape[2], plane.shape[3], plane.shape[4]
-    flat = jnp.take(plane, block_tables.reshape(-1), axis=1, mode="clip")
-    return flat.reshape(depth, b, nb, bt, h, dh).reshape(
-        depth, b, nb * bt, h, dh)
-
-
-def _scatter_rows(plane: jax.Array, new_view: jax.Array,
-                  block_tables: jax.Array, positions: jax.Array
-                  ) -> jax.Array:
-    """Write the view rows at ``positions`` back into the pool.
-
-    ``new_view``: (depth, B, L, H, Dh) updated logical views;
-    ``positions``: (B, T) logical indices that were written this call.
-    Rows mapping through a sentinel table entry (or past the table) are
-    dropped — never clamped onto a live block."""
-    nbpool = plane.shape[1]
-    bt = plane.shape[2]
-    b, t = positions.shape
-    nb = block_tables.shape[1]
-    limit = nb * bt
-    blk_ix = jnp.clip(positions // bt, 0, nb - 1)               # (B, T)
-    phys = jnp.take_along_axis(block_tables, blk_ix, axis=1)    # (B, T)
-    phys = jnp.where(positions < limit, phys, nbpool)           # drop pads
+    phys = jnp.take_along_axis(
+        block_tables, jnp.clip(positions // bt, 0, nb - 1), axis=1)
+    phys = jnp.where(positions < nb * bt, phys, nbpool)  # (B, T); drop pads
     off = positions % bt
-    # rows being written: (depth, B, T, H, Dh)
-    vals = jnp.take_along_axis(
-        new_view, positions[None, :, :, None, None], axis=2)
-    return plane.at[:, phys, off].set(vals, mode="drop")
+    heads = cfg.encoder.heads
+    view = (b, nb * bt, heads, pk.shape[3] // heads)
+    rows = positions.shape + pk.shape[3:]
+    x = _embed_tokens(params, ids, positions, pk.dtype)
+
+    def body(carry, layer):
+        x, pk, pv = carry
+        p, l = layer
+        lk = pk.at[l, block_tables].get(mode="clip").reshape(view)
+        lv = pv.at[l, block_tables].get(mode="clip").reshape(view)
+        x, lk, lv = _cached_block(p, x, lk, lv, positions, heads)
+        # the rows come back out of the view the block wrote them to, so
+        # that the pool's write follows its read and needs no copy
+        k = jnp.take_along_axis(lk, positions[:, :, None, None], axis=1)
+        v = jnp.take_along_axis(lv, positions[:, :, None, None], axis=1)
+        pk = pk.at[l, phys, off].set(k.reshape(rows), mode="drop")
+        pv = pv.at[l, phys, off].set(v.reshape(rows), mode="drop")
+        return (x, pk, pv), None
+
+    (x, pk, pv), _ = jax.lax.scan(
+        body, (x, pk, pv), (params["blocks"], jnp.arange(pk.shape[0])))
+    return _lm_head(params, x), {"k": pk, "v": pv}
 
 
 def paged_prefill(params: Params, cache: Cache, block_table: jax.Array,
@@ -329,12 +355,8 @@ def paged_prefill(params: Params, cache: Cache, block_table: jax.Array,
     t = ids.shape[1]
     start = jnp.asarray(start, jnp.int32)
     positions = (start + jnp.arange(t, dtype=jnp.int32))[None]   # (1, T)
-    bt2 = jnp.asarray(block_table, jnp.int32)[None]              # (1, NB)
-    vk = _paged_view(cache["k"], bt2)
-    vv = _paged_view(cache["v"], bt2)
-    logits, ck, cv = _cached_forward(params, vk, vv, ids, positions, cfg)
-    cache = {"k": _scatter_rows(cache["k"], ck, bt2, positions),
-             "v": _scatter_rows(cache["v"], cv, bt2, positions)}
+    logits, cache = _paged_forward(params, cache, ids, positions,
+                                   jnp.asarray(block_table)[None], cfg)
     last = jnp.asarray(length, jnp.int32) - 1
     return logits[0, last], cache
 
@@ -349,12 +371,8 @@ def paged_decode_step(params: Params, cache: Cache, ids: jax.Array,
     only clipped garbage."""
     ids = jnp.asarray(ids, jnp.int32)[:, None]                   # (S, 1)
     positions2 = jnp.asarray(positions, jnp.int32)[:, None]
-    bts = jnp.asarray(block_tables, jnp.int32)
-    vk = _paged_view(cache["k"], bts)
-    vv = _paged_view(cache["v"], bts)
-    logits, ck, cv = _cached_forward(params, vk, vv, ids, positions2, cfg)
-    cache = {"k": _scatter_rows(cache["k"], ck, bts, positions2),
-             "v": _scatter_rows(cache["v"], cv, bts, positions2)}
+    logits, cache = _paged_forward(params, cache, ids, positions2,
+                                   block_tables, cfg)
     return logits[:, 0], cache
 
 
@@ -564,19 +582,15 @@ def paged_verify_step(params: Params, cache: Cache, ids: jax.Array,
     decode loop.
 
     The K/V written for rejected suffixes need no device-side rollback:
-    ``_cached_forward`` writes every new row before attention and the
+    ``_cached_block`` writes every new row before attention and the
     causal mask bounds reads at the query's own position, so the next
     round's writes overwrite any stale row before it can be attended.
     Returns (accept_len (S,) int32, tokens (S, k+1) int32 — the committed
     tokens left-packed, entries past accept_len are padding — cache)."""
     ids = jnp.asarray(ids, jnp.int32)
     positions = jnp.asarray(positions, jnp.int32)
-    bts = jnp.asarray(block_tables, jnp.int32)
-    vk = _paged_view(cache["k"], bts)
-    vv = _paged_view(cache["v"], bts)
-    logits, ck, cv = _cached_forward(params, vk, vv, ids, positions, cfg)
-    cache = {"k": _scatter_rows(cache["k"], ck, bts, positions),
-             "v": _scatter_rows(cache["v"], cv, bts, positions)}
+    logits, cache = _paged_forward(params, cache, ids, positions,
+                                   block_tables, cfg)
     s, k1 = ids.shape
     k = k1 - 1
     d = ids[:, 1:]                                       # (S, k) proposals

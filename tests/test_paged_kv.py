@@ -136,6 +136,167 @@ def test_paged_cow_divergence_no_corruption():
                 tables[s][blk] = 3 + s
 
 
+_BT, _NB, _POOL = 8, 4, 10          # block tokens, table width, pool blocks
+_SHARED = [4, 8, 15, 16, 23, 42, 7, 1]   # exactly one block
+
+
+def _ring_and_pool():
+    """Two live streams in a ring and in a pool that hold the same rows:
+    A (11 tokens) and B (14) share their first block, slot 2 is idle. Every
+    pool block no table names holds noise, the clipped-to last one too."""
+    import jax
+
+    from rafiki_tpu.models import lm
+
+    cfg = lm.tiny(vocab=64, max_len=_NB * _BT, dim=16, depth=2, heads=2)
+    params = lm.init(jax.random.PRNGKey(3), cfg)
+    prompts = [_SHARED + [11, 12, 13], _SHARED + [33, 2, 9, 40, 6, 21]]
+    ring = lm.init_kv_cache(cfg, max_slots=3, max_len=_NB * _BT)
+    toks = []
+    for slot, prompt in enumerate(prompts):
+        lg, ring = lm.prefill(params, ring, slot, np.pad(
+            np.asarray(prompt, np.int32), (0, 16 - len(prompt))),
+            len(prompt), cfg)
+        toks.append(int(lm.greedy_token(lg)))
+    rk, rv = np.asarray(ring["k"]), np.asarray(ring["v"])
+    assert np.array_equal(rk[:, 0, :_BT], rk[:, 1, :_BT])  # one shared block
+    tables = np.full((3, _NB), _POOL, np.int32)
+    tables[0, :2], tables[1, :2] = (4, 7), (4, 2)
+    rng = np.random.default_rng(5)
+    pool = {}
+    for name, plane in (("k", rk), ("v", rv)):
+        pp = rng.normal(size=(2, _POOL, _BT, 16)).astype(np.float32)
+        for slot in (0, 1):
+            for blk in (0, 1):
+                pp[:, tables[slot, blk]] = plane[
+                    :, slot, blk * _BT:(blk + 1) * _BT].reshape(2, _BT, 16)
+        pool[name] = pp
+    frontiers = np.array([len(prompts[0]), len(prompts[1]), 0], np.int32)
+    return cfg, params, ring, pool, tables, frontiers, toks
+
+
+def _assert_pool_tracks_ring(pool, before, ring, tables, frontiers, shared=4):
+    """Through its table each live slot reads the ring's rows, bit for bit,
+    up to its frontier; no block outside the live tables, nor the shared
+    one, has changed."""
+    for name in ("k", "v"):
+        pp, rr = np.asarray(pool[name]), np.asarray(ring[name])
+        for slot, n in enumerate(frontiers):
+            pos = np.arange(min(int(n), _NB * _BT))
+            phys = tables[slot, pos // _BT]
+            live = phys < _POOL          # rows past the table were dropped
+            rows = pp[:, phys[live], pos[live] % _BT]
+            assert np.array_equal(
+                rows, rr[:, slot, pos[live]].reshape(rows.shape))
+        named = {int(b) for b in tables.ravel() if b < _POOL}
+        for blk in (set(range(_POOL)) - named) | {shared}:
+            assert np.array_equal(pp[:, blk], before[name][:, blk]), blk
+
+
+@pytest.mark.parametrize("entry", ["decode", "prefill", "verify"])
+def test_paged_entry_points_bit_identical_to_ring(entry):
+    """Decode (T = 1), prefill in chunks of two bucket sizes and verify
+    (T = k+1) give the ring forward's bits, with an idle slot whose table
+    row is all sentinel, rows that map to sentinel entries (in the view,
+    never in the pool) and a block two tables share; and they write the new
+    rows and nothing else."""
+    import jax.numpy as jnp
+
+    from rafiki_tpu.models import lm
+
+    cfg, params, ring, pool, tables, front, toks = _ring_and_pool()
+    before = {n: a.copy() for n, a in pool.items()}
+    pool = {n: jnp.asarray(a) for n, a in pool.items()}
+    if entry == "decode":
+        ids = np.array(toks + [0], np.int32)
+        pos = front.copy()
+        for _ in range(6):            # B, then A, grow into a third block
+            for slot in (0, 1):
+                if tables[slot, pos[slot] // _BT] == _POOL:
+                    tables[slot, pos[slot] // _BT] = 5 + slot
+            lg_r, ring = lm.decode_step(params, ring, ids, pos, cfg)
+            lg_p, pool = lm.paged_decode_step(params, pool, ids, pos,
+                                              tables, cfg)
+            assert np.array_equal(np.asarray(lg_r), np.asarray(lg_p))
+            ids[:2] = np.asarray(lm.greedy_token(lg_r))[:2]
+            pos[:2] += 1
+        front = pos
+    elif entry == "prefill":
+        # a third stream of 13 tokens: a full chunk of 8, then 5 padded to
+        # a bucket of 16 whose last 8 rows map to a sentinel entry
+        prompt = np.asarray(_SHARED[::-1] + [3, 1, 4, 1, 5], np.int32)
+        tables[2, :2] = (9, 0)
+        for start, bucket, n in ((0, 8, 8), (8, 16, 5)):
+            ids = np.zeros(bucket, np.int32)
+            ids[:n] = prompt[start:start + n]
+            positions = (start + np.arange(bucket, dtype=np.int32))[None]
+            lg_r, ck, cv = lm._cached_forward(
+                params, ring["k"][:, 2:3], ring["v"][:, 2:3], ids[None],
+                positions, cfg)
+            ring = {"k": ring["k"].at[:, 2:3].set(ck),
+                    "v": ring["v"].at[:, 2:3].set(cv)}
+            lg_p, pool = lm.paged_prefill(params, pool, tables[2], ids,
+                                          start, n, cfg)
+            assert np.array_equal(np.asarray(lg_r)[0, n - 1],
+                                  np.asarray(lg_p))
+        front = np.array([front[0], front[1], 16], np.int32)
+    else:
+        k = 3                         # B's rows 16, 17 map to a sentinel
+        ids = np.zeros((3, k + 1), np.int32)
+        ids[:2, 0] = toks
+        positions = front[:, None] + np.arange(k + 1, dtype=np.int32)
+        for j in range(k + 1):        # the greedy chain, a proposal a pass
+            if j == k:
+                ids[0, k] = (ids[0, k] + 1) % 64   # A's last one is wrong
+            lg_r, ck, cv = lm._cached_forward(params, ring["k"], ring["v"],
+                                              ids, positions, cfg)
+            am = np.asarray(lm.greedy_token(lg_r))        # (3, k+1)
+            if j < k:
+                ids[:2, j + 1] = am[:2, j]
+        ring = {"k": ck, "v": cv}
+        sampling = {"seed": np.zeros(3, np.uint32),
+                    "temperature": np.zeros(3, np.float32),
+                    "top_k": np.zeros(3, np.int32),
+                    "top_p": np.ones(3, np.float32),
+                    "role": lm.ROLE_TARGET}
+        acc, out, pool = lm.paged_verify_step(
+            params, pool, ids, positions, tables,
+            np.full((3, k, 64), 1.0 / 64, np.float32), sampling, cfg)
+        for slot in (0, 1):
+            hits = list(ids[slot, 1:] == am[slot, :k]) + [False]
+            a = hits.index(False)
+            assert int(np.asarray(acc)[slot]) == a
+            assert list(np.asarray(out)[slot, :a + 1]) == \
+                list(ids[slot, 1:a + 1]) + [am[slot, a]]
+        assert list(np.asarray(acc)[:2]) == [k - 1, k]   # B earns the bonus
+        front = np.array([front[0] + k + 1, front[1] + k + 1, 0], np.int32)
+    _assert_pool_tracks_ring(pool, before, ring, tables, front)
+
+
+def test_paged_decode_program_keeps_no_whole_depth_view():
+    """The decode program gathers one layer's blocks at a time and updates
+    the donated pool in place: compiled, its temporaries stay under ONE
+    view of all slots over all layers. A pool-sized copy, or the views of
+    every layer gathered ahead of the scan, is at least two of them."""
+    import jax
+    import jax.numpy as jnp
+
+    from rafiki_tpu.models import lm
+
+    depth, slots, nb, bt, dim = 4, 4, 32, 16, 64
+    cfg = lm.tiny(vocab=256, max_len=nb * bt, dim=dim, depth=depth, heads=4)
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(
+        lambda: lm.init_paged_kv_cache(cfg, slots * nb, bt))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, i, q, t: lm.paged_decode_step(p, c, i, q, t, cfg),
+        donate_argnums=1).lower(
+            params, pool, i32(slots), i32(slots), i32(slots, nb)).compile()
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < depth * slots * nb * bt * dim * 4, temporaries
+
+
 def test_paged_cache_refuses_moe():
     from rafiki_tpu.models import lm
 
