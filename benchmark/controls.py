@@ -1,7 +1,7 @@
 """The readings the limits of `correct` are set from, taken on the chip at
 the cells' own sizes. Not part of a benchmark run.
 
-    python -m benchmark.controls vit --seeds 11 12 13
+    python -m benchmark.controls vit --seeds 11 12 13 [--workload <cell>]
     python -m benchmark.controls lm --samples benchmark/out/served_sample_*.json
 
 `vit`: for each seed the reference trial (float32, `highest`), then put in
@@ -10,10 +10,12 @@ that returns its state unchanged reads 1 by that measure and needs no run.
 `lm`: for each sample a run kept (the prompts and the tokens the program
 served), the token that each lower precision puts first at every position,
 beside the program's own tokens.
-Each goes through the family's own `judge()` with the cell's limits, as a
-run's numbers do, and is printed with the `correct` it would get: the
-control (`control_fp8`, `int8w`) and the fault have to read false. `bf16`
-is read beside them: the configuration says why it is no step down.
+The cell is `--workload`'s; its configuration file names the family and
+the reference. Each reading goes through the family's own `judge()` with the
+cell's limits, as a run's numbers do, and is printed with the `correct` it
+would get: the control (`control_fp8`, `int8w`) and the fault have to read
+false. `bf16` is read beside them: the configuration says why it is no step
+down.
 """
 
 from __future__ import annotations
@@ -27,16 +29,22 @@ import numpy as np
 from benchmark import harness, trafficgen
 
 
-def vit(seeds: list, lrs: list) -> None:
-    from benchmark.correct import vit_train
-    from benchmark.reference import vit as reference
+def _parts(workload: str) -> tuple:
+    """The cell, its family's comparison and its plain reference."""
+    cell = harness.load_cell(workload)
+    cfg = cell["config_data"]
+    return (cell, harness.load_by_name("correct", cfg["family"]),
+            harness.load_by_name("reference", cfg["reference"]))
 
-    cell = harness.load_cell("vit_b16.hpo_search")
-    cfg = vit_train.reference_cfg(cell["config_data"])
+
+def vit(workload: str, seeds: list, lrs: list) -> None:
+    cell, family, reference = _parts(workload)
+    cfg = family.reference_cfg(cell["config_data"])
     traffic = cell["traffic_data"]
+    sizes = harness.template_values(cfg)
     for seed, lr in [(s, r) for s in seeds for r in lrs]:
-        x, y = trafficgen.images(seed, traffic["n_train"], cfg["image_size"],
-                                 cfg["num_channels"], cfg["num_labels"])
+        x, y = trafficgen.images(seed, traffic["n_train"], sizes["IMAGE"],
+                                 sizes["CHANNELS"], sizes["CLASSES"])
         args = (seed % harness.SEED_MOD, cfg, x, y, lr,
                 traffic["batch_size"], traffic["epochs"])
         ref = reference.train(*args)
@@ -45,7 +53,7 @@ def vit(seeds: list, lrs: list) -> None:
         for name, kw in (("control_fp8", {"quant": "fp8"}),
                          ("fault_half_batch", {"fault": "half_batch"})):
             other = reference.train(*args, **kw)
-            checks = vit_train.judge(cfg, vit_train.compare(
+            checks = family.judge(cfg, family.compare(
                 {"epoch_losses": other["epoch_losses"],
                  "change_norm": other["change_norm"]}, ref))
             out[name] = {"correct": harness.within_limits(checks),
@@ -54,13 +62,10 @@ def vit(seeds: list, lrs: list) -> None:
         print(json.dumps(out), flush=True)
 
 
-def lm(paths: list) -> None:
+def lm(workload: str, paths: list) -> None:
     import jax
 
-    from benchmark.correct import lm_serve
-    from benchmark.reference import gpt2 as reference
-
-    cell = harness.load_cell("gpt2_large.chat_saturated")
+    cell, family, reference = _parts(workload)
     cfg = cell["config_data"]
     for path in paths:
         with open(path, encoding="utf-8") as f:
@@ -78,7 +83,7 @@ def lm(paths: list) -> None:
             readings[precision] = reference.token_gaps(ref_logits, first)
             del held
         for name, read in readings.items():
-            checks = lm_serve.judge(cfg, read)
+            checks = family.judge(cfg, read)
             out[name] = {"correct": harness.within_limits(checks),
                          "checks": checks, "gap_max": float(read.max()),
                          "off_best": int((read > 0).sum())}
@@ -93,17 +98,19 @@ def main(argv=None) -> int:
     a = sub.add_parser("vit")
     a.add_argument("--seeds", type=int, nargs="+", required=True)
     a.add_argument("--lr", type=float, nargs="+", default=[3e-4])
+    a.add_argument("--workload", default="vit_b16.hpo_search")
     b = sub.add_parser("lm")
     b.add_argument("--samples", nargs="+", required=True)
+    b.add_argument("--workload", default="gpt2_large.chat_saturated")
     args = ap.parse_args(argv)
     harness.find_chip(1)
     from rafiki_tpu.sdk import compile_cache
 
     compile_cache.enable()
     if args.what == "vit":
-        vit(args.seeds, args.lr)
+        vit(args.workload, args.seeds, args.lr)
     else:
-        lm(args.samples)
+        lm(args.workload, args.samples)
     return 0
 
 

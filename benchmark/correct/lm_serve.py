@@ -1,7 +1,7 @@
 """What decides `correct` in a generate cell: a sample, drawn from the seed,
-of the requests the window finished, with the longest in it, against
-benchmark/reference/gpt2.py's full forward pass over each prompt with its
-served tokens.
+of the requests the window finished, with the longest in it, against the
+full forward pass of the configuration's plain reference (`"reference"` in
+its file) over each prompt with its served tokens.
 
 The door gives tokens, not logits, and with random weights the largest logit
 changes on rounding. So what is read, for every served token of the sample,
@@ -21,7 +21,7 @@ import os
 
 import numpy as np
 
-from benchmark.reference import gpt2 as reference
+from benchmark import harness
 
 
 def sample(records: list, seed: int, count: int) -> list:
@@ -41,6 +41,7 @@ def sample(records: list, seed: int, count: int) -> list:
 
 
 def served_gaps(cfg: dict, seed31: int, picked: list) -> np.ndarray:
+    reference = harness.load_by_name("reference", cfg["reference"])
     weights = reference.make_weights(seed31, cfg)
     requests = [(r["prompt_ids"], r["tokens"]) for r in picked]
     logits = reference.served_logits(weights, cfg, requests)
@@ -57,8 +58,6 @@ def judge(cfg: dict, gaps: np.ndarray | None) -> dict:
 
 
 def check(cell: dict, ctx, result: dict) -> dict:
-    from benchmark.harness import SEED_MOD
-
     cfg, traffic = cell["config_data"], cell["traffic_data"]
     picked = sample(result["records"], ctx.seed, traffic["check_requests"])
     if not picked:
@@ -70,7 +69,7 @@ def check(cell: dict, ctx, result: dict) -> dict:
         json.dump({"seed": ctx.seed, "requests": [
             {"prompt_ids": r["prompt_ids"], "tokens": r["tokens"]}
             for r in picked]}, f)
-    gaps = served_gaps(cfg, ctx.seed % SEED_MOD, picked)
+    gaps = served_gaps(cfg, ctx.seed % harness.SEED_MOD, picked)
     result["check_info"] = {
         "requests": len(picked), "tokens": int(gaps.size),
         "tokens_off_best": int((gaps > 0).sum()),

@@ -7,6 +7,7 @@ which proved these on the chip in PR 21; nothing here is imported from it.
 
 from __future__ import annotations
 
+import functools
 import importlib
 import json
 import os
@@ -47,20 +48,40 @@ def load_json(*parts: str) -> dict:
 
 
 def load_by_name(package: str, name: str):
-    """The module `benchmark/<package>/<name>.py`: cells, kinds, readers and
-    operation counts are found by the name BENCHMARK.json gives, never by an
-    edit to a file that is there."""
+    """The module `benchmark/<package>/<name>.py`: kinds, readers, families,
+    references and operation counts are found by the name BENCHMARK.json, a
+    configuration file or a traffic file gives, never by an edit to a file
+    that is there."""
     if not re.fullmatch(r"[A-Za-z0-9_.\-]+", name):
         raise BenchmarkError(f"bad name {name!r}")
     return importlib.import_module(
         f"benchmark.{package}.{name.replace('.', '_').replace('-', '_')}")
 
 
+def template_values(cfg: dict) -> dict:
+    """The `# @cell` values a configuration sets in its template: its
+    `template.values` maps each name to a key of the same file, dotted for
+    a nested one. A kind adds what the seed and the traffic file give."""
+    values = {}
+    for name, key in cfg["template"]["values"].items():
+        try:
+            values[name] = functools.reduce(lambda d, k: d[k],
+                                            key.split("."), cfg)
+        except (KeyError, TypeError):
+            raise BenchmarkError(f"template.values: {name} names {key!r}, "
+                                 f"which the configuration does not have")
+    return values
+
+
+def load_benchmark() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
 def load_cell(workload: str) -> dict:
     """BENCHMARK.json's entry for `workload`, with its configuration and
     traffic files read in."""
-    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
-        bench = json.load(f)
+    bench = load_benchmark()
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise BenchmarkError(f"unknown workload {workload!r}; BENCHMARK.json "
@@ -239,21 +260,20 @@ class Platform:
 
 def render_template(config_name: str, values: dict, out_dir: str) -> str:
     """Write `configs/<config>_template.py` with its `# @cell` lines set to
-    this cell's values, and return the path to upload. The template on disk
-    is valid Python at a tiny size, which is what the CPU rehearsal runs."""
+    this cell's values, every one of them, and return the path to upload.
+    The template on disk is valid Python at a tiny size."""
     with open(os.path.join(HERE, "configs", f"{config_name}_template.py"),
               encoding="utf-8") as f:
         lines = f.read().split("\n")
-    seen = set()
-    for i, line in enumerate(lines):
-        m = re.match(r"^([A-Z_]+) = .*# @cell$", line)
-        if m and m.group(1) in values:
-            lines[i] = f"{m.group(1)} = {values[m.group(1)]!r}  # @cell"
-            seen.add(m.group(1))
-    missing = set(values) - seen
-    if missing:
-        raise BenchmarkError(f"template {config_name} has no # @cell line "
-                             f"for {sorted(missing)}")
+    at = {m.group(1): i for i, m in enumerate(
+        re.match(r"^([A-Z_]+) = .*# @cell$", line) for line in lines) if m}
+    if set(at) != set(values):  # a line left unset would run at its tiny size
+        raise BenchmarkError(
+            f"template {config_name}: no # @cell line for "
+            f"{sorted(set(values) - set(at))}, no value for "
+            f"{sorted(set(at) - set(values))}")
+    for name, i in at.items():
+        lines[i] = f"{name} = {values[name]!r}  # @cell"
     path = os.path.join(out_dir, f"{config_name}_template.py")
     with open(path, "w", encoding="utf-8") as f:
         f.write("\n".join(lines))

@@ -1,14 +1,13 @@
 """The least time the chip could take for one decode round at the stated
-widths and dtypes (benchmark/ops/lm_decode_round.py: weights read once, the
-live keys and values read once, the new rows written; memory-bound), over
+widths and dtypes (the configuration's `ops.decode_round` module: weights
+read once, the live keys and values read once, the new rows written), over
 the device time of one run of the decode program in the trace. Sequences
 and live tokens are those of the streams decoding during the traced
 seconds, from the client's log (serving.decoding): what the slots hold, not
 what the pool holds."""
 
-from benchmark import serving
+from benchmark import harness, serving
 from benchmark.layer_metrics import _shared
-from benchmark.ops import lm_decode_round
 
 
 def read(result, cell, peaks):
@@ -20,6 +19,7 @@ def read(result, cell, peaks):
         result["records"], trace["t0"], trace["t0"] + trace["window_s"])
     if not sequences:
         return None
-    least, _ = lm_decode_round.least_seconds(
-        cell["config_data"], sequences, live_tokens, peaks)
+    cfg = cell["config_data"]
+    ops = harness.load_by_name("ops", cfg["ops"]["decode_round"])
+    least, _ = ops.least_seconds(cfg, sequences, live_tokens, peaks)
     return 100.0 * least / took

@@ -1,9 +1,10 @@
-"""The whole serving step's share of the chip's peak: 2 operations for each
-parameter a token passes through (position table left out), for every
-prompt token prefilled and every answer token received in the window, over
-the window's seconds times the bf16 peak."""
+"""The whole serving step's share of the chip's peak: the operations a token
+costs (the configuration's `ops.decode_round` module says: 2 for each
+parameter the token passes through), for every prompt token prefilled and
+every answer token received in the window, over the window's seconds times
+the bf16 peak."""
 
-from benchmark.ops import lm_decode_round
+from benchmark import harness
 
 
 def read(result, cell, peaks):
@@ -11,7 +12,7 @@ def read(result, cell, peaks):
     tokens = result["tokens_in_window"] + result["prompt_tokens_in_window"]
     if not tokens:
         return None
-    per_token = 2.0 * (lm_decode_round.parameters(cfg)
-                       - cfg["n_positions"] * cfg["n_embd"])
+    ops = harness.load_by_name("ops", cfg["ops"]["decode_round"])
     window = result["t1"] - result["t0"]
-    return 100.0 * per_token * tokens / (window * peaks["bf16_flops_per_s"])
+    return (100.0 * ops.flops_per_token(cfg) * tokens
+            / (window * peaks["bf16_flops_per_s"]))

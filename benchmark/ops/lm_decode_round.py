@@ -12,10 +12,16 @@ def parameters(cfg: dict) -> int:
             + cfg["n_positions"] * dim + 2 * dim)
 
 
+def flops_per_token(cfg: dict) -> float:
+    """2 operations for each parameter a token passes through: all of this
+    dense model's but the position table, which is looked up."""
+    return 2.0 * (parameters(cfg) - cfg["n_positions"] * cfg["n_embd"])
+
+
 def flops(cfg: dict, sequences: int, live_tokens: float) -> float:
     """`live_tokens` is the sum over resident sequences of their lengths."""
     dim = cfg["n_embd"]
-    matmul = 2.0 * (parameters(cfg) - cfg["n_positions"] * dim) * sequences
+    matmul = flops_per_token(cfg) * sequences
     attention = 4.0 * cfg["n_layer"] * dim * live_tokens
     return matmul + attention
 

@@ -13,7 +13,7 @@ def flops(cfg: dict, batch: int) -> float:
                  + 4 * seq * seq * dim        # scores and weighted values
                  + 4 * seq * dim * hidden)    # MLP in and out
     patch = 2 * seq * dim * cfg["patch_size"] ** 2 * cfg["num_channels"]
-    head = 2 * dim * cfg["num_labels"]
+    head = 2 * dim * cfg["assumed"]["num_labels"]
     forward = cfg["num_hidden_layers"] * per_block + patch + head
     return 3.0 * forward * batch
 
@@ -26,8 +26,8 @@ def parameters(cfg: dict) -> int:
              + 4 * dim)                         # two LayerNorms
     return (cfg["num_hidden_layers"] * block
             + cfg["patch_size"] ** 2 * cfg["num_channels"] * dim + dim
-            + seq * dim + 2 * dim + dim * cfg["num_labels"]
-            + cfg["num_labels"])
+            + seq * dim + 2 * dim
+            + (dim + 1) * cfg["assumed"]["num_labels"])
 
 
 def bytes_moved(cfg: dict, batch: int) -> float:

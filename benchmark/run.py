@@ -71,7 +71,7 @@ def run_cell(cell: dict, ctx: Context) -> dict:
     line["info"] = {"setup_s": result["setup_s"], "check_s": check_s,
                     **harness.memory_parts(ctx.devices),
                     "compiles_in_window": result["compile"]["programs"],
-                    **result.get("check_info", {})}
+                    **result.get("info", {}), **result.get("check_info", {})}
     line["checks"] = checks
     return line
 

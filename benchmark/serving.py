@@ -20,18 +20,16 @@ import numpy as np
 from benchmark import harness, trafficgen
 
 APP, MODEL = "bench_chat", "bench_lm"
-TEMPLATE_KEYS = {"VOCAB": "vocab_size", "MAX_CONTEXT": "n_positions",
-                 "DIM": "n_embd", "DEPTH": "n_layer", "HEADS": "n_head"}
 GAUGES = ("rafiki_gen_slots_busy",)
 COUNTERS = ("rafiki_gen_tokens_total", "rafiki_gen_preemptions_total",
             "rafiki_gen_prefix_hits_total")
 
 
 def template_values(cfg: dict, traffic: dict, seed: int) -> dict:
-    values = {k: cfg[v] for k, v in TEMPLATE_KEYS.items()}
-    values.update(SEED=seed % harness.SEED_MOD,
-                  FAULT=traffic.get("fault", ""))
-    return values
+    """The configuration's own values (among them `VOCAB` and `MAX_CONTEXT`,
+    which the requests are drawn within), the seed, and a test's fault."""
+    return {**harness.template_values(cfg), "SEED": seed % harness.SEED_MOD,
+            "FAULT": traffic.get("fault", "")}
 
 
 def _registry_total(name: str) -> float:
@@ -73,9 +71,9 @@ def run(cell: dict, ctx) -> dict:
     child = None
     try:
         client = platform.login()
-        path = harness.render_template(
-            cell["config"], template_values(cfg, traffic, ctx.seed),
-            platform.workdir)
+        values = template_values(cfg, traffic, ctx.seed)
+        path = harness.render_template(cell["config"], values,
+                                       platform.workdir)
         task = cfg["template"]["task"]
         client.create_model(MODEL, task, path, cfg["template"]["class"])
         client.create_train_job(
@@ -94,7 +92,7 @@ def run(cell: dict, ctx) -> dict:
         if inf["status"] != "RUNNING" or not inf.get("predictor_port"):
             raise harness.BenchmarkError(f"inference job not serving: {inf}")
 
-        spec = _spec(cell, ctx, platform)
+        spec = _spec(traffic, ctx, platform, values)
         # the first request through the door compiles (or loads) the prefill
         # chunk and the decode round; the child's own warm request follows
         _one_stream(client, spec["warm"])
@@ -155,16 +153,15 @@ def run(cell: dict, ctx) -> dict:
     }
 
 
-def _spec(cell: dict, ctx, platform) -> dict:
-    cfg, traffic = cell["config_data"], cell["traffic_data"]
-    vocab = cfg["vocab_size"]
+def _spec(traffic: dict, ctx, platform, values: dict) -> dict:
+    vocab = values["VOCAB"]
     count = int(ctx.seconds * traffic["most_requests_per_s"]
                 + traffic["callers"])
     requests = trafficgen.request_stream(traffic, ctx.seed, vocab, count)
     # a chunk and a half, ending inside a block: the prefill chunk, the
     # decode round and the copy-on-write of the published last block
     chunk = int(os.environ.get("RAFIKI_GEN_PREFILL_CHUNK", "64"))
-    warm_prompt = min(chunk + chunk // 2 + 5, cfg["n_positions"] - 8)
+    warm_prompt = min(chunk + chunk // 2 + 5, values["MAX_CONTEXT"] - 8)
     warm = {"prompt_ids": np.random.default_rng([ctx.seed, 3]).integers(
         0, vocab, size=warm_prompt).tolist(), "max_tokens": 4}
     return {**platform.credentials(), "app": APP,
@@ -206,7 +203,11 @@ def reduce_records(records: list, t0: float, t1: float,
               or len(r["tokens"]) != r["max_tokens"]]
     prefilled = sum(r["prompt_tokens"] for r in records
                     if r["deltas"] and t0 <= r["deltas"][0][0] < t1)
+    why = [r["error"] or f"{len(r['tokens'])} of {r['max_tokens']} tokens, "
+           f"reason {r['reason']}, ended {r['done'] is not None}"
+           for r in failed[:3]]
     return {"attempted": len(records), "failed": len(failed),
+            "info": {"failures": why},
             "tokens_in_window": tokens, "prompt_tokens_in_window": prefilled,
             "end_to_end": {"tokens_per_s": tokens / seconds}}
 

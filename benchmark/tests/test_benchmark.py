@@ -23,9 +23,7 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
-def bench() -> dict:
-    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+CELLS = tiny.cases()  # every cell of BENCHMARK.json, with its faults
 
 
 def check_line(line: dict, cell: dict, trace: bool) -> None:
@@ -46,7 +44,7 @@ def check_line(line: dict, cell: dict, trace: bool) -> None:
 # -- BENCHMARK.json ------------------------------------------------------------
 
 def test_names_units_and_files():
-    b = bench()
+    b = harness.load_benchmark()
     e2e = {m["name"]: m for m in b["end_to_end"]}
     cells = {w["name"]: w for w in b["workloads"]}
     for group in (b["configs"], b["workloads"], b["end_to_end"],
@@ -120,7 +118,10 @@ def test_the_client_log_gives_the_rate_and_what_the_rounds_held():
     assert out["prompt_tokens_in_window"] == 5 and out["failed"] == 0
     short = rec(10.0, 10.5)
     short["tokens"] = [1]
-    assert serving.reduce_records([short], 10.0, 20.0, 10.0)["failed"] == 1
+    cut = serving.reduce_records([short], 10.0, 20.0, 10.0)
+    assert cut["failed"] == 1
+    assert cut["info"]["failures"] == [
+        "1 of 3 tokens, reason length, ended True"]
     # decoding from 10.5 to 10.6 at length 5 + 1, a tenth of [10, 11)
     sequences, live = serving.decoding([rec(10.4, 10.5)], 10.0, 11.0)
     assert sequences == pytest.approx(0.1) and live == pytest.approx(0.6)
@@ -224,11 +225,7 @@ def test_trace_reduce_on_a_recorded_trace():
 # -- rehearsals: a whole run, tiny, on the CPU ----------------------------------
 
 @pytest.mark.parametrize("workload,trace", [
-    ("vit_b16.hpo_search", False),
-    ("vit_b16.hpo_search", True),
-    ("gpt2_large.chat_saturated", False),
-    ("gpt2_large.chat_saturated", True),
-])
+    (name, trace) for name, _ in CELLS for trace in (False, True)])
 def test_rehearsal(workload, trace, tmp_path):
     cell = tiny.cell(workload)
     line = run.run_cell(cell, tiny.context(str(tmp_path), seed=2**31 + 11,
@@ -239,10 +236,7 @@ def test_rehearsal(workload, trace, tmp_path):
 
 
 @pytest.mark.parametrize("workload,fault", [
-    ("vit_b16.hpo_search", "frozen"),
-    ("vit_b16.hpo_search", "half_batch"),
-    ("gpt2_large.chat_saturated", "wrong_token"),
-])
+    (name, fault) for name, faults in CELLS for fault in faults])
 def test_a_broken_timed_path_is_not_correct(workload, fault, tmp_path):
     """The rest of a run with the timed path broken underneath: a step that
     returns its state unchanged, half of each batch left out with the mean
@@ -311,28 +305,77 @@ def test_control_precision_is_not_correct_lm(seed):
 
 # -- driven by data: a later PR adds files and entries only ----------------------
 
-def test_a_cell_a_configuration_and_a_metric_are_added_by_files(tmp_path):
-    root = tmp_path / "copy"
-    shutil.copytree(os.path.join(harness.ROOT, "benchmark"),
-                    root / "benchmark",
-                    ignore=shutil.ignore_patterns("out", "__pycache__"))
-    b = bench()
+def test_the_committed_templates_render_as_the_fixed_keys_rendered_them(
+        tmp_path):
+    """Both templates at the committed sizes: the `# @cell` lines that the
+    parent's two TEMPLATE_KEYS tables and kinds gave (kept here as text),
+    and every other line as it is on disk."""
+    was = {
+        "vit_b16.hpo_search": [
+            "SEED = 7", "IMAGE = 224", "PATCH = 16", "CHANNELS = 3",
+            "DIM = 768", "DEPTH = 12", "HEADS = 12", "CLASSES = 10",
+            "BATCH = 32", "EPOCHS = 8", "LR_MIN = 0.0001", "LR_MAX = 0.001",
+            "FAULT = ''"],
+        "gpt2_large.chat_saturated": [
+            "SEED = 7", "VOCAB = 50257", "MAX_CONTEXT = 1024", "DIM = 1280",
+            "DEPTH = 36", "HEADS = 20", "FAULT = ''"]}
+    from benchmark.traffic.kinds import train_job
+
+    values_of = {"vit_b16.hpo_search": train_job.template_values,
+                 "gpt2_large.chat_saturated": serving.template_values}
+    for workload, lines in was.items():  # the parent's two, not every cell
+        cell = harness.load_cell(workload)
+        values = values_of[workload](
+            cell["config_data"], cell["traffic_data"], 7 + harness.SEED_MOD)
+        with open(harness.render_template(cell["config"], values,
+                                          str(tmp_path))) as f:
+            rendered = f.read().split("\n")
+        with open(os.path.join(harness.HERE, "configs",
+                               f"{cell['config']}_template.py")) as f:
+            on_disk = f.read().split("\n")
+        set_lines = lambda text: [t for t in text if t.endswith("# @cell")]
+        assert set_lines(rendered) == [line + "  # @cell" for line in lines]
+        assert len(rendered) == len(on_disk) and all(
+            r == d or d in set_lines(on_disk)
+            for r, d in zip(rendered, on_disk))
+
+
+OTHER_KEYS = {"n_embd": "hidden_size", "n_layer": "num_hidden_layers",
+              "n_head": "num_attention_heads",
+              "n_positions": "max_position_embeddings"}
+
+
+def _reads_other_keys(package: str, module: str, with_cfg: dict) -> str:
+    """A reference or an operation count of the new family's own: a module
+    that reads the new file's keys (and hands them to its relative under
+    the names that one reads). `with_cfg`: function -> where `cfg` stands
+    among its arguments."""
+    return "\n".join([
+        f"from benchmark.{package} import {module} as _base",
+        f"KEYS = {OTHER_KEYS!r}",
+        "def _with_cfg(name, at):",
+        "    def call(*args):",
+        "        cfg = args[at]",
+        "        cfg = {**cfg, **{old: cfg[new] for old, new in KEYS.items()}}",
+        "        return getattr(_base, name)(*args[:at], cfg, *args[at + 1:])",
+        "    return call",
+        *[f"{name} = _with_cfg({name!r}, {at})"
+          for name, at in with_cfg.items()]]) + "\n"
+
+
+def _add_a_vit_under_the_same_keys(root, b: dict) -> None:
     cfgs = root / "benchmark" / "configs"
     new_cfg = json.loads((cfgs / "vit_b16.json").read_text())
-    new_cfg.update(tiny.TINY_VIT, name="vit_new")
-    new_cfg["limits"] = {"loss_first_epoch_rel": 0.003,
-                         "change_worst_leaf_rel": 0.008,
-                         "change_median_leaf_rel": 0.002}
+    new_cfg["name"] = "vit_new"
     (cfgs / "vit_new.json").write_text(json.dumps(new_cfg))
     shutil.copy(cfgs / "vit_b16_template.py", cfgs / "vit_new_template.py")
-    traffic = harness.load_json("traffic", "hpo_search.json")
-    traffic.update(n_train=32, n_test=16, batch_size=8, epochs=2)
-    (root / "benchmark" / "traffic" / "short_search.json").write_text(
-        json.dumps(traffic))
+    shutil.copy(root / "benchmark" / "traffic" / "hpo_search.json",
+                root / "benchmark" / "traffic" / "short_search.json")
     (root / "benchmark" / "layer_metrics" / "trials_scored.py").write_text(
         "def read(result, cell, peaks):\n"
-        "    return float(sum(t['status'] == 'COMPLETED'\n"
-        "                     for t in result['trials']))\n")
+        "    done = [t for t in result['trials']\n"
+        "            if t['status'] == 'COMPLETED']\n"
+        "    return float(len(done)) if done else None\n")
     b["configs"].append({"name": "vit_new", "source": "test",
                          "file": "benchmark/configs/vit_new.json",
                          "reduced": [], "why": "test"})
@@ -347,24 +390,137 @@ def test_a_cell_a_configuration_and_a_metric_are_added_by_files(tmp_path):
         "source": "program_counter", "layer": "train worker",
         "moves": "train_samples_per_s",
         "workloads": ["vit_new.short_search"]})
+
+
+def _add_a_language_model_under_other_keys(root, b: dict) -> None:
+    """`lm_new`: GPT-2 large's file keyed as most published configurations
+    are, a template with one more `# @cell` line (the MLP's ratio, 2 on disk
+    and 4 in the file: weights of another shape where it is not set), a
+    reference and an operation count that read those keys, `tiny` in the
+    file; one cell on it, reporting what the committed generate cell does."""
+    at = root / "benchmark"
+    old = json.loads((at / "configs" / "gpt2_large.json").read_text())
+    rename = lambda d: {OTHER_KEYS.get(k, k): v for k, v in d.items()
+                        if k != "n_ctx"}
+    new_cfg = rename(old)
+    new_cfg.update(name="lm_new", mlp_ratio=4, reference="lm_new",
+                   ops={"decode_round": "lm_new_round"})
+    new_cfg["template"]["values"] = {
+        "VOCAB": "vocab_size", "MAX_CONTEXT": "max_position_embeddings",
+        "DIM": "hidden_size", "DEPTH": "num_hidden_layers",
+        "HEADS": "num_attention_heads", "MLP_RATIO": "mlp_ratio"}
+    new_cfg["tiny"]["sizes"] = rename(old["tiny"]["sizes"])
+    (at / "configs" / "lm_new.json").write_text(json.dumps(new_cfg))
+    template = (at / "configs" / "gpt2_large_template.py").read_text()
+    for was, now in (("HEADS = 4  # @cell\n",
+                      "HEADS = 4  # @cell\nMLP_RATIO = 2  # @cell\n"),
+                     ("heads=HEADS,", "heads=HEADS, mlp_ratio=MLP_RATIO,"),
+                     ("DEPTH, 4 * DIM\n", "DEPTH, MLP_RATIO * DIM\n")):
+        assert template.count(was) == 1
+        template = template.replace(was, now)
+    (at / "configs" / "lm_new_template.py").write_text(template)
+    (at / "reference" / "lm_new.py").write_text(
+        _reads_other_keys("reference", "gpt2",
+                          {"make_weights": 1, "served_logits": 1})
+        + "token_gaps, at_precision = _base.token_gaps, _base.at_precision\n")
+    (at / "ops" / "lm_new_round.py").write_text(_reads_other_keys(
+        "ops", "lm_decode_round", {"flops_per_token": 0, "least_seconds": 0}))
+    shutil.copy(at / "traffic" / "chat_saturated.json",
+                at / "traffic" / "short_chat.json")
+    b["configs"].append({"name": "lm_new", "source": "test",
+                         "file": "benchmark/configs/lm_new.json",
+                         "reduced": [], "why": "test"})
+    b["workloads"].append({"name": "lm_new.short_chat", "config": "lm_new",
+                           "traffic": "short_chat", "chips": 1,
+                           "why": "test"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if "gpt2_large.chat_saturated" in m.get("workloads", []):
+            m["workloads"].append("lm_new.short_chat")
+
+
+def _drive_a_copy_with(add, tmp_path, script: str, suite=False) -> list:
+    """What a later PR does: files and entries added by `add` to a copy of
+    the benchmark, none that was there touched. `script` then runs in the
+    copy, with `rehearse(workload, trace, fault)` at hand; the JSON lines it
+    printed. With `suite`, the copy's own tests then run over its cells:
+    a test that closes the set of cells fails here, not in the later PR."""
+    root = tmp_path / "copy"
+    shutil.copytree(os.path.join(harness.ROOT, "benchmark"),
+                    root / "benchmark",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    b = harness.load_benchmark()
+    add(root, b)
     (root / "BENCHMARK.json").write_text(json.dumps(b))
+    assert all(p.read_bytes() == was for p, was in before.items())
     driver = (
         "import json, sys\n"
         "from benchmark import harness, run\n"
         "from benchmark.tests import tiny\n"
-        "cell = harness.load_cell('vit_new.short_search')\n"
-        "line = run.run_cell(cell, tiny.context(sys.argv[1], seconds=2.0,"
-        " trace=True))\n"
-        "print(json.dumps(line))\n")
+        "def rehearse(workload, trace, fault=''):\n"
+        "    cell = tiny.cell(workload, fault=fault)\n"
+        "    line = run.run_cell(cell, tiny.context(\n"
+        "        sys.argv[1], seconds=2.0, trace=trace))\n"
+        "    print(json.dumps(line))\n") + script
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": f"{root}{os.pathsep}{harness.ROOT}"}
     done = subprocess.run([sys.executable, "-c", driver, str(tmp_path)],
                           cwd=root, env=env, capture_output=True, text=True,
                           timeout=600)
     assert done.returncode == 0, done.stderr[-3000:]
-    line = json.loads(done.stdout.strip().split("\n")[-1])
+    if suite:
+        tests = subprocess.run(
+            [sys.executable, "-m", "pytest", "benchmark/tests", "-q", "-x",
+             "-p", "no:cacheprovider", "-k", "not added_by_files"],
+            cwd=root, env=env, capture_output=True, text=True, timeout=1800)
+        assert tests.returncode == 0, tests.stdout[-3000:]
+    return [json.loads(line) for line in done.stdout.strip().split("\n")
+            if line.startswith(("{", "["))]
+
+
+def test_a_cell_a_configuration_and_a_metric_are_added_by_files(tmp_path):
+    line, = _drive_a_copy_with(
+        _add_a_vit_under_the_same_keys, tmp_path,
+        "rehearse('vit_new.short_search', True)\n")
     assert line["correct"] is True
     assert line["metrics"]["trials_scored"]["value"] >= 1
+
+
+def test_a_language_model_under_other_keys_is_added_by_files(tmp_path):
+    def both(root, b):
+        _add_a_vit_under_the_same_keys(root, b)
+        _add_a_language_model_under_other_keys(root, b)
+
+    lm, broken, unset, rooflines = _drive_a_copy_with(
+        both, tmp_path, suite=True, script=
+        "rehearse('lm_new.short_chat', True)\n"
+        "rehearse('lm_new.short_chat', False, 'wrong_token')\n"
+        # a `# @cell` line that the file forgets would run at its tiny size
+        "from benchmark import serving\n"
+        "cell = tiny.cell('lm_new.short_chat')\n"
+        "del cell['config_data']['template']['values']['MLP_RATIO']\n"
+        "values = serving.template_values(\n"
+        "    cell['config_data'], cell['traffic_data'], 1)\n"
+        "try:\n"
+        "    harness.render_template('lm_new', values, sys.argv[1])\n"
+        "except harness.BenchmarkError as e:\n"
+        "    print(json.dumps({'refused': str(e)}))\n"
+        # the CPU's trace has no device plane: the roofline's reader on a
+        # reduced trace made by hand, over the new cell and the committed
+        "record = {'prompt_tokens': 5, 'deltas': [[0.1, 1], [0.6, 2]]}\n"
+        "result = {'records': [record], 'trace': {'path': 'by hand',\n"
+        "          't0': 0.0, 'window_s': 1.0}, '_reduced': {\n"
+        "          'module_s': {'jit_paged_decode_round': 0.5},\n"
+        "          'module_runs': {'jit_paged_decode_round': 10}}}\n"
+        "reader = harness.load_by_name('layer_metrics',\n"
+        "                              'decode_step_roofline')\n"
+        "print(json.dumps([reader.read(result, tiny.cell(w), tiny.CPU_PEAKS)\n"
+        "    for w in ('lm_new.short_chat', 'gpt2_large.chat_saturated')]))\n")
+    assert lm["correct"] is True and lm["failed"] == 0
+    assert lm["metrics"]["mfu.generate.sat"]["value"] > 0
+    assert broken["correct"] is False
+    assert "no value for ['MLP_RATIO']" in unset["refused"]
+    assert rooflines[0] == rooflines[1] and rooflines[0] > 0
 
 
 def test_a_reader_that_finds_nothing_returns_nothing():

@@ -11,37 +11,28 @@ import time
 from benchmark import harness
 from benchmark.harness import Context
 
-TINY_VIT = {"hidden_size": 64, "num_hidden_layers": 2,
-            "num_attention_heads": 4, "intermediate_size": 256,
-            "patch_size": 4, "image_size": 32}
-TINY_LM = {"n_embd": 64, "n_layer": 2, "n_head": 4, "n_positions": 128,
-           "n_ctx": 128, "vocab_size": 512}
 CPU_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
              "hbm_bytes": 1e10}
 
 
 def cell(workload: str, **traffic_changes) -> dict:
-    """`workload` of BENCHMARK.json at a tiny size."""
+    """`workload` of BENCHMARK.json at a tiny size: the `tiny` of its
+    configuration file (`sizes` over the published keys, `limits` for the
+    limits) and of its traffic file (over the file's own keys)."""
     c = copy.deepcopy(harness.load_cell(workload))
-    if c["config"] == "vit_b16":
-        c["config_data"].update(TINY_VIT)
-        c["traffic_data"].update(n_train=32, n_test=16, batch_size=8,
-                                 epochs=2, trace_seconds=2)
-        c["config_data"]["limits"] = {
-            "loss_first_epoch_rel": 0.003, "change_worst_leaf_rel": 0.008,
-            "change_median_leaf_rel": 0.002}
-    else:
-        c["config_data"].update(TINY_LM)
-        for key in ("prompt_tokens", "answer_tokens"):
-            c["traffic_data"][key] = {"mean": 13, "sigma": 0.5, "min": 4,
-                                      "max": 40}
-        c["traffic_data"]["answer_tokens"]["max"] = 16
-        c["traffic_data"].update(shapes=8, check_requests=4, trace_seconds=1)
-        c["traffic_data"]["settings"] = {
-            "budget": {}, "env": {"RAFIKI_GEN_PREFILL_CHUNK": "16"}}
-        c["config_data"]["limits"] = {"served_gap_mean": 1e-3}
+    small = c["config_data"].pop("tiny")
+    c["config_data"].update(small["sizes"], limits=small["limits"])
+    c["traffic_data"].update(c["traffic_data"].pop("tiny"))
     c["traffic_data"].update(traffic_changes)
     return c
+
+
+def cases() -> list:
+    """Every cell of BENCHMARK.json with the faults its configuration's
+    template knows: what the rehearsals and the broken paths run over."""
+    names = [w["name"] for w in harness.load_benchmark()["workloads"]]
+    return [(name, harness.load_cell(name)["config_data"]["tiny"]["faults"])
+            for name in names]
 
 
 def context(out_dir: str, seed: int = 5, seconds: float = 3.0,

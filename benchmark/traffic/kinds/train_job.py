@@ -18,30 +18,24 @@ import numpy as np
 from benchmark import harness, trafficgen
 
 APP, MODEL = "bench_search", "bench_vit"
-TEMPLATE_KEYS = {"IMAGE": "image_size", "PATCH": "patch_size",
-                 "CHANNELS": "num_channels", "DIM": "hidden_size",
-                 "DEPTH": "num_hidden_layers", "HEADS": "num_attention_heads"}
 
 
 def template_values(cfg: dict, traffic: dict, seed: int) -> dict:
-    values = {k: cfg[v] for k, v in TEMPLATE_KEYS.items()}
-    if cfg["intermediate_size"] != 4 * cfg["hidden_size"]:
-        raise harness.BenchmarkError("models/transformer.py fixes the MLP at "
-                                     "four times the hidden size")
-    values.update(SEED=seed % harness.SEED_MOD,
-                  CLASSES=cfg["assumed"]["num_labels"],
-                  BATCH=traffic["batch_size"], EPOCHS=traffic["epochs"],
-                  LR_MIN=traffic["lr_min"], LR_MAX=traffic["lr_max"],
-                  FAULT=traffic.get("fault", ""))
-    return values
+    """The configuration's own values (among them `IMAGE`, `CHANNELS` and
+    `CLASSES`, which the data set is made to), the seed, and the traffic
+    file's trial."""
+    return {**harness.template_values(cfg), "SEED": seed % harness.SEED_MOD,
+            "BATCH": traffic["batch_size"], "EPOCHS": traffic["epochs"],
+            "LR_MIN": traffic["lr_min"], "LR_MAX": traffic["lr_max"],
+            "FAULT": traffic.get("fault", "")}
 
 
 def run(cell: dict, ctx) -> dict:
     cfg, traffic = cell["config_data"], cell["traffic_data"]
     n_train, n_test = traffic["n_train"], traffic["n_test"]
-    x, y = trafficgen.images(ctx.seed, n_train + n_test, cfg["image_size"],
-                             cfg["num_channels"],
-                             cfg["assumed"]["num_labels"])
+    values = template_values(cfg, traffic, ctx.seed)
+    x, y = trafficgen.images(ctx.seed, n_train + n_test, values["IMAGE"],
+                             values["CHANNELS"], values["CLASSES"])
     platform = harness.Platform(len(ctx.devices), traffic["settings"])
     try:
         client = platform.login()
@@ -52,9 +46,8 @@ def run(cell: dict, ctx) -> dict:
                            ("test", slice(n_train, None))):
             uris[name] = os.path.join(data_dir, f"{name}.npz")
             np.savez(uris[name], x=x[rows], y=y[rows])  # floats do not deflate
-        path = harness.render_template(
-            cell["config"], template_values(cfg, traffic, ctx.seed),
-            platform.workdir)
+        path = harness.render_template(cell["config"], values,
+                                       platform.workdir)
         client.create_model(MODEL, cfg["template"]["task"], path,
                             cfg["template"]["class"])
         budget = {"MODEL_TRIAL_COUNT": traffic["trial_count"],
