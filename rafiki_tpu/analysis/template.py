@@ -656,8 +656,10 @@ def _check_generation(
         return None
     kwargs = {kw.arg: kw.value for kw in node.keywords if kw.arg}
     args = list(node.args)
-    spec: Dict[str, Any] = {"eos_token_id": None, "max_context": 128}
-    for key, pos in (("eos_token_id", 0), ("max_context", 1)):
+    spec: Dict[str, Any] = {"eos_token_id": None, "max_context": 128,
+                            "recurrent_state": False}
+    for key, pos in (("eos_token_id", 0), ("max_context", 1),
+                     ("recurrent_state", 2)):
         val_node = args[pos] if len(args) > pos else kwargs.get(key)
         if val_node is not None and astutil.is_constant(val_node):
             try:
@@ -682,6 +684,11 @@ def _check_generation(
     to_check.update({m: n
                      for m, n in SAMPLING_GENERATION_SIGNATURES.items()
                      if m in methods})
+    if spec["recurrent_state"]:
+        # the worker also passes max_slots and the slot (sdk/model.py)
+        for mname in ("init_paged_kv_cache", "paged_prefill"):
+            if mname in to_check:
+                to_check[mname] += 1
     for mname, n_args in to_check.items():
         fn = methods[mname]
         if fn.args.vararg is not None:

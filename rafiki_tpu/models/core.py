@@ -84,6 +84,33 @@ def layernorm(params: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return y.astype(x.dtype)
 
 
+def rmsnorm_init(dim: int) -> Params:
+    return {"scale": jnp.ones((dim,), jnp.float32)}
+
+
+def rmsnorm(params: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """Root-mean-square norm, no centring and no bias; statistics in f32.
+    Returns f32: what follows it is a product's input, which casts."""
+    xf = x.astype(jnp.float32)
+    rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return xf * rms * params["scale"]
+
+
+def group_rmsnorm(scale: jax.Array, x: jax.Array, groups: int,
+                  eps: float = 1e-5) -> jax.Array:
+    """RMS norm over each of `groups` equal runs of the last axis (the
+    gated norm of a Mamba-2 mixer), then the elementwise scale; f32."""
+    xf = x.astype(jnp.float32)
+    g = xf.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(x.shape) * scale
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """Squared ReLU."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def embedding_init(rng: jax.Array, vocab: int, dim: int, std: float = 0.02) -> Params:
     return {"table": normal_init(rng, (vocab, dim), std)}
 

@@ -103,8 +103,8 @@ def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array],
 #
 # Both forwards share one implementation (``_cached_forward``): prefill is
 # the T=P case with positions 0..P-1, decode the T=1 case at each slot's
-# current position. Dense blocks only — MoE routing differs per token batch
-# and is refused at cache init.
+# current position. An expert block routes each token on its own and drops
+# none (parallel/moe.py), so it decodes through the cache as a dense one does.
 
 Cache = Dict[str, jax.Array]
 
@@ -115,11 +115,6 @@ def init_kv_cache(cfg: LMConfig, max_slots: int,
     """Preallocate the decode cache: per-layer K/V of shape
     ``(depth, max_slots, max_len, heads, head_dim)``. ``max_len`` defaults
     to ``cfg.max_len`` (prompt + generated tokens must fit)."""
-    if cfg.encoder.moe_experts > 0:
-        raise ValueError(
-            "KV-cached decode supports dense blocks only (moe_experts=0): "
-            "MoE top-k routing is per-token and the fixed-shape decode "
-            "program cannot carry its dispatch state in the cache")
     enc = cfg.encoder
     max_len = int(max_len or cfg.max_len)
     shape = (enc.depth, int(max_slots), max_len, enc.heads,
@@ -143,7 +138,7 @@ def _embed_tokens(params: Params, ids, positions, dtype) -> jax.Array:
 
 def _cached_block(p: Params, x: jax.Array, lk: jax.Array, lv: jax.Array,
                   positions: jax.Array, heads: int):
-    """One dense block over cache views ``lk``/``lv`` (B, L, H, Dh), for the
+    """One block (dense or expert feed-forward) over cache views ``lk``/``lv`` (B, L, H, Dh), for the
     ring and the paged forward alike: sets the new tokens' K/V at
     ``positions``, attends up to each query's own position. Returns
     (x, lk, lv), the views updated."""
@@ -167,9 +162,14 @@ def _cached_block(p: Params, x: jax.Array, lk: jax.Array, lv: jax.Array,
         "bthk,hkd->btd", o, p["attn"]["wo"].astype(x.dtype))
     x = x + attn_out + p["attn"]["bo"].astype(x.dtype)
     h = core.layernorm(p["ln2"], x)
-    h = core.dense(p["mlp"]["w1"], h)
-    h = jax.nn.gelu(h)
-    h = core.dense(p["mlp"]["w2"], h)
+    if "moe" in p:
+        from rafiki_tpu.parallel.moe import moe_apply
+
+        h, _ = moe_apply(p["moe"], h)
+    else:
+        h = core.dense(p["mlp"]["w1"], h)
+        h = jax.nn.gelu(h)
+        h = core.dense(p["mlp"]["w2"], h)
     return x + h, lk, lv
 
 
@@ -270,13 +270,7 @@ def init_paged_kv_cache(cfg: LMConfig, pool_blocks: int, block_tokens: int,
     """Preallocate the paged decode pool: per-layer K/V of shape
     ``(depth, pool_blocks, block_tokens, heads * head_dim)``: a row's heads
     side by side, so that the minor dimension fills the device's lanes and
-    a block is one contiguous run. Same MoE refusal as the ring cache — the
-    fixed-shape decode program cannot carry per-token dispatch state."""
-    if cfg.encoder.moe_experts > 0:
-        raise ValueError(
-            "KV-cached decode supports dense blocks only (moe_experts=0): "
-            "MoE top-k routing is per-token and the fixed-shape decode "
-            "program cannot carry its dispatch state in the cache")
+    a block is one contiguous run."""
     enc = cfg.encoder
     shape = (enc.depth, int(pool_blocks), int(block_tokens), enc.dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -659,3 +653,283 @@ def partition_specs(cfg: LMConfig) -> Params:
 
 def batch_spec() -> Any:
     return (P("data", "seq"), P("data", "seq"))
+
+
+# -- a stack of layer kinds: state-space, expert and attention layers ---------
+#
+# A hybrid language model's layer is ONE mixer: ``x <- x + mixer(RMSNorm(x))``
+# with the mixer a Mamba-2 scan (``M``, ops/mamba2.py), a sparse-expert
+# feed-forward (``E``, parallel/moe.py) or grouped-query attention without
+# positions (``*``, ops/attention.py), in the order ``pattern`` gives, each
+# layer with its own leaves. The decode cache is a group a kind of
+# state: paged keys and values ``(attention layers, blocks, tokens,
+# kv_heads * head_dim)`` for ``*`` behind the same block tables as the dense
+# model's pool, a fixed state a SLOT for ``M`` (``conv``: the convolution's
+# last inputs, ``h``: the SSM state, f32), nothing for ``E``. So the forward
+# is told which slot each sequence is: prefill continues the slot's state
+# from a chunk's ``start`` and starts from zero at ``start == 0``; a decode
+# row whose table is all sentinel (an idle or still-prefilling slot) moves no
+# state and reads no expert.
+
+@dataclass(frozen=True)
+class HybridConfig:
+    vocab: int = 256
+    max_len: int = 128
+    dim: int = 64
+    pattern: str = "MEM*E"
+    mamba: Any = None                 # ops.mamba2.Mamba2Config
+    q_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    n_experts: int = 8
+    top_k: int = 2
+    ffn: int = 32
+    shared_ffn: int = 64
+    route_scale: float = 2.5
+    held: Tuple[int, int] = (0, 8)    # (first, count) of the experts held
+    eps: float = 1e-5
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+
+HYBRID_KINDS = ("M", "E", "*")
+
+
+def hybrid_layer_init(rng: jax.Array, kind: str, cfg: HybridConfig,
+                      dtype=jnp.bfloat16) -> Params:
+    from rafiki_tpu.ops.attention import gqa_init
+    from rafiki_tpu.ops.mamba2 import mamba2_init
+
+    norm = core.rmsnorm_init(cfg.dim)
+    if kind == "M":
+        return {"norm": norm, **mamba2_init(rng, cfg.mamba, dtype)}
+    if kind == "*":
+        return {"norm": norm, **gqa_init(rng, cfg.dim, cfg.q_heads,
+                                         cfg.kv_heads, cfg.head_dim, dtype)}
+    if kind != "E":
+        raise ValueError(f"unknown layer kind {kind!r} in {cfg.pattern!r}")
+    kr, kb, ku, kd, su, sd = jax.random.split(rng, 6)
+    count = cfg.held[1]
+    into = cfg.dim ** -0.5  # by fan-in
+    return {
+        "norm": norm,
+        "router": core.normal_init(kr, (cfg.dim, cfg.n_experts), std=into),
+        "b_corr": core.normal_init(kb, (cfg.n_experts,)),
+        "w_up": core.normal_init(ku, (count, cfg.dim, cfg.ffn), std=into,
+                                 dtype=dtype),
+        "w_down": core.normal_init(kd, (count, cfg.ffn, cfg.dim),
+                                   std=cfg.ffn ** -0.5, dtype=dtype),
+        "s_up": core.normal_init(su, (cfg.dim, cfg.shared_ffn), std=into,
+                                 dtype=dtype),
+        "s_down": core.normal_init(sd, (cfg.shared_ffn, cfg.dim),
+                                   std=cfg.shared_ffn ** -0.5, dtype=dtype),
+    }
+
+
+def hybrid_layers(layers: list) -> Params:
+    """Per-layer parameter trees, in the pattern's order, as the tree the
+    forward reads: keyed ``"00"``, ``"01"``, ... (a layer's kind is the
+    pattern's character at its index)."""
+    return {f"{l:02d}": p for l, p in enumerate(layers)}
+
+
+def hybrid_init(rng: jax.Array, cfg: HybridConfig,
+                dtype=jnp.bfloat16) -> Params:
+    keys = jax.random.split(rng, len(cfg.pattern) + 2)
+    layers = [hybrid_layer_init(k, kind, cfg, dtype)
+              for k, kind in zip(keys, cfg.pattern)]
+    return {"embed": {"table": core.normal_init(
+                keys[-2], (cfg.vocab, cfg.dim), dtype=dtype)},
+            "head": core.normal_init(keys[-1], (cfg.vocab, cfg.dim),
+                                     std=cfg.dim ** -0.5, dtype=dtype),
+            "norm_f": core.rmsnorm_init(cfg.dim),
+            "layers": hybrid_layers(layers)}
+
+
+def init_hybrid_cache(cfg: HybridConfig, pool_blocks: int, block_tokens: int,
+                      max_slots: int, kv_dtype=jnp.bfloat16) -> Cache:
+    """The cache's groups: ``k``/``v`` the attention layers' paged pool,
+    ``conv``/``h`` every Mamba layer's state for each of ``max_slots``."""
+    from rafiki_tpu.ops.mamba2 import mamba2_state_init
+
+    kv = (cfg.count("*"), int(pool_blocks), int(block_tokens),
+          cfg.kv_heads * cfg.head_dim)
+    state = mamba2_state_init(cfg.mamba, int(max_slots))
+    n_m = cfg.count("M")
+    return {"k": jnp.zeros(kv, kv_dtype), "v": jnp.zeros(kv, kv_dtype),
+            **{name: jnp.zeros((n_m,) + a.shape, a.dtype)
+               for name, a in state.items()}}
+
+
+def hybrid_state_bytes(cache: Cache) -> int:
+    """Bytes of the per-slot recurrent state (not the paged pool)."""
+    return int(cache["conv"].nbytes + cache["h"].nbytes)
+
+
+def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
+                    positions: jax.Array, block_tables: jax.Array,
+                    slots: jax.Array, lengths: jax.Array, reset: jax.Array,
+                    cfg: HybridConfig
+                    ) -> Tuple[jax.Array, Cache, Dict[str, jax.Array]]:
+    """ids/positions (B, T), block_tables (B, NB), slots (B,) the state rows
+    of the sequences, lengths (B,) how many of the T tokens are real (0: an
+    idle row, which moves no state), reset (B,) bool: start from a zero
+    state. Returns (x (B, T, D) f32 before the last norm, cache, counts of
+    the expert layers summed over them)."""
+    from rafiki_tpu.ops.attention import gqa_cached
+    from rafiki_tpu.ops.mamba2 import mamba2_mixer
+    from rafiki_tpu.parallel.moe import expert_layer, ffn
+
+    b, t = ids.shape
+    nbpool, bt = cache["k"].shape[1], cache["k"].shape[2]
+    nb = block_tables.shape[1]
+    phys = jnp.take_along_axis(
+        block_tables, jnp.clip(positions // bt, 0, nb - 1), axis=1)
+    phys = jnp.where(positions < nb * bt, phys, nbpool)  # drop the pads
+    off = positions % bt
+    batch_ix = jnp.arange(b)[:, None]
+    live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(b * t)
+    x = jnp.take(params["embed"]["table"], ids, axis=0).astype(jnp.float32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def mamba(p, x, cache, l):
+        with jax.named_scope("ssm"):
+            u = core.rmsnorm(p["norm"], x, cfg.eps)
+            state = {name: jnp.where(
+                reset.reshape((b,) + (1,) * (cache[name].ndim - 2)), 0.0,
+                cache[name][l, slots]) for name in ("conv", "h")}
+            out, state = mamba2_mixer(p, u, state, lengths, cfg.mamba)
+            cache = {**cache, **{name: cache[name].at[l, slots].set(
+                state[name]) for name in ("conv", "h")}}
+            x = x + out
+        return x, cache
+
+    def attention(p, x, cache, l):
+        with jax.named_scope("attn"):
+            u = core.rmsnorm(p["norm"], x, cfg.eps).astype(p["wq"].dtype)
+            proj = lambda w: jnp.dot(u, w,
+                                     preferred_element_type=jnp.float32)
+            kv_dt = cache["k"].dtype
+            q = proj(p["wq"]).astype(kv_dt).reshape(
+                b, t, cfg.q_heads, cfg.head_dim)
+            k, v = proj(p["wk"]).astype(kv_dt), proj(p["wv"]).astype(kv_dt)
+            view = (b, nb * bt, cfg.kv_heads, cfg.head_dim)
+            lk = cache["k"].at[l, block_tables].get(mode="clip").reshape(view)
+            lv = cache["v"].at[l, block_tables].get(mode="clip").reshape(view)
+            rows = (b, t, cfg.kv_heads, cfg.head_dim)
+            lk = lk.at[batch_ix, positions].set(k.reshape(rows))
+            lv = lv.at[batch_ix, positions].set(v.reshape(rows))
+            o = gqa_cached(q, lk, lv, positions)
+            out = jnp.dot(o, p["wo"], preferred_element_type=jnp.float32)
+            # the rows come back out of the view, so that the pool's write
+            # follows its read and needs no copy (as `_paged_forward`)
+            k = jnp.take_along_axis(
+                lk, positions[:, :, None, None], axis=1).reshape(k.shape)
+            v = jnp.take_along_axis(
+                lv, positions[:, :, None, None], axis=1).reshape(v.shape)
+            cache = {**cache,
+                     "k": cache["k"].at[l, phys, off].set(k, mode="drop"),
+                     "v": cache["v"].at[l, phys, off].set(v, mode="drop")}
+            x = x + out
+        return x, cache
+
+    def experts(p, x, counts):
+        with jax.named_scope("moe"):
+            u = core.rmsnorm(p["norm"], x, cfg.eps).reshape(b * t, cfg.dim)
+            # the loop over the experts hit reads each one's two matrices
+            # in place: only the experts a token chose are touched
+            routed, c = expert_layer(
+                p, u, cfg.top_k, held=cfg.held, scale=cfg.route_scale,
+                live=live, gather=True)
+            out = routed + ffn(u, p["s_up"], p["s_down"])
+            counts = {name: counts[name] + c[name] for name in counts}
+            x = x + out.reshape(b, t, cfg.dim)
+        return x, counts
+
+    # The layers run in line, each with its own leaves. Under a `lax.scan`
+    # over the pattern's periods (leaves stacked by period) the TPU compiler
+    # copied the whole stack of W_up into the loop on every call (3.96 GB at
+    # 6 x 64 experts of 2688 x 1856; compiled for a described v5e, PR 27);
+    # 14 layers in line compile in 6 s.
+    counts = {"expert_tokens": zero, "experts_hit": zero}
+    at = {kind: 0 for kind in HYBRID_KINDS}  # the kind's cache row
+    for l, kind in enumerate(cfg.pattern):
+        p = params["layers"][f"{l:02d}"]
+        if kind == "M":
+            x, cache = mamba(p, x, cache, at[kind])
+        elif kind == "*":
+            x, cache = attention(p, x, cache, at[kind])
+        else:
+            x, counts = experts(p, x, counts)
+        at[kind] += 1
+    counts["expert_layers"] = jnp.asarray(cfg.count("E"), jnp.int32)
+    return x, cache, counts
+
+
+def _hybrid_head(params: Params, x: jax.Array, cfg: HybridConfig) -> jax.Array:
+    x = core.rmsnorm(params["norm_f"], x, cfg.eps).astype(
+        params["head"].dtype)
+    return jnp.einsum("...d,vd->...v", x, params["head"],
+                      preferred_element_type=jnp.float32)
+
+
+def hybrid_paged_prefill(params: Params, cache: Cache, block_table: jax.Array,
+                         ids: jax.Array, start: jax.Array, length: jax.Array,
+                         slot: jax.Array, cfg: HybridConfig,
+                         reset: Optional[jax.Array] = None
+                         ) -> Tuple[jax.Array, Cache]:
+    """:func:`paged_prefill` for a hybrid stack: the chunk continues
+    ``slot``'s recurrent state, from zero where ``start == 0`` (``reset``
+    overrides that, for a test of what a stale state does). Returns
+    (logits (V,) at the chunk's last real position, cache)."""
+    ids = jnp.asarray(ids, jnp.int32)[None]
+    start = jnp.asarray(start, jnp.int32)
+    length = jnp.asarray(length, jnp.int32)
+    positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+    reset = (start == 0) if reset is None else jnp.asarray(reset, bool)
+    x, cache, _ = _hybrid_forward(
+        params, cache, ids, positions,
+        jnp.asarray(block_table, jnp.int32)[None],
+        jnp.asarray(slot, jnp.int32)[None], length[None], reset[None], cfg)
+    return _hybrid_head(params, x[0, length - 1], cfg), cache
+
+
+def hybrid_paged_decode_step(params: Params, cache: Cache, ids: jax.Array,
+                             positions: jax.Array, block_tables: jax.Array,
+                             cfg: HybridConfig
+                             ) -> Tuple[jax.Array, Cache,
+                                        Dict[str, jax.Array]]:
+    """:func:`paged_decode_step` for a hybrid stack: row i is slot i. A row
+    whose table is all sentinel keeps its state and chooses no expert.
+    Returns (logits (S, V), cache, the expert layers' counts)."""
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    s = block_tables.shape[0]
+    live = block_tables[:, 0] < cache["k"].shape[1]
+    x, cache, counts = _hybrid_forward(
+        params, cache, jnp.asarray(ids, jnp.int32)[:, None],
+        jnp.asarray(positions, jnp.int32)[:, None], block_tables,
+        jnp.arange(s, dtype=jnp.int32), live.astype(jnp.int32),
+        jnp.zeros((s,), bool), cfg)
+    return _hybrid_head(params, x[:, 0], cfg), cache, counts
+
+
+def hybrid_apply(params: Params, ids: jax.Array, cfg: HybridConfig
+                 ) -> jax.Array:
+    """ids (B, S) -> logits (B, S, V): the whole sequences at once from a
+    zero state, through a cache made for the call."""
+    ids = jnp.asarray(ids, jnp.int32)
+    b, s = ids.shape
+    cache = init_hybrid_cache(cfg, b, s, b, kv_dtype=params["head"].dtype)
+    x, _, _ = _hybrid_forward(
+        params, cache, ids, jnp.broadcast_to(jnp.arange(s), (b, s)),
+        jnp.arange(b, dtype=jnp.int32)[:, None], jnp.arange(b),
+        jnp.full((b,), s, jnp.int32), jnp.ones((b,), bool), cfg)
+    return _hybrid_head(params, x, cfg)
+
+
+def copy_hybrid_kv_blocks(cache: Cache, src: jax.Array,
+                          dst: jax.Array) -> Cache:
+    """:func:`copy_kv_blocks` over the paged group; the state has no blocks."""
+    return {**cache, **copy_kv_blocks({"k": cache["k"], "v": cache["v"]},
+                                      src, dst)}

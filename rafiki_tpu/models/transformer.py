@@ -42,8 +42,8 @@ class TransformerConfig:
     # (ops/attention.py FLASH_SCORES_BYTES); XLA's fused attention is
     # faster below that
     use_flash: Optional[bool] = None
-    moe_experts: int = 0  # >0 replaces the MLP with an expert-parallel MoE
-    moe_capacity_factor: float = 1.25
+    # >0 replaces the MLP with an expert-parallel MoE (top-1, drop-free)
+    moe_experts: int = 0
     # "ring" routes attention through parallel/ring.py when the current mesh
     # has a seq axis > 1: exact attention with k/v shards rotating over ICI,
     # sequence length scaling linearly in chips. None = GSPMD seq-sharding
@@ -129,7 +129,7 @@ def block_apply(params: Params, x: jax.Array, cfg: TransformerConfig,
     h = core.layernorm(params["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe_experts > 0:
-        h, aux = moe_apply(params["moe"], h, cfg.moe_capacity_factor)
+        h, aux = moe_apply(params["moe"], h)
     else:
         h = core.dense(params["mlp"]["w1"], h)
         h = jax.nn.gelu(h)

@@ -116,3 +116,37 @@ def multi_head_attention(params: Params, x: jax.Array,
         o = mha_reference(q, k, v, causal=causal)
     out = jnp.einsum("bhsk,hkd->bsd", o, params["wo"].astype(dt))
     return out + params["bo"].astype(dt)
+
+
+def gqa_init(rng: jax.Array, dim: int, q_heads: int, kv_heads: int,
+             head_dim: int, dtype=jnp.float32) -> Params:
+    """Grouped-query projections with no bias, heads side by side in the
+    minor axis; `q_heads * head_dim` need not be `dim`."""
+    kq, kk, kv, ko = jax.random.split(rng, 4)
+    q, kvd = q_heads * head_dim, kv_heads * head_dim
+    into = dim ** -0.5  # by fan-in
+    return {"wq": core.normal_init(kq, (dim, q), std=into, dtype=dtype),
+            "wk": core.normal_init(kk, (dim, kvd), std=into, dtype=dtype),
+            "wv": core.normal_init(kv, (dim, kvd), std=into, dtype=dtype),
+            "wo": core.normal_init(ko, (q, dim), std=q ** -0.5,
+                                   dtype=dtype)}
+
+
+def gqa_cached(q: jax.Array, lk: jax.Array, lv: jax.Array,
+               positions: jax.Array) -> jax.Array:
+    """Grouped-query attention of new tokens over a cache view, with no
+    position encoding. ``q`` (B, T, Hq, Dh); ``lk``/``lv`` (B, L, Hkv, Dh)
+    hold the new tokens' rows already; a query at ``positions[b, i]``
+    attends rows up to its own. Query head ``h`` reads key/value head
+    ``h // (Hq // Hkv)``. Returns (B, T, Hq * Dh) in q's dtype; softmax
+    statistics in f32."""
+    b, t, hq, dh = q.shape
+    hkv = lk.shape[2]
+    qg = q.reshape(b, t, hkv, hq // hkv, dh)
+    s = jnp.einsum("btgrk,blgk->bgrtl", qg, lk.astype(q.dtype),
+                   preferred_element_type=jnp.float32) / math.sqrt(dh)
+    mask = jnp.arange(lk.shape[1])[None, None, :] <= positions[:, :, None]
+    s = jnp.where(mask[:, None, None], s, -1e30)
+    a = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    o = jnp.einsum("bgrtl,blgk->btgrk", a, lv.astype(q.dtype))
+    return o.reshape(b, t, hq * dh)

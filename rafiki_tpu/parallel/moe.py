@@ -1,26 +1,145 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Mixture-of-experts feed-forward layer, drop-free, with expert parallelism.
 
-Switch-style top-1 routing with a static capacity: tokens are dispatched to
-experts through one-hot einsums (dense dispatch — static shapes, no gathers,
-exactly what XLA tiles well), experts are sharded over the ``expert`` mesh
-axis, and GSPMD turns the dispatch/combine einsums into the all-to-alls.
-Returns the load-balancing auxiliary loss (Switch Transformer eq. 4) so the
-trainer can add it to the objective.
+One layer for every use: the router scores all experts (softmax, or
+sigmoid with a correction bias that moves the choice and not the weight),
+each token takes its top ``k``, the weights are renormalised and scaled as
+the model says, and **no token is dropped**: there is no capacity. A layer
+is told which experts it holds, ``held = (first, count)``: it routes over
+all of them and computes the held ones' part of the result (this chip's
+share of an expert-parallel deployment; what the absent experts would add
+is left out, and no code stands in for their exchange). A shared expert,
+where the model has one, is every chip's alike and is added by the caller.
+
+The experts' products come in two forms with one result. ``dense``: every
+held expert over every token, weighted by the gates, as one batched product
+(static shapes; what GSPMD partitions over the ``expert`` mesh axis for
+training). ``gather``: a loop over the experts that a token chose, reading
+only those experts' weights in place, which is what serving wants: a decode
+round of 16 tokens at 6 of 128 hits about half of 64 held experts, and the
+weights are most of the round's bytes (a prefill chunk hits them all and
+still reads each once).
+
+``moe_apply`` is the ``k = 1`` softmax case with the Switch load-balancing
+loss (Fedus et al. 2021, eq. 4) for the trainer.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from rafiki_tpu.models.core import normal_init
+from rafiki_tpu.models.core import normal_init, relu2
 
 Params = Dict[str, Any]
+HIGHEST = jax.lax.Precision.HIGHEST
 
+
+def route(x: jax.Array, router: jax.Array, k: int, *,
+          bias: Optional[jax.Array] = None, score: str = "sigmoid",
+          renorm: bool = True, scale: float = 1.0
+          ) -> Tuple[jax.Array, jax.Array]:
+    """x (N, D) -> (gates (N, E), scores (N, E)), f32. ``gates[n, e]`` is
+    the weight of expert e for token n, 0 where the token did not choose it.
+    Scores in f32 at ``highest`` (a near tie decides an expert); the choice
+    is the top k of ``scores + bias``, the weight the score itself."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=HIGHEST)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    _, chosen = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)       # (N, k)
+    if renorm:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    hot = jax.nn.one_hot(chosen, scores.shape[-1], dtype=jnp.float32)
+    return jnp.sum(hot * (picked * scale)[..., None], axis=1), scores
+
+
+def expert_products(x: jax.Array, gates: jax.Array, w_up: jax.Array,
+                    w_down: jax.Array, *, b_up: Optional[jax.Array] = None,
+                    b_down: Optional[jax.Array] = None,
+                    act: Callable = relu2, gather: bool = False
+                    ) -> jax.Array:
+    """sum_e gates[:, e] * (act(x W_up[e] + b_up[e]) W_down[e] + b_down[e])
+    over the E' experts of ``gates``: x (N, D), gates (N, E'), w_up
+    (E', D, F), w_down (E', F, D) -> (N, D) f32. Operands in the weights'
+    dtype, accumulation in f32."""
+    xw = x.astype(w_up.dtype)
+    if not gather:
+        h = jnp.einsum("nd,edf->enf", xw, w_up,
+                       preferred_element_type=jnp.float32)
+        if b_up is not None:
+            h = h + b_up[:, None, :]
+        h = act(h) * gates.T[..., None]
+        y = jnp.einsum("enf,efd->nd", h.astype(w_down.dtype), w_down,
+                       preferred_element_type=jnp.float32)
+        if b_down is not None:
+            y = y + jnp.dot(gates, b_down.astype(jnp.float32))
+        return y
+    if b_up is not None or b_down is not None:
+        raise ValueError("the gathered form has no biases")
+    hit = jnp.any(gates > 0.0, axis=0)                        # (E',)
+    order = jnp.argsort(~hit, stable=True)                    # hit ones first
+
+    def one(i, acc):
+        e = order[i]
+        up = jax.lax.dynamic_index_in_dim(w_up, e, keepdims=False)
+        down = jax.lax.dynamic_index_in_dim(w_down, e, keepdims=False)
+        g = jax.lax.dynamic_index_in_dim(gates, e, axis=1, keepdims=False)
+        h = act(jnp.dot(xw, up, preferred_element_type=jnp.float32))
+        h = h * g[:, None]
+        return acc + jnp.dot(h.astype(down.dtype), down,
+                             preferred_element_type=jnp.float32)
+
+    return jax.lax.fori_loop(0, jnp.sum(hit), one,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def expert_layer(p: Params, x: jax.Array, k: int, *,
+                 held: Optional[Tuple[int, int]] = None,
+                 score: str = "sigmoid", renorm: bool = True,
+                 scale: float = 1.0, act: Callable = relu2,
+                 live: Optional[jax.Array] = None, gather: bool = False
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The routed part of the layer for the experts held here. ``p``:
+    ``router`` (D, E), ``b_corr`` (E,) or absent, ``w_up`` (count, D, F),
+    ``w_down`` (count, F, D), the held experts' own.
+    x (N, D) -> (y (N, D) f32, counts): ``expert_tokens`` the (token, held
+    expert) choices and ``experts_hit`` the held experts with a token.
+    ``live`` (N,) bool takes tokens out of the routing (an idle decode slot
+    reads no expert)."""
+    n_experts = p["router"].shape[-1]
+    first, count = held if held is not None else (0, n_experts)
+    gates, _ = route(x, p["router"], k, bias=p.get("b_corr"), score=score,
+                     renorm=renorm, scale=scale)
+    if live is not None:
+        gates = jnp.where(live[:, None], gates, 0.0)
+    gates = jax.lax.dynamic_slice_in_dim(gates, first, count, axis=1)
+    y = expert_products(x, gates, p["w_up"], p["w_down"], act=act,
+                        gather=gather)
+    chosen = gates > 0.0
+    return y, {"expert_tokens": jnp.sum(chosen, dtype=jnp.int32),
+               "experts_hit": jnp.sum(jnp.any(chosen, axis=0),
+                                      dtype=jnp.int32)}
+
+
+def ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
+        act: Callable = relu2) -> jax.Array:
+    """A plain ungated feed-forward (a shared expert): (N, D) -> f32."""
+    h = act(jnp.dot(x.astype(w_up.dtype), w_up,
+                    preferred_element_type=jnp.float32))
+    return jnp.dot(h.astype(w_down.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+
+
+# -- the trainer's layer: k = 1, softmax, GELU with biases -------------------
 
 def moe_init(rng: jax.Array, dim: int, hidden: int, n_experts: int) -> Params:
     kr, k1, k2 = jax.random.split(rng, 3)
@@ -45,39 +164,18 @@ def moe_partition_specs() -> Params:
     }
 
 
-def moe_apply(params: Params, x: jax.Array, capacity_factor: float = 1.25
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (y, aux_loss). Tokens over capacity are dropped
-    (residual connection carries them — standard Switch behavior)."""
+def moe_apply(params: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, D) -> (y, aux_loss): each token through its top-1 expert,
+    weighted by the router's softmax probability; none dropped."""
     b, s, d = x.shape
-    n_tok = b * s
-    xt = x.reshape(n_tok, d)
+    xt = x.reshape(b * s, d).astype(jnp.float32)
     n_exp = params["router"].shape[-1]
-    capacity = int(math.ceil(n_tok / n_exp * capacity_factor))
-
-    logits = jnp.dot(xt.astype(jnp.float32), params["router"])
-    gates = jax.nn.softmax(logits, axis=-1)          # (N, E)
-    expert = jnp.argmax(gates, axis=-1)              # (N,)
-    gate = jnp.take_along_axis(gates, expert[:, None], axis=-1)[:, 0]
-
-    exp_oh = jax.nn.one_hot(expert, n_exp, dtype=jnp.float32)  # (N, E)
-    # position of each token within its expert's queue
-    pos = jnp.cumsum(exp_oh, axis=0) * exp_oh - 1.0            # (N, E)
-    keep = (pos >= 0) & (pos < capacity)
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), capacity,
-                            dtype=jnp.float32) * keep[..., None]  # (N, E, C)
-
-    dispatch = pos_oh                                 # (N, E, C)
-    combine = dispatch * gate[:, None, None]          # (N, E, C)
-
-    xe = jnp.einsum("nec,nd->ecd", dispatch, xt.astype(jnp.float32))
-    he = jax.nn.gelu(
-        jnp.einsum("ecd,edh->ech", xe, params["w1"]) + params["b1"][:, None, :])
-    ye = jnp.einsum("ech,ehd->ecd", he, params["w2"]) + params["b2"][:, None, :]
-    y = jnp.einsum("nec,ecd->nd", combine, ye)
-
+    gates, probs = route(xt, params["router"], 1, score="softmax",
+                         renorm=False)
+    y = expert_products(xt, gates, params["w1"], params["w2"],
+                        b_up=params["b1"], b_down=params["b2"],
+                        act=jax.nn.gelu)
     # Switch load-balancing loss: E * sum_e f_e * p_e
-    frac_tokens = jnp.mean(exp_oh, axis=0)
-    frac_router = jnp.mean(gates, axis=0)
-    aux = n_exp * jnp.sum(frac_tokens * frac_router)
+    frac_tokens = jnp.mean((gates > 0.0).astype(jnp.float32), axis=0)
+    aux = n_exp * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
     return y.reshape(b, s, d).astype(x.dtype), aux

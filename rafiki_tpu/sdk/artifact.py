@@ -49,11 +49,29 @@ class ArtifactCorruptError(Exception):
         self.detail = detail
 
 
+def _header(payload) -> bytes:
+    return _HEADER.pack(MAGIC, VERSION, zlib.crc32(payload) & 0xFFFFFFFF,
+                        len(payload))
+
+
 def wrap(payload: bytes) -> bytes:
     """Frame ``payload`` with the checksummed header."""
-    return _HEADER.pack(MAGIC, VERSION,
-                        zlib.crc32(payload) & 0xFFFFFFFF,
-                        len(payload)) + payload
+    return _header(payload) + payload
+
+
+def _verify(header: bytes, payload, path: str) -> None:
+    """Raise unless ``payload`` is what the frame's ``header`` says."""
+    _, version, crc, length = _HEADER.unpack(header)
+    if version != VERSION:
+        raise ArtifactCorruptError(
+            path, f"unknown artifact frame version {version}")
+    if len(payload) != length:
+        raise ArtifactCorruptError(
+            path, f"payload is {len(payload)} bytes, header says {length} "
+                  "(truncated or half-written)")
+    if zlib.crc32(payload) & 0xFFFFFFFF != crc:
+        raise ArtifactCorruptError(path, "checksum mismatch (bit rot or "
+                                         "torn write)")
 
 
 def unwrap(data: bytes, path: str = "<bytes>") -> bytes:
@@ -72,31 +90,25 @@ def unwrap(data: bytes, path: str = "<bytes>") -> bytes:
     if len(data) < HEADER_SIZE:
         raise ArtifactCorruptError(
             path, f"truncated inside the header ({len(data)} bytes)")
-    magic, version, crc, length = _HEADER.unpack_from(data)
     payload = data[HEADER_SIZE:]
-    if version != VERSION:
-        raise ArtifactCorruptError(
-            path, f"unknown artifact frame version {version}")
-    if len(payload) != length:
-        raise ArtifactCorruptError(
-            path, f"payload is {len(payload)} bytes, header says {length} "
-                  "(truncated or half-written)")
-    if zlib.crc32(payload) & 0xFFFFFFFF != crc:
-        raise ArtifactCorruptError(path, "checksum mismatch (bit rot or "
-                                         "torn write)")
+    _verify(data[:HEADER_SIZE], payload, path)
     return payload
 
 
 def atomic_write_bytes(path: str, data: bytes,
-                       mode: int | None = None) -> None:
-    """Write ``data`` to ``path`` via tmp + fsync + rename. Readers only
-    ever observe the previous complete file or the new complete file."""
+                       mode: int | None = None, *, head: bytes = b"") -> None:
+    """Write ``head + data`` to ``path`` via tmp + fsync + rename (the two
+    are written one after the other: a frame's header goes before gigabytes
+    of payload without a copy of both). Readers only ever observe the
+    previous complete file or the new complete file."""
     directory = os.path.dirname(os.path.abspath(path)) or "."
     fd, tmp = tempfile.mkstemp(dir=directory,
                                prefix=os.path.basename(path) + ".",
                                suffix=".tmp")
     try:
         with os.fdopen(fd, "wb") as f:
+            if head:
+                f.write(head)
             f.write(data)
             f.flush()
             os.fsync(f.fileno())
@@ -124,11 +136,18 @@ def atomic_write_bytes(path: str, data: bytes,
 def write_artifact(path: str, payload: bytes,
                    mode: int | None = None) -> None:
     """Atomically persist ``payload`` inside a checksummed frame."""
-    atomic_write_bytes(path, wrap(payload), mode=mode)
+    atomic_write_bytes(path, payload, mode=mode, head=_header(payload))
 
 
 def read_artifact(path: str) -> bytes:
     """Read and verify an artifact file; raises :class:`ArtifactCorruptError`
-    on checksum/length damage, passes legacy (un-framed) files through."""
+    on checksum/length damage, passes legacy (un-framed) files through. The
+    payload of a framed file is read into its own buffer, not sliced out of
+    a copy of the whole file (a model's parameters may be gigabytes)."""
     with open(path, "rb") as f:
-        return unwrap(f.read(), path=path)
+        head = f.read(HEADER_SIZE)
+        if len(head) < HEADER_SIZE or not head.startswith(MAGIC):
+            return unwrap(head + f.read(), path=path)
+        payload = f.read()
+    _verify(head, payload, path)
+    return payload

@@ -101,17 +101,34 @@ class GenerationSpec:
     methods on :class:`BaseModel` (``init_kv_cache``, ``prefill``,
     ``decode_step``); :func:`generation_capability` refuses specs whose
     methods are still the base stubs, so a half-wired template is a typed
-    deploy error instead of a mid-serving crash."""
+    deploy error instead of a mid-serving crash.
+
+    ``recurrent_state=True`` declares that a sequence also holds a fixed
+    state that every token it has seen went into (a state-space or linear
+    layer's), kept by SLOT beside the keys and values. The worker then tells
+    the paged methods which slot they serve (``init_paged_kv_cache(...,
+    max_slots)``, ``paged_prefill(..., start, slot)``); the model starts the
+    slot's state from zero at ``start == 0`` and continues it at
+    ``start > 0``, and leaves the state of a decode row whose table is all
+    sentinel as it was. Such a state cannot be shared, rewound or rolled
+    back, so for this model the worker serves no prefix-cache hit (each
+    counts as a miss), resumes a preempted stream from position 0, and
+    refuses at deploy a template that also wires sampling or speculative
+    verify (a sampled stream's first round replays the prompt's last token;
+    a rejected draft would have to leave the state)."""
 
     def __init__(self, eos_token_id: Optional[int] = None,
-                 max_context: int = 128):
+                 max_context: int = 128, recurrent_state: bool = False):
         self.eos_token_id = (None if eos_token_id is None
                              else int(eos_token_id))
         self.max_context = max(int(max_context), 2)
+        self.recurrent_state = bool(recurrent_state)
 
     def __repr__(self) -> str:
         return (f"GenerationSpec(eos_token_id={self.eos_token_id!r}, "
-                f"max_context={self.max_context})")
+                f"max_context={self.max_context}"
+                + (", recurrent_state=True" if self.recurrent_state else "")
+                + ")")
 
 
 class BaseModel(abc.ABC):
@@ -248,7 +265,9 @@ class BaseModel(abc.ABC):
         co-resident streams are then bound by *used* tokens, not
         ``slots x max_context`` — and gain shared-prefix caching and
         chunked prefill for free. Templates without them keep the ring
-        path unchanged."""
+        path unchanged. A template whose spec declares ``recurrent_state``
+        is called with a third argument, ``max_slots``, and keeps its
+        per-slot state in the same cache."""
         raise NotImplementedError
 
     def paged_prefill(self, cache: Any, block_table: Any,
@@ -259,7 +278,10 @@ class BaseModel(abc.ABC):
         are ``block_table`` (int32, fixed width, sentinel = pool size for
         unallocated entries). Returns ``(next_token_id, cache)`` — the
         token is only meaningful when this call covered the prompt's last
-        position (chunked prefill ignores intermediate returns)."""
+        position (chunked prefill ignores intermediate returns). A
+        template whose spec declares ``recurrent_state`` is called with a
+        fifth argument, ``slot``: the chunk continues that slot's state,
+        from zero where ``start == 0``."""
         raise NotImplementedError
 
     def paged_decode_step(self, cache: Any, ids: Any, positions: Any,
@@ -267,8 +289,16 @@ class BaseModel(abc.ABC):
         """One token for EVERY slot against the block pool:
         ``block_tables`` is (max_slots, table_blocks) int32 (idle slots
         carry all-sentinel rows). Same fixed-shape/one-program contract
-        as ``decode_step``."""
+        as ``decode_step``. May return a third value, a dict of small
+        arrays the decode program counted (``expert_tokens``,
+        ``experts_hit``, ``expert_layers``): the worker fetches it with
+        the tokens and adds it to the ``rafiki_gen_expert*`` counters."""
         raise NotImplementedError
+
+    def recurrent_state_bytes(self, cache: Any) -> int:
+        """Bytes of the per-slot recurrent state inside ``cache`` (the
+        ``rafiki_gen_state_bytes`` gauge); 0 for a model that has none."""
+        return 0
 
     def kv_copy_blocks(self, cache: Any, src: Any, dst: Any) -> Any:
         """Copy whole pool pages ``src[i] -> dst[i]`` — the allocator's

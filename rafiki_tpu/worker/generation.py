@@ -199,6 +199,28 @@ def _metrics():
                 "rafiki_gen_spec_degraded_total",
                 "speculation degradations to plain decode (draft fault, "
                 "verify fault, capability mismatch)"),
+            "state_resets": REGISTRY.counter(
+                "rafiki_gen_state_resets_total",
+                "prefills from position 0 of a model that declares "
+                "recurrent state: a slot's state started from zero "
+                "(admissions, and resumes of preempted streams)"),
+            "state_bytes": REGISTRY.gauge(
+                "rafiki_gen_state_bytes",
+                "bytes of per-slot recurrent state the decode cache holds "
+                "beside its keys and values", ("service",)),
+            "expert_tokens": REGISTRY.counter(
+                "rafiki_gen_expert_tokens_total",
+                "(token, expert) choices that fell on an expert held here, "
+                "over the expert layers of every decode round"),
+            "experts_hit": REGISTRY.counter(
+                "rafiki_gen_experts_hit_total",
+                "held experts that a decode round's tokens chose, summed "
+                "over expert layers and rounds (whose weights a round "
+                "has to read)"),
+            "expert_layer_rounds": REGISTRY.counter(
+                "rafiki_gen_expert_layer_rounds_total",
+                "expert layers run by decode rounds (experts_hit over "
+                "this is the mean number hit a round a layer)"),
             "migrated": REGISTRY.counter(
                 "rafiki_gen_streams_migrated_total",
                 "unfinished streams handed back typed (MIGRATING) by a "
@@ -306,17 +328,30 @@ class GenerationWorker(InferenceWorker):
             max_slots = max(int(config.GEN_MAX_SLOTS), 1)
             self._alloc: Optional[PagedKVAllocator] = None
             self._chunk = 0
+            # a fixed state a slot beside the keys and values (sdk/model.py
+            # GenerationSpec): nothing of it can be shared, rewound or
+            # rolled back
+            self._recurrent = bool(spec.recurrent_state)
+            if self._recurrent:
+                self._refuse_unsound_for_state(type(model))
             paged_spec = paged_generation_capability(type(model))
             if bool(config.GEN_KV_PAGED) and paged_spec is not None:
                 block_tokens = max(int(config.GEN_KV_BLOCK_TOKENS), 1)
                 table_blocks = -(-int(spec.max_context) // block_tokens)
                 pool_blocks = (int(config.GEN_KV_POOL_BLOCKS)
                                or max_slots * table_blocks)
+                # a cached prefix holds keys and values and no state, so a
+                # recurrent model is served no hit: every admission counts
+                # as a miss and prefills from position 0
                 self._alloc = PagedKVAllocator(
                     pool_blocks, block_tokens, table_blocks,
-                    prefix_cache=bool(config.GEN_PREFIX_CACHE))
+                    prefix_cache=(bool(config.GEN_PREFIX_CACHE)
+                                  and not self._recurrent))
                 self._chunk = max(int(config.GEN_PREFILL_CHUNK), 0)
-                cache = model.init_paged_kv_cache(pool_blocks, block_tokens)
+                cache = (model.init_paged_kv_cache(pool_blocks, block_tokens,
+                                                   max_slots)
+                         if self._recurrent else
+                         model.init_paged_kv_cache(pool_blocks, block_tokens))
                 logger.info(
                     "generation worker %s: paged KV (%d blocks x %d "
                     "tokens, prefix cache %s, prefill chunk %d)",
@@ -325,6 +360,9 @@ class GenerationWorker(InferenceWorker):
                     self._chunk)
             else:
                 cache = model.init_kv_cache(max_slots)
+            if self._recurrent:
+                _metrics()["state_bytes"].labels(ctx.service_id).set(
+                    int(model.recurrent_state_bytes(cache)))
             self._init_spec(model, spec, max_slots, ctx)
             # pre-warm per-bucket prefill + decode programs under the
             # persistent compile cache, before ctx.ready(): a still-
@@ -451,6 +489,23 @@ class GenerationWorker(InferenceWorker):
             if model is not None:
                 model.destroy()
             set_device_grant(None)
+
+    @staticmethod
+    def _refuse_unsound_for_state(clazz: type) -> None:
+        """A template that declares recurrent state and also wires sampled
+        decode or speculative verify is refused at deploy: a sampled
+        stream's first round replays the prompt's last token (once more
+        into the state), and a rejected draft cannot leave it."""
+        wired = [what for what, cap in (
+            ("sampled decode", sampling_capability),
+            ("speculative verify", spec_verify_capability))
+            if cap(clazz) is not None]
+        if wired:
+            raise GenerationUnsupportedError(
+                f"{clazz.__name__} declares recurrent_state and wires "
+                f"{' and '.join(wired)}: a fixed per-slot state cannot be "
+                "rewound one token or rolled back past a rejected draft, "
+                "so such a template serves greedy decode only")
 
     # -- sampling + speculation setup ----------------------------------------
 
@@ -640,6 +695,8 @@ class GenerationWorker(InferenceWorker):
         t0 = time.monotonic()
         try:
             first_id, cache = model.prefill(cache, slot_ix, list(history))
+            if self._recurrent:
+                _metrics()["state_resets"].inc()
         except Exception as e:
             free.insert(0, slot_ix)
             logger.error("prefill failed in generation worker %s:\n%s",
@@ -869,9 +926,15 @@ class GenerationWorker(InferenceWorker):
         # asynchronous dispatch, and its device time lands under the next
         # gen.decode.device.
         with trace.span("gen.prefill_chunk"):
-            tok, cache = model.paged_prefill(
-                cache, self._alloc.table_row(slot_ix), list(chunk_tokens),
-                int(start))
+            args = (cache, self._alloc.table_row(slot_ix),
+                    list(chunk_tokens), int(start))
+            if self._recurrent:
+                # the chunk continues THIS slot's state; at 0 it starts anew
+                tok, cache = model.paged_prefill(*args, slot_ix)
+                if start == 0:
+                    _metrics()["state_resets"].inc()
+            else:
+                tok, cache = model.paged_prefill(*args)
             if end == n and slot.temperature <= 0.0:
                 tok = int(tok)
         slot.pending_from = end
@@ -1124,8 +1187,16 @@ class GenerationWorker(InferenceWorker):
                             self._sampling_arrays(slots, ROLE_TARGET,
                                                   only=live))
                 elif paged:
-                    next_ids, cache = model.paged_decode_step(
+                    # a third value is what the program counted (sdk/model.py
+                    # paged_decode_step), fetched with the tokens
+                    next_ids, cache, *counted = model.paged_decode_step(
                         cache, ids, positions, tables)
+                    if counted:
+                        import jax
+
+                        next_ids, counted = jax.device_get(
+                            (next_ids, counted[0]))
+                        self._count_experts(counted)
                 elif sampled:
                     next_ids, _probs, cache = model.decode_step_sampled(
                         cache, ids, positions,
@@ -1191,6 +1262,15 @@ class GenerationWorker(InferenceWorker):
                 if finished:
                     self._evict_slot(slots, i, reason)
         return cache
+
+    @staticmethod
+    def _count_experts(counted: Dict[str, object]) -> None:
+        m = _metrics()
+        for key, name in (("expert_tokens", "expert_tokens"),
+                          ("experts_hit", "experts_hit"),
+                          ("expert_layers", "expert_layer_rounds")):
+            if key in counted:
+                m[name].inc(int(counted[key]))
 
     def _fail_round(self, slots, ctx, cache):
         """A decode_step crash poisons the whole table (the cache may be

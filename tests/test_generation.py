@@ -73,12 +73,26 @@ def test_lm_prefill_decode_consistency():
     assert int(lm.greedy_token(lg2)) == toks[-1]
 
 
-def test_lm_kv_cache_refuses_moe():
+def test_lm_kv_cache_takes_expert_blocks():
+    """The refusal went with the capacity: a drop-free top-1 expert block
+    routes each token on its own, so the ring cache serves it and a decode
+    step tracks the full forward."""
+    import jax
+    import jax.numpy as jnp
+
     from rafiki_tpu.models import lm
 
-    cfg = lm.tiny(moe_experts=2)
-    with pytest.raises(ValueError, match="dense blocks only"):
-        lm.init_kv_cache(cfg, max_slots=2)
+    cfg = lm.tiny(vocab=64, max_len=16, dim=16, depth=1, heads=2,
+                  moe_experts=2)
+    params = lm.init(jax.random.PRNGKey(0), cfg)
+    ids = jnp.arange(6, dtype=jnp.int32) * 5 % 64
+    cache = lm.init_kv_cache(cfg, max_slots=2)
+    assert cache["k"].shape[:2] == (1, 2)
+    _, cache = lm.prefill(params, cache, 1, ids[:5], 5, cfg)
+    logits, _ = lm.decode_step(params, cache, jnp.array([0, ids[5]]),
+                               jnp.array([0, 5]), cfg)
+    full, _ = lm.apply(params, ids[None], cfg)
+    assert np.abs(np.asarray(logits[1]) - np.asarray(full[0, 5])).max() < 0.05
 
 
 # -- data plane: TokenStream ------------------------------------------------

@@ -297,11 +297,25 @@ def test_paged_decode_program_keeps_no_whole_depth_view():
     assert temporaries < depth * slots * nb * bt * dim * 4, temporaries
 
 
-def test_paged_cache_refuses_moe():
+def test_paged_cache_takes_expert_blocks():
+    """The paged pool of a model with expert blocks: same planes as a
+    dense one's, and a prefill through it gives the ring path's logits."""
+    import jax
+    import jax.numpy as jnp
+
     from rafiki_tpu.models import lm
 
-    with pytest.raises(ValueError, match="dense blocks only"):
-        lm.init_paged_kv_cache(lm.tiny(moe_experts=2), 4, 8)
+    cfg = lm.tiny(vocab=64, max_len=32, dim=16, depth=2, heads=2,
+                  moe_experts=2)
+    params = lm.init(jax.random.PRNGKey(0), cfg)
+    pool = lm.init_paged_kv_cache(cfg, 4, 8)
+    assert pool["k"].shape == (2, 4, 8, 16)
+    ids = jnp.array([5, 9, 2, 7, 3, 0, 0, 0], jnp.int32)
+    ring = lm.init_kv_cache(cfg, max_slots=1, max_len=32)
+    lg_r, _ = lm.prefill(params, ring, 0, ids, 5, cfg)
+    lg_p, _ = lm.paged_prefill(params, pool, np.arange(4, dtype=np.int32),
+                               ids, 0, 5, cfg)
+    assert np.array_equal(np.asarray(lg_r), np.asarray(lg_p))
 
 
 # -- the allocator ------------------------------------------------------------

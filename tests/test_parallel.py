@@ -146,7 +146,7 @@ def test_moe_single_expert_equals_dense():
     dim, hidden = 8, 16
     params = moe_init(jax.random.key(0), dim, hidden, n_experts=1)
     x = jax.random.normal(jax.random.key(1), (2, 4, dim))
-    y, aux = moe_apply(params, x, capacity_factor=1.0)
+    y, aux = moe_apply(params, x)
     # with one expert the gate is 1 and MoE reduces to its dense FFN
     xt = x.reshape(-1, dim).astype(jnp.float32)
     href = jax.nn.gelu(xt @ params["w1"][0] + params["b1"][0])
@@ -156,16 +156,24 @@ def test_moe_single_expert_equals_dense():
     np.testing.assert_allclose(float(aux), 1.0, atol=1e-5)
 
 
-def test_moe_capacity_drops_overflow():
+def test_moe_top1_drops_no_token():
+    """The k = 1 case of the drop-free layer: with every token sent to one
+    expert (which a capacity of 2 of 8 used to cut), every token comes out
+    as that expert's feed-forward weighted by its gate."""
     dim, hidden, n_exp = 4, 8, 2
     params = moe_init(jax.random.key(0), dim, hidden, n_exp)
     # positive inputs + this router force every token to expert 0
     params["router"] = jnp.array([[10.0, -10.0]] * dim)
     x = jnp.abs(jax.random.normal(jax.random.key(1), (1, 8, dim))) + 0.1
-    y, _ = moe_apply(params, x, capacity_factor=0.5)  # capacity = 2 of 8
-    # overflowed tokens produce zero output (residual carries them)
+    y, aux = moe_apply(params, x)
     n_nonzero = int(jnp.sum(jnp.any(jnp.abs(y[0]) > 1e-9, axis=-1)))
-    assert n_nonzero == 2
+    assert n_nonzero == 8
+    href = jax.nn.gelu(x[0] @ params["w1"][0] + params["b1"][0])
+    yref = href @ params["w2"][0] + params["b2"][0]
+    np.testing.assert_allclose(np.asarray(y[0]), np.asarray(yref),
+                               atol=1e-4, rtol=1e-4)
+    # all tokens on one of two experts: the balance loss is at its worst
+    np.testing.assert_allclose(float(aux), 2.0, atol=1e-3)
 
 
 def test_gpipe_streamed_input_matches_sequential():
