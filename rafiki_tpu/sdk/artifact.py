@@ -12,7 +12,9 @@ This module is the single place artifact durability lives:
 
 - :func:`atomic_write_bytes` — tmp file in the target directory, flush +
   fsync, ``os.replace``: a crash mid-write leaves the old file (or
-  nothing), never a torn one;
+  nothing), never a torn one. The content is bytes or a sequence of
+  buffers streamed into the tmp file one by one (a trial's parameters,
+  ``sdk/params.py stream_params``: no copy of the whole is ever made);
 - :func:`wrap`/:func:`unwrap` — a small checksummed frame (magic +
   version + CRC32 + payload length) so damage is detected AT READ TIME
   and reported as the typed :class:`ArtifactCorruptError` instead of a
@@ -95,21 +97,39 @@ def unwrap(data: bytes, path: str = "<bytes>") -> bytes:
     return payload
 
 
-def atomic_write_bytes(path: str, data: bytes,
-                       mode: int | None = None, *, head: bytes = b"") -> None:
-    """Write ``head + data`` to ``path`` via tmp + fsync + rename (the two
-    are written one after the other: a frame's header goes before gigabytes
-    of payload without a copy of both). Readers only ever observe the
-    previous complete file or the new complete file."""
+def _buffers(data):
+    """``data`` as a sequence of buffers: bytes in hand are a sequence of
+    one; anything else already is one (``sdk/params.py stream_params``)."""
+    if isinstance(data, (bytes, bytearray, memoryview)):
+        return (data,)
+    return data
+
+
+def _atomic_write(path: str, data, mode: int | None, framed: bool) -> int:
+    """Stream ``data``'s buffers to ``path`` via tmp + fsync + rename, under
+    the checksummed frame if ``framed``; returns the bytes streamed. No
+    buffer of the whole is built: each buffer goes to the tmp file as it
+    comes, with the crc32 running and the length summed, behind a
+    placeholder that the real header then overwrites, before the flush and
+    fsync that make the file whole. A buffer (or the iteration) that raises
+    removes the tmp and leaves ``path`` as it was."""
     directory = os.path.dirname(os.path.abspath(path)) or "."
     fd, tmp = tempfile.mkstemp(dir=directory,
                                prefix=os.path.basename(path) + ".",
                                suffix=".tmp")
+    crc = length = 0
     try:
         with os.fdopen(fd, "wb") as f:
-            if head:
-                f.write(head)
-            f.write(data)
+            if framed:
+                f.write(bytes(HEADER_SIZE))
+            for buf in _buffers(data):
+                if framed:
+                    crc = zlib.crc32(buf, crc)
+                length += memoryview(buf).nbytes
+                f.write(buf)
+            if framed:
+                f.seek(0)
+                f.write(_HEADER.pack(MAGIC, VERSION, crc & 0xFFFFFFFF, length))
             f.flush()
             os.fsync(f.fileno())
         if mode is not None:
@@ -131,12 +151,23 @@ def atomic_write_bytes(path: str, data: bytes,
             os.close(dfd)
     except OSError:
         pass
+    return length
 
 
-def write_artifact(path: str, payload: bytes,
-                   mode: int | None = None) -> None:
-    """Atomically persist ``payload`` inside a checksummed frame."""
-    atomic_write_bytes(path, payload, mode=mode, head=_header(payload))
+def atomic_write_bytes(path: str, data, mode: int | None = None) -> None:
+    """Write ``data`` (bytes, or a sequence of buffers written one after
+    the other) to ``path`` via tmp + fsync + rename. Readers only ever
+    observe the previous complete file or the new complete file."""
+    _atomic_write(path, data, mode, framed=False)
+
+
+def write_artifact(path: str, payload, mode: int | None = None) -> int:
+    """Atomically persist ``payload`` inside a checksummed frame; returns
+    the payload's length. ``payload`` is bytes in hand (a checkpoint, a
+    sandbox child's parameters) or a sequence of buffers (a parameter
+    tree's stream, gigabytes that are never joined): the file is the same,
+    bit for bit."""
+    return _atomic_write(path, payload, mode, framed=True)
 
 
 def read_artifact(path: str) -> bytes:

@@ -39,9 +39,10 @@ from rafiki_tpu.sdk import compile_cache
 from rafiki_tpu.sdk.artifact import write_artifact
 from rafiki_tpu.sdk.log import ModelLogger, StopTrialEarly
 from rafiki_tpu.sdk.model import load_model_class, population_capability
-from rafiki_tpu.sdk.params import dump_params
+from rafiki_tpu.sdk.params import stream_params
 from rafiki_tpu.worker.vmap_partition import partition_for_vmap
 from rafiki_tpu.utils import chaos
+from rafiki_tpu.utils.metrics import REGISTRY
 from rafiki_tpu.utils.trace import Tracer, jax_profile
 from rafiki_tpu.worker import faults, warmup
 from rafiki_tpu.worker.faults import FaultKind, TrialChaosError, validate_score
@@ -61,6 +62,45 @@ EVENT_BUDGET_REACHED = "sub_train_job_budget_reached"
 EVENT_TRIAL_FAULT_LIMIT = "sub_train_job_fault_limit"
 
 EventFn = Callable[[str, Dict[str, Any]], None]
+
+
+def persist_trusted_params(tracer: Tracer, params_path: str,
+                           dump: Callable[[], Any]) -> None:
+    """Persist what ``dump`` returns (a trial's ``dump_parameters``, one
+    member's ``dump_member_parameters``) to ``params_path``: the one
+    sequence of the trusted paths, under the caller's ``persist_params``
+    span, on the trial's own thread. Its three depth-1 spans:
+    ``persist.dump`` around the template's dump (its fetch from the
+    device); ``persist.serialize`` around the host fetch of any leaf still
+    on the device and the building of the msgpack stream (``sdk/params.py``:
+    headers between views of the leaves' own memory; only a leaf that is
+    not C-contiguous is copied); ``persist.write`` around crc32, the write
+    and the two fsyncs (``sdk/artifact.py``: atomic and checksummed, so a
+    crash mid-write or later bit rot is a typed ArtifactCorruptError at
+    download or deploy, never a deserialize traceback), with the attributes
+    ``bytes`` and ``copied_bytes``. An ``OSError`` from the write is
+    trusted-side I/O (full disk, yanked volume), the platform's fault and
+    never the template's knobs: the INFRA TrialFault. Whatever ``dump``
+    raises is the template's."""
+    with tracer.span("persist.dump"):
+        params = dump()
+    with tracer.span("persist.serialize"):
+        buffers, copied = stream_params(params)
+    try:
+        with tracer.span("persist.write", copied_bytes=copied) as span:
+            span.attrs["bytes"] = written = write_artifact(
+                params_path, buffers)
+    except OSError as e:
+        raise faults.TrialFault(f"params persist failed: {e}",
+                                kind=FaultKind.INFRA) from e
+    REGISTRY.counter(
+        "rafiki_params_persist_bytes_total",
+        "parameter bytes the train worker's trusted persist wrote").inc(
+            written)
+    REGISTRY.counter(
+        "rafiki_params_persist_copied_bytes_total",
+        "of those, array bytes that were copied on the host first (leaves "
+        "not C-contiguous or outside the tree's dicts)").inc(copied)
 
 
 class TrainWorker:
@@ -790,13 +830,9 @@ class TrainWorker:
                         # member scalar (same id, no budget burn),
                         # user-class kinds error it with infeasible
                         # feedback.
-                        with tracer.span("persist.dump"):
-                            params = model.dump_member_parameters(i)
-                        with tracer.span("persist.serialize"):
-                            params_bytes = dump_params(params)
-                            del params
-                        with tracer.span("persist.write"):
-                            write_artifact(params_path, params_bytes)
+                        persist_trusted_params(
+                            tracer, params_path,
+                            lambda: model.dump_member_parameters(i))
                     except OSError as e:
                         results.append((tid, knobs, None, None,
                                         faults.TrialFault(
@@ -1125,21 +1161,8 @@ class TrainWorker:
             with tracer.span("persist_params"):
                 params_path = os.path.join(
                     self._params_dir, f"{trial_id}.params")
-                # atomic + checksummed (sdk/artifact.py) — see the
-                # sandboxed persist path for the rationale; trusted-side
-                # I/O failures (full disk) are typed INFRA, not USER
-                with tracer.span("persist.dump"):
-                    params = model.dump_parameters()
-                with tracer.span("persist.serialize"):
-                    params_bytes = dump_params(params)
-                    del params
-                try:
-                    with tracer.span("persist.write"):
-                        write_artifact(params_path, params_bytes)
-                except OSError as e:
-                    raise faults.TrialFault(
-                        f"params persist failed: {e}",
-                        kind=FaultKind.INFRA) from e
+                persist_trusted_params(tracer, params_path,
+                                       model.dump_parameters)
             # the trial is complete: its mid-trial checkpoint is dead weight
             self._cleanup_ckpt(trial_id)
             return score, params_path
