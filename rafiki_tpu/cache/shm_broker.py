@@ -14,8 +14,8 @@ Wire format (cache/wire.py): one **binary frame per request** each way —
 "results": [...], "errors": {...}}`` on the per-job response queue —
 ndarrays as raw bytes, decoded worker-side with zero-copy
 ``np.frombuffer`` views. The float→text→float tax of the old per-query
-JSON messages was the serving path's dominant CPU cost (BENCH_r05), not
-the model. Receivers *sniff* every popped message (binary magic vs JSON),
+JSON messages was the serving path's dominant CPU cost, not the model.
+Receivers *sniff* every popped message (binary magic vs JSON),
 so legacy per-query JSON peers interoperate; responses echo the format
 their query frame arrived in, and ``RAFIKI_WIRE_BINARY=0`` forces JSON
 framing on the submit side for a version-mismatched fleet. A listener

@@ -4,7 +4,7 @@ Every internal hop of the serving path (shm broker frames, the fleet
 HTTP relay) used to ride ``utils/jsonutil.py``, which turns each ndarray
 into float *text* (``tolist()``) — ~20 bytes and a float parse per
 element, which for a dense 3072-float query is the transport's CPU, not
-the model (BENCH_r05: the JSON door saturates at ~1/3 the binary door's
+the model (the JSON door saturated at about a third of the binary door's
 throughput on the same model). This module is the binary replacement:
 ndarrays travel as raw C-contiguous bytes behind a tiny JSON header and
 decode with **zero-copy** ``np.frombuffer`` views into the frame.
