@@ -66,17 +66,6 @@ class TransformerConfig:
     # GSPMD weight-sharding of the scanned depth axis.
     pipeline: Optional[str] = None
     n_microbatches: int = 4
-    # lax.scan unroll factor for the depth scan: >1 lets XLA fuse and
-    # software-pipeline across adjacent blocks (scan bodies compile once
-    # and cannot overlap otherwise) at the cost of unroll x compile time.
-    # Single-chip throughput knob; numerics identical.
-    scan_unroll: int = 1
-    # One (BS, D) x (D, 3HDh) matmul for the q/k/v projections (x read
-    # from HBM once per layer, one wide MXU gemm) instead of three —
-    # runtime weight stack, param layout/checkpoints/TP specs unchanged.
-    # Sweep lever (bench_models.py RAFIKI_SWEEP_QKV); same math, low-bit
-    # differences only from contraction order.
-    fused_qkv: bool = False
 
 
 def block_init(rng: jax.Array, cfg: TransformerConfig) -> Params:
@@ -124,7 +113,7 @@ def block_apply(params: Params, x: jax.Array, cfg: TransformerConfig,
             q, k, v, mesh, causal=causal)
     h = multi_head_attention(params["attn"], core.layernorm(params["ln1"], x),
                              causal=cfg.causal, use_flash=cfg.use_flash,
-                             attn_fn=attn_fn, fused_qkv=cfg.fused_qkv)
+                             attn_fn=attn_fn)
     x = x + core.dropout(r1, h, cfg.dropout, deterministic)
     h = core.layernorm(params["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
@@ -226,8 +215,7 @@ def stack_apply(stacked: Params, x: jax.Array, cfg: TransformerConfig,
         y, aux = block(layer, x, sub)
         return (y, key), aux
 
-    (x, _), auxs = jax.lax.scan(body, (x, rng), stacked,
-                                unroll=max(cfg.scan_unroll, 1))
+    (x, _), auxs = jax.lax.scan(body, (x, rng), stacked)
     return x, jnp.sum(auxs)
 
 

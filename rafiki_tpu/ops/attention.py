@@ -76,32 +76,20 @@ def attention_init(rng: jax.Array, dim: int, heads: int) -> Params:
 def multi_head_attention(params: Params, x: jax.Array,
                          causal: bool = False,
                          use_flash: Optional[bool] = None,
-                         attn_fn=None,
-                         fused_qkv: bool = False) -> jax.Array:
+                         attn_fn=None) -> jax.Array:
     """Self-attention over (B, S, D). ``use_flash=None`` auto-selects the
     pallas kernel once the (S, S) score tensors would crowd HBM (see
     FLASH_SCORES_BYTES — below that, XLA's fused attention is faster).
     ``attn_fn(q, k, v, causal)`` overrides the inner attention entirely
     (the seam ring attention plugs into — see models/transformer.py
-    seq_parallel). ``fused_qkv`` computes all three projections as ONE
-    (BS, D) x (D, 3HDh) matmul over runtime-stacked weights — x streams
-    from HBM once instead of three times per layer and the MXU sees one
-    wide gemm; param layout (and thus checkpoints/TP specs) is
-    unchanged. Whether XLA's dot-merger already gets this is
-    hardware-measured, not assumed — it is a sweep lever
-    (bench_models.py RAFIKI_SWEEP_QKV)."""
+    seq_parallel)."""
     from rafiki_tpu.ops.flash_attention import flash_attention
 
     b, s, d = x.shape
     dt = x.dtype
-    if fused_qkv:
-        wqkv = jnp.stack(
-            [params["wq"], params["wk"], params["wv"]], axis=0).astype(dt)
-        q, k, v = jnp.einsum("bsd,tdhk->tbhsk", x, wqkv)
-    else:
-        q = jnp.einsum("bsd,dhk->bhsk", x, params["wq"].astype(dt))
-        k = jnp.einsum("bsd,dhk->bhsk", x, params["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bhsk", x, params["wv"].astype(dt))
+    q = jnp.einsum("bsd,dhk->bhsk", x, params["wq"].astype(dt))
+    k = jnp.einsum("bsd,dhk->bhsk", x, params["wk"].astype(dt))
+    v = jnp.einsum("bsd,dhk->bhsk", x, params["wv"].astype(dt))
     n_heads = params["wq"].shape[1]
     scores_bytes = 4 * b * n_heads * s * s
     if attn_fn is not None:
