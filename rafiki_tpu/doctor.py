@@ -746,13 +746,14 @@ def check_int8_serving() -> Check:
     if os.environ.get("RAFIKI_SERVE_INT8") != "1":
         return ("int8 serving", PASS,
                 "off (default; measured 0.805x SLOWDOWN on the bench "
-                "matmul shapes, VERDICT r5 — enable only after "
-                "RAFIKI_BENCH_INT8=1 shows a win on YOUR shapes)")
+                "matmul shapes, VERDICT r5 — no cell measures it on the "
+                "chip (ROADMAP D3))")
     return ("int8 serving", WARN,
             "RAFIKI_SERVE_INT8=1: this path measured a 0.805x SLOWDOWN "
             "on the bench matmul shapes (VERDICT r5) — it also "
-            "quantizes trial-time evaluate. Re-verify with "
-            "RAFIKI_BENCH_INT8=1 (int8_unloaded_speedup > 1) or unset it; "
+            "quantizes trial-time evaluate. No cell measures it on the "
+            "chip (ROADMAP D3): unset it unless your own measurement "
+            "shows a win; "
             "docs/performance.md explains when int8 can still win")
 
 

@@ -26,8 +26,8 @@ in-graph dequantize costs more than the weight-stream saving returns.
 The numerics stay correct and test-bounded, and the path remains
 available for genuinely weight-bandwidth-bound models (large kernels,
 batch ~1) — but ``doctor`` WARNs while ``RAFIKI_SERVE_INT8=1`` is set
-and the bench phase is opt-in (``RAFIKI_BENCH_INT8=1``). See
-docs/performance.md for the full account.
+and no cell measures it on the chip (ROADMAP D3). See
+docs/performance.md.
 """
 
 from __future__ import annotations

@@ -36,10 +36,10 @@ export APP_SECRET="${APP_SECRET:-rafiki-tpu-dev-secret}"
 #   RAFIKI_PREDICTOR_PORTS=1  dedicated POST /predict port per inference
 #                             job (bind: RAFIKI_PREDICTOR_HOST)
 #   RAFIKI_SERVE_INT8=1       int8 weight-only serving for SDK-trainer
-#                             templates — RETIRED from the defaults:
-#                             measured a 0.805x SLOWDOWN on the bench
-#                             matmul shapes (VERDICT r5); doctor WARNs
-#                             while set (docs/performance.md)
+#                             templates — off by default: no cell
+#                             measures it on the chip (ROADMAP D3);
+#                             doctor WARNs while set
+#                             (docs/performance.md)
 #   RAFIKI_INSTALL_DEPS=1     provision model dependencies per set into
 #                             $RAFIKI_WORKDIR/deps (pip flags via
 #                             RAFIKI_PIP_ARGS, e.g. an offline mirror)
@@ -94,8 +94,7 @@ export APP_SECRET="${APP_SECRET:-rafiki-tpu-dev-secret}"
 # text; cross-hop request tracing is sampled at the predictor door and
 # rides queue entries / wire frames / the fleet relay:
 #   RAFIKI_METRICS=1                0 = registry writes become no-ops
-#                                   (/metrics exposes zeros; the bench
-#                                   overhead guard measures against this)
+#                                   (/metrics exposes zeros)
 #   RAFIKI_METRICS_RING_S=300       seconds of ~1 s-resolution history in
 #                                   the autoscaler ring series (queue
 #                                   depth, shed rate, EWMA wait)
@@ -225,7 +224,8 @@ export APP_SECRET="${APP_SECRET:-rafiki-tpu-dev-secret}"
 # streams are bound by USED tokens, shared prompt prefixes are prefilled
 # once, and long-prompt joins never stall resident streams:
 #   RAFIKI_GEN_KV_PAGED=1               0 = legacy contiguous ring per
-#                                       slot (the bench A/B baseline)
+#                                       slot (tier-1's bit-identity
+#                                       reference)
 #   RAFIKI_GEN_KV_BLOCK_TOKENS=16       K/V rows per pool page — the
 #                                       paging granularity (doctor WARNs
 #                                       outside 8..2048)
