@@ -558,6 +558,24 @@ def _drain(stream, timeout_s=30.0):
     return toks, reason
 
 
+def _spy_paged_calls(model):
+    """Wrap the model's paged prefill and decode so that each call is
+    noted, in the worker's own order: ("prefill", start) | ("decode", None)."""
+    events = []
+    op, od = model.paged_prefill, model.paged_decode_step
+
+    def spy_p(cache, bt, ids, start):
+        events.append(("prefill", int(start)))
+        return op(cache, bt, ids, start)
+
+    def spy_d(cache, ids, pos, bts):
+        events.append(("decode", None))
+        return od(cache, ids, pos, bts)
+
+    model.paged_prefill, model.paged_decode_step = spy_p, spy_d
+    return events
+
+
 def test_worker_paged_matches_ring_e2e(monkeypatch):
     """The scheduler-level half of the invariant: the same prompts served
     under the paged allocator (prefix sharing + COW + chunked prefill
@@ -691,18 +709,7 @@ def test_chunked_prefill_interleaves_with_decode(monkeypatch):
     monkeypatch.setenv("RAFIKI_GEN_PREFIX_CACHE", "0")
     monkeypatch.setenv("RAFIKI_GEN_PREFILL_CHUNK", "8")
     model = _tiny_model()
-    events = []
-    op, od = model.paged_prefill, model.paged_decode_step
-
-    def spy_p(cache, bt, ids, start):
-        events.append(("prefill", int(start)))
-        return op(cache, bt, ids, start)
-
-    def spy_d(cache, ids, pos, bts):
-        events.append(("decode", None))
-        return od(cache, ids, pos, bts)
-
-    model.paged_prefill, model.paged_decode_step = spy_p, spy_d
+    events = _spy_paged_calls(model)
     broker = InProcessBroker()
     worker, ctx, t = _start_worker(broker, model, job="joinjob")
     q = list(broker.get_worker_queues("joinjob").values())[0]
@@ -792,20 +799,9 @@ def test_long_prompt_join_intertoken_p95_within_budget(monkeypatch):
     monkeypatch.setenv("RAFIKI_GEN_KV_PAGED", "1")
     model = _tiny_model()
     context = model.generation_spec.max_context
-    events = []  # the worker's own order: ("prefill", start) | ("decode",)
-    op, od = model.paged_prefill, model.paged_decode_step
-
-    def spy_p(cache, bt, ids, start):
-        events.append(("prefill", int(start)))
-        return op(cache, bt, ids, start)
-
-    def spy_d(cache, ids, pos, bts):
-        events.append(("decode",))
-        return od(cache, ids, pos, bts)
-
-    model.paged_prefill, model.paged_decode_step = spy_p, spy_d
+    events = _spy_paged_calls(model)
     broker = InProcessBroker()
-    worker, ctx, t = _start_worker(broker, model, job="drilljob")
+    _, ctx, t = _start_worker(broker, model, job="drilljob")
     q = list(broker.get_worker_queues("drilljob").values())[0]
     rng = np.random.default_rng(3)
 
