@@ -254,10 +254,13 @@ def decode_step(params: Params, cache: Cache, ids: jax.Array,
 # once (the worker-side allocator, worker/kv_paging.py, owns refcounts and
 # copy-on-write; this layer is pure array math).
 #
-# Shapes stay fixed, and the pool is read and written in place
-# (``_paged_forward``): inside the layer scan, layer l gathers its own
-# blocks through the table into a ``(B, table_blocks*block_tokens, H, Dh)``
-# view, runs the SAME ``_cached_block`` as the ring path on it (so paged
+# A call's shapes are its table's: the view is as long as the table it is
+# handed is wide, so a caller chooses how far each program reads (the worker
+# hands a decode round the narrowest of a short ladder of widths that holds
+# its longest live sequence; one compiled program a width). The pool is read
+# and written in place (``_paged_forward``): inside the layer scan, layer l
+# gathers its own blocks through the table into a ``(B, NB*block_tokens, H,
+# Dh)`` view, runs the SAME ``_cached_block`` as the ring path on it (so paged
 # outputs are bit-identical given the same logical contents), and writes
 # ONLY the new rows into the pool; no view of all layers, and no copy of
 # the donated pool, is ever made. Sentinel table entries (>= pool size)
@@ -359,10 +362,13 @@ def paged_decode_step(params: Params, cache: Cache, ids: jax.Array,
                       positions: jax.Array, block_tables: jax.Array,
                       cfg: LMConfig) -> Tuple[jax.Array, Cache]:
     """Advance every slot one token against the pool: ``ids``/``positions``
-    (S,) int32, ``block_tables`` (S, NB) int32. Fixed shapes — one jitted
-    program serves the pool's whole lifetime; idle slots carry all-sentinel
-    table rows so their writes are dropped and their (ignored) outputs read
-    only clipped garbage."""
+    (S,) int32, ``block_tables`` (S, NB) int32. One jitted program for each
+    NB it is called with: NB need only cover every live row's position (a
+    write at or past ``NB * block_tokens`` is dropped), and a table cut to
+    fewer columns returns the same bits, since what lies past a row's
+    position is masked. Idle slots carry all-sentinel table rows so their
+    writes are dropped and their (ignored) outputs read only clipped
+    garbage."""
     ids = jnp.asarray(ids, jnp.int32)[:, None]                   # (S, 1)
     positions2 = jnp.asarray(positions, jnp.int32)[:, None]
     logits, cache = _paged_forward(params, cache, ids, positions2,

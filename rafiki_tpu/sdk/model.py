@@ -287,12 +287,18 @@ class BaseModel(abc.ABC):
     def paged_decode_step(self, cache: Any, ids: Any, positions: Any,
                           block_tables: Any) -> Tuple[Any, Any]:
         """One token for EVERY slot against the block pool:
-        ``block_tables`` is (max_slots, table_blocks) int32 (idle slots
-        carry all-sentinel rows). Same fixed-shape/one-program contract
-        as ``decode_step``. May return a third value, a dict of small
-        arrays the decode program counted (``expert_tokens``,
-        ``experts_hit``, ``expert_layers``): the worker fetches it with
-        the tokens and adds it to the ``rafiki_gen_expert*`` counters."""
+        ``block_tables`` is (max_slots, W) int32 (idle slots carry
+        all-sentinel rows). W varies between calls: the worker hands a
+        round the narrowest of a short ladder of widths (128 tokens'
+        blocks, doubling, up to the context's) that covers its longest
+        live sequence, and calls each width once, all rows idle, before
+        it admits a request. A ``jax.jit`` keyed by shape needs nothing
+        for that (one program a width); a template that lowers ahead of
+        time lowers one program a width. May return a third value, a
+        dict of small arrays the decode program counted
+        (``expert_tokens``, ``experts_hit``, ``expert_layers``): the
+        worker fetches it with the tokens and adds it to the
+        ``rafiki_gen_expert*`` counters."""
         raise NotImplementedError
 
     def recurrent_state_bytes(self, cache: Any) -> int:
@@ -327,7 +333,8 @@ class BaseModel(abc.ABC):
                                   positions: Any, block_tables: Any,
                                   sampling: Any) -> Tuple[Any, Any, Any]:
         """``paged_decode_step`` with the same in-graph sampled draw and
-        key discipline as ``decode_step_sampled``."""
+        key discipline as ``decode_step_sampled``; ``block_tables`` varies
+        in width as it does there (no width is called ahead of time)."""
         raise NotImplementedError
 
     def paged_verify_step(self, cache: Any, ids: Any, positions: Any,

@@ -63,9 +63,11 @@ _NO_ANNOTATION = contextlib.nullcontext()
 _annotation_cls: Any = None
 
 
-def annotation(name: str):
+def annotation(name: str, **attrs: Any):
     """A context manager that puts ``name`` into the open `jax.profiler`
-    session's trace for its duration. The class is looked up on first use
+    session's trace for its duration, ``attrs`` as the event's own
+    statistics beside it (the event keeps the bare name). The class is
+    looked up on first use
     in a process that has imported jax, never at import of this module
     and never by importing jax from here: a process without jax (the
     admin in process placement) has no session to write into and must not
@@ -80,7 +82,7 @@ def annotation(name: str):
         except ImportError:
             cls = False
         _annotation_cls = cls
-    return cls(name) if cls else _NO_ANNOTATION
+    return cls(name, **attrs) if cls else _NO_ANNOTATION
 
 
 _phase_hist = None
@@ -105,15 +107,17 @@ def phase_histogram():
 class span:
     """``with trace.span("gen.decode.device"):`` — a span for code that
     has no per-unit :class:`Tracer`: an :func:`annotation` of the name
-    plus one observation of `rafiki_worker_phase_seconds{phase=name}`."""
+    plus one observation of `rafiki_worker_phase_seconds{phase=name}`.
+    Keyword arguments go to the annotation alone."""
 
-    __slots__ = ("name", "_ann", "_t0")
+    __slots__ = ("name", "attrs", "_ann", "_t0")
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, **attrs: Any) -> None:
         self.name = name
+        self.attrs = attrs
 
     def __enter__(self) -> "span":
-        self._ann = annotation(self.name)
+        self._ann = annotation(self.name, **self.attrs)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self

@@ -9,7 +9,9 @@ top of the platform's existing data plane:
 
 - a **fixed-width slot table** (``RAFIKI_GEN_MAX_SLOTS``): the model's KV
   cache is preallocated for that many co-resident sequences, so one jitted
-  ``decode_step`` program serves the table for its whole lifetime;
+  ``decode_step`` program serves the table for its whole lifetime (under
+  the paged layout below, one program for each width of block table: a
+  short ladder, every rung run once before the first request is admitted);
 - per decode round the scheduler **pulls newly queued requests** from the
   same bounded ``WorkerQueue`` every serving hop already uses (deadline /
   expiry / depth-cap semantics preserved), prefills them into free slots,
@@ -27,7 +29,10 @@ Templates that also implement the paged methods (sdk/model.py
 (worker/kv_paging.py, ``RAFIKI_GEN_KV_PAGED``): a fixed pool of
 ``RAFIKI_GEN_KV_BLOCK_TOKENS``-sized pages plus per-slot block tables, so
 resident streams are bound by *used* tokens rather than
-``slots x max_context``. The paged path adds three levers the ring cannot
+``slots x max_context``. A decode round's block tables are as wide as its
+longest live sequence needs, not as the context (the narrowest rung of
+``kv_paging.table_ladder``), so the round gathers and masks no further than
+its sequences reach. The paged path adds three levers the ring cannot
 offer:
 
 - **shared prefix cache** (``RAFIKI_GEN_PREFIX_CACHE``): prompt-prefix
@@ -199,6 +204,12 @@ def _metrics():
                 "rafiki_gen_spec_degraded_total",
                 "speculation degradations to plain decode (draft fault, "
                 "verify fault, capability mismatch)"),
+            "table_blocks": REGISTRY.counter(
+                "rafiki_gen_decode_table_blocks",
+                "paged decode rounds by the width, in blocks, of the block "
+                "tables they were handed: the narrowest rung of the "
+                "worker's ladder that covers the round's longest live "
+                "sequence", ("blocks",)),
             "state_resets": REGISTRY.counter(
                 "rafiki_gen_state_resets_total",
                 "prefills from position 0 of a model that declares "
@@ -315,6 +326,7 @@ class GenerationWorker(InferenceWorker):
         from rafiki_tpu.utils.metrics import REGISTRY
 
         set_device_grant(ctx.chips)
+        t_start = time.monotonic()
         model = None
         queue = self._broker.register_worker(self._job_id, ctx.service_id)
         try:
@@ -369,9 +381,33 @@ class GenerationWorker(InferenceWorker):
             # compiling generation replica stays DEPLOYING/unroutable
             from rafiki_tpu.worker.warmup import run_warmup
 
-            run_warmup(ctx.service_id, self._job_id,
-                       [("warm_up", model.warm_up)])
+            # Every rung of the decode round's table ladder is run once,
+            # all rows idle, so that no width is first met, and compiled,
+            # under live streams. The deploy waits SERVICE_DEPLOY_TIMEOUT_S
+            # for this replica and the model's load may have taken most of
+            # it (40 of 60 s for 9 GB of weights on a v5e), so the rungs
+            # that do not fit the first half of that wait (a cold compile
+            # cache: seconds a rung; a warm one loads them all) are run
+            # after ready(), still before the first request is admitted.
+            unwarmed = (list(self._alloc.table_widths)
+                        if self._alloc is not None else [])
+
+            def warm_table_widths(until: Optional[float] = None):
+                nonlocal cache
+                while unwarmed and not ctx.stopping and (
+                        until is None or time.monotonic() < until):
+                    cache = self._idle_round(model, cache, max_slots,
+                                             unwarmed[0])
+                    del unwarmed[0]
+
+            run_warmup(
+                ctx.service_id, self._job_id,
+                [("warm_up", model.warm_up)]
+                + ([("decode_table_widths", lambda: warm_table_widths(
+                    t_start + float(config.SERVICE_DEPLOY_TIMEOUT_S) / 2))]
+                   if unwarmed else []))
             ctx.ready()
+            warm_table_widths()
             if self._report_stats is not None:
                 threading.Thread(
                     target=self._stats_reporter, args=(ctx,),
@@ -606,6 +642,33 @@ class GenerationWorker(InferenceWorker):
             tp[i] = np.float32(s.top_p)
         return {"seed": seed, "temperature": temp, "top_k": tk,
                 "top_p": tp, "role": int(role)}
+
+    # -- the decode round's block tables -------------------------------------
+
+    def _idle_round(self, model, cache, max_slots: int, width: int):
+        """One greedy decode round with every row idle, at table width
+        ``width``: what warms that rung's program before the worker admits
+        its first request. The rows are all sentinel, so the writes are
+        dropped, a recurrent model keeps its state and chooses no expert,
+        and the (donated) cache comes back as it went in. The sampled
+        program is not warmed: it compiles at first use, as it always has,
+        now once a rung."""
+        zeros = np.zeros(max_slots, np.int32)
+        tables = np.tile(self._alloc.idle_row(width), (max_slots, 1))
+        _, cache, *_ = model.paged_decode_step(cache, zeros, zeros, tables)
+        return cache
+
+    def _decode_tables(self, slots, live) -> np.ndarray:
+        """The round's (slots, width) block tables: ``width`` the narrowest
+        rung that holds the furthest position a ``live`` row writes this
+        round (a position past its table is dropped by the model, silently:
+        ``table_width`` refuses what no rung holds); other rows idle."""
+        alloc = self._alloc
+        width = alloc.table_width(
+            max(slots[i].position for i in live) + 1)
+        return np.stack([
+            alloc.table_row(i, width) if i in live else alloc.idle_row(width)
+            for i in range(len(slots))])
 
     # -- admission -----------------------------------------------------------
 
@@ -1169,17 +1232,17 @@ class GenerationWorker(InferenceWorker):
                        and any(s.temperature > 0.0 for _, s in active))
             live = set(i for i, _ in active)
             try:
-                tables = np.stack([
-                    self._alloc.table_row(i) if i in live
-                    else self._alloc.idle_row()
-                    for i in range(n)]) if paged else None
+                tables = self._decode_tables(slots, live) if paged else None
             # lint: absorb(_fail_round logs it and fails the streams typed)
             except Exception:
                 return self._fail_round(slots, ctx, cache)
+            attrs = {"table_blocks": tables.shape[1]} if paged else {}
+            if paged:
+                _metrics()["table_blocks"].labels(tables.shape[1]).inc()
         try:
             # the model call through the fetch of its tokens: the one span
             # under which JAX's own host events nest
-            with trace.span("gen.decode.device"):
+            with trace.span("gen.decode.device", **attrs):
                 if paged and sampled:
                     next_ids, _probs, cache = \
                         model.paged_decode_step_sampled(

@@ -42,6 +42,24 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 
+#: The narrowest table a decode round is handed, in tokens: the gathered
+#: view's token dimension then fills the 128 lanes of a TPU tile, and a
+#: narrower view would be padded back up to them.
+MIN_TABLE_TOKENS = 128
+
+
+def table_ladder(block_tokens: int, table_blocks: int) -> Tuple[int, ...]:
+    """The widths, in blocks, a decode round's table is cut to: the blocks
+    of ``MIN_TABLE_TOKENS``, doubling, up to and always including
+    ``table_blocks``. One compiled decode program a rung."""
+    rungs = []
+    width = -(-MIN_TABLE_TOKENS // block_tokens)
+    while width < table_blocks:
+        rungs.append(width)
+        width *= 2
+    return tuple(rungs) + (table_blocks,)
+
+
 class KVPoolExhaustedError(RuntimeError):
     """The pool cannot hold even one stream's working set — a typed
     stream-level error (the caller fails THAT stream; siblings and the
@@ -80,8 +98,11 @@ class PagedKVAllocator:
     """Block pool + per-slot tables + refcounted prefix cache.
 
     ``pool_blocks`` physical pages of ``block_tokens`` K/V rows each;
-    ``table_blocks`` is the fixed per-slot table width (ceil(max_context /
-    block_tokens)) so the jitted decode program's shapes never change.
+    ``table_blocks`` is the widest per-slot table (ceil(max_context /
+    block_tokens)): what prefill and verify are handed. A decode round is
+    handed the narrowest of ``table_widths`` (:func:`table_ladder`) that
+    covers its longest live sequence, so the jitted decode program has one
+    shape a rung and reads no further than the round's sequences reach.
     The sentinel id ``pool_blocks`` marks unallocated table entries —
     the model layer drops writes through it.
     """
@@ -96,6 +117,8 @@ class PagedKVAllocator:
         self.pool_blocks = int(pool_blocks)
         self.block_tokens = int(block_tokens)
         self.table_blocks = int(table_blocks)
+        self.table_widths = table_ladder(self.block_tokens,
+                                         self.table_blocks)
         self.sentinel = self.pool_blocks
         self.prefix_cache = bool(prefix_cache)
         self.max_tails_per_chain = int(max_tails_per_chain)
@@ -342,16 +365,34 @@ class PagedKVAllocator:
 
     # -- views ---------------------------------------------------------------
 
-    def table_row(self, slot: Any) -> np.ndarray:
-        """The slot's fixed-width table row, sentinel-padded — what the
-        jitted paged forwards consume."""
-        row = np.full(self.table_blocks, self.sentinel, np.int32)
-        t = self._tables[slot]
+    def table_width(self, tokens: int) -> int:
+        """The narrowest rung of ``table_widths`` whose view holds logical
+        positions ``0 .. tokens - 1``. The model layer DROPS a write at or
+        past its table's last position, silently, so a caller takes its
+        width from the furthest position it is about to write, plus one;
+        what no rung holds is refused here."""
+        need = self.blocks_for(tokens)
+        for width in self.table_widths:
+            if need <= width:
+                return width
+        raise KVPoolExhaustedError(
+            f"position {tokens - 1} is past the table "
+            f"({self.table_blocks} x {self.block_tokens} tokens)")
+
+    def table_row(self, slot: Any, width: Optional[int] = None) -> np.ndarray:
+        """The slot's table row, sentinel-padded — what the jitted paged
+        forwards consume: ``table_blocks`` wide, or cut to ``width`` (a
+        rung of ``table_widths``; blocks past it hold no position the
+        call's causal mask lets through)."""
+        width = self.table_blocks if width is None else width
+        row = np.full(width, self.sentinel, np.int32)
+        t = self._tables[slot][:width]
         row[:len(t)] = t
         return row
 
-    def idle_row(self) -> np.ndarray:
-        return np.full(self.table_blocks, self.sentinel, np.int32)
+    def idle_row(self, width: Optional[int] = None) -> np.ndarray:
+        return np.full(self.table_blocks if width is None else width,
+                       self.sentinel, np.int32)
 
     def refcounts(self) -> List[int]:
         return list(self._refs)

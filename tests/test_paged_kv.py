@@ -273,6 +273,98 @@ def test_paged_entry_points_bit_identical_to_ring(entry):
     _assert_pool_tracks_ring(pool, before, ring, tables, front)
 
 
+def _fixture(module):
+    sys.path.insert(0, HERE)
+    try:
+        return __import__(f"fixtures.{module}", fromlist=[module])
+    finally:
+        sys.path.pop(0)
+
+
+@pytest.mark.parametrize("width", [2, 4, 8, 16])
+@pytest.mark.parametrize("entry", ["dense", "dense_sampled", "hybrid"])
+def test_decode_over_a_cut_table_is_bit_identical(entry, width):
+    """A decode round whose tables are cut to 1x, 2x and 4x the blocks its
+    furthest write needs returns the full table's bits: logits (tokens and
+    distributions, sampled), the pool's planes and a recurrent model's
+    state. The fixtures' own models (context 64, here in blocks of 4), an
+    idle row, a row that maps a block past its position, and a pool of
+    noise: whatever is gathered beyond the cut is masked, and adds zeros."""
+    import jax
+    import jax.numpy as jnp
+
+    from rafiki_tpu.models import lm
+
+    bt, full, pool_blocks, slots = 4, 16, 24, 4
+    positions = np.array([5, 7, 3, 0], np.int32)   # need: 2 blocks
+    ids = np.array([9, 30, 2, 0], np.int32)
+    tables = np.full((slots, full), pool_blocks, np.int32)
+    tables[0, :3], tables[1, :2], tables[2, :1] = (4, 11, 6), (9, 2), (17,)
+    noise = lambda shape, k: jax.random.normal(jax.random.key(k), shape)
+    if entry == "hybrid":
+        cfg = _fixture("hybrid_gen_model").CFG
+        params = lm.hybrid_init(jax.random.key(0), cfg, dtype=jnp.float32)
+        cache = lm.init_hybrid_cache(cfg, pool_blocks, bt, slots,
+                                     kv_dtype=jnp.float32)
+        step = lambda t: lm.hybrid_paged_decode_step(
+            params, cache, ids, positions, t, cfg)
+    else:
+        model = _fixture("gen_model").TinyGenLM()
+        model.train(None)
+        cfg, params = model._cfg, model._params
+        cache = lm.init_paged_kv_cache(cfg, pool_blocks, bt)
+        sampling = {"seed": np.arange(slots, dtype=np.uint32) + 7,
+                    "temperature": np.array([0.0, 0.9, 1.3, 0.0], np.float32),
+                    "top_k": np.array([0, 8, 0, 0], np.int32),
+                    "top_p": np.array([1.0, 1.0, 0.8, 1.0], np.float32),
+                    "role": lm.ROLE_TARGET}
+        step = (lambda t: lm.paged_decode_step_sampled(
+            params, cache, ids, positions, t, sampling, cfg)) \
+            if entry == "dense_sampled" else (lambda t: lm.paged_decode_step(
+                params, cache, ids, positions, t, cfg))
+    cache = {name: noise(a.shape, i).astype(a.dtype)
+             for i, (name, a) in enumerate(sorted(cache.items()))}
+    want, got = step(tables), step(tables[:, :width])
+    same = jax.tree.map(lambda a, b: np.array_equal(np.asarray(a),
+                                                    np.asarray(b)), want, got)
+    assert all(jax.tree.leaves(same)), same
+    # and the round wrote its rows: the pool is not the one it was handed
+    new = want[-2] if entry == "hybrid" else want[-1]
+    assert not np.array_equal(np.asarray(new["k"]), np.asarray(cache["k"]))
+
+
+def test_decode_tables_take_the_next_rung_and_refuse_past_the_last():
+    """The model drops a write at `width * block_tokens`, silently. The
+    worker's tables for a row about to write there are a rung wider, and
+    past the widest rung the round is refused, typed."""
+    from rafiki_tpu.worker.generation import GenerationWorker, _Slot
+
+    alloc = PagedKVAllocator(pool_blocks=80, block_tokens=16,
+                             table_blocks=40)
+    assert alloc.table_widths == (8, 16, 32, 40)
+    assert PagedKVAllocator(8, 8, 8).table_widths == (8,)    # 64 tokens
+    assert PagedKVAllocator(8, 256, 4).table_widths == (1, 2, 4)
+    worker = GenerationWorker("widthjob", "trial1", db=None, broker=None)
+    worker._alloc = alloc
+    slot = _Slot(None, [1], 1, None, seq=1)
+    slots = [None, slot]
+    alloc.open_slot(1, [1])
+    for width, wider in zip(alloc.table_widths,
+                            alloc.table_widths[1:] + (None,)):
+        slot.position = width * 16 - 1          # the rung's last position
+        assert alloc.ensure_capacity(1, slot.position)
+        tables = worker._decode_tables(slots, {1})
+        assert tables.shape == (2, width)
+        assert (tables[0] == alloc.sentinel).all()
+        assert (tables[1] < alloc.sentinel).all()
+        slot.position = width * 16              # one past it
+        if wider is None:
+            with pytest.raises(KVPoolExhaustedError, match="past the table"):
+                worker._decode_tables(slots, {1})
+        else:
+            assert worker._decode_tables(slots, {1}).shape == (2, wider)
+
+
 def test_paged_decode_program_keeps_no_whole_depth_view():
     """The decode program gathers one layer's blocks at a time and updates
     the donated pool in place: compiled, its temporaries stay under ONE
@@ -503,18 +595,14 @@ class _Ctx:
         self.service_id = service_id
         self.chips = None
         self.stopping = False
+        self.is_ready = threading.Event()
 
     def ready(self):
-        pass
+        self.is_ready.set()
 
 
 def _tiny_model():
-    sys.path.insert(0, HERE)
-    try:
-        from fixtures.gen_model import TinyGenLM
-    finally:
-        sys.path.pop(0)
-    m = TinyGenLM()
+    m = _fixture("gen_model").TinyGenLM()
     m.train(None)
     return m
 
@@ -560,7 +648,8 @@ def _drain(stream, timeout_s=30.0):
 
 def _spy_paged_calls(model):
     """Wrap the model's paged prefill and decode so that each call is
-    noted, in the worker's own order: ("prefill", start) | ("decode", None)."""
+    noted, in the worker's own order: ("prefill", start) | ("decode", the
+    width of its tables in blocks, the furthest position it writes at)."""
     events = []
     op, od = model.paged_prefill, model.paged_decode_step
 
@@ -569,7 +658,7 @@ def _spy_paged_calls(model):
         return op(cache, bt, ids, start)
 
     def spy_d(cache, ids, pos, bts):
-        events.append(("decode", None))
+        events.append(("decode", int(np.shape(bts)[1]), int(np.max(pos))))
         return od(cache, ids, pos, bts)
 
     model.paged_prefill, model.paged_decode_step = spy_p, spy_d
@@ -612,6 +701,201 @@ def test_worker_paged_matches_ring_e2e(monkeypatch):
     assert worker2._alloc is None
     assert paged_out == ring_out
     assert paged_out[0] == paged_out[1]     # identical prompts, same stream
+
+
+# -- the decode round's table follows the longest live sequence ---------------
+
+_LONG_CONTEXT = 512     # at blocks of 16: rungs of 8, 16 and 32 blocks
+
+
+def _long_model(monkeypatch):
+    """TinyGenLM over a context of three rungs (the fixture's own is one)."""
+    from rafiki_tpu.models import lm
+    from rafiki_tpu.sdk import GenerationSpec
+
+    mod = _fixture("gen_model")
+    monkeypatch.setattr(mod, "_MAX_CONTEXT", _LONG_CONTEXT)
+    monkeypatch.setattr(mod, "_PREFILL_BUCKETS",
+                        (8, 16, 32, 64, 128, 256, _LONG_CONTEXT))
+
+    class LongGenLM(mod.TinyGenLM):
+        generation_spec = GenerationSpec(eos_token_id=None,
+                                         max_context=_LONG_CONTEXT)
+
+        def __init__(self, **knobs):
+            super().__init__(**knobs)
+            self._cfg = lm.tiny(vocab=64, max_len=_LONG_CONTEXT, dim=16,
+                                depth=1, heads=2)
+
+    model = LongGenLM()
+    model.train(None)
+    return model
+
+
+def _width_env(monkeypatch):
+    monkeypatch.setenv("RAFIKI_GEN_MAX_SLOTS", "2")
+    monkeypatch.setenv("RAFIKI_GEN_KV_BLOCK_TOKENS", "16")
+    monkeypatch.setenv("RAFIKI_GEN_PREFILL_CHUNK", "64")
+    monkeypatch.setenv("RAFIKI_GEN_MAX_TOKENS", "64")
+
+
+def _table_rounds():
+    from rafiki_tpu.utils.metrics import REGISTRY
+
+    metric = REGISTRY.get("rafiki_gen_decode_table_blocks")
+    return {} if metric is None else {
+        int(key[0]): child.value() for key, child in metric.children().items()}
+
+
+@pytest.fixture(scope="module")
+def crossing():
+    """One scenario, served once under the paged layout (calls, counter and
+    span attributes noted) and once under the ring: a stream of 120 + 20
+    tokens crosses the 128-token rung beside a short one, and a third short
+    one follows when both have left."""
+    from rafiki_tpu.cache.queue import InProcessBroker
+    from rafiki_tpu.utils import trace
+
+    rng = np.random.default_rng(11)
+    prompts = [[int(x) for x in rng.integers(1, 60, size=n)]
+               for n in (120, 5, 9)]
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        _width_env(mp)
+        annotated = []
+        real = trace.annotation
+
+        def spy_annotation(name, **attrs):
+            if name == "gen.decode.device":
+                annotated.append(attrs)
+            return real(name, **attrs)
+
+        mp.setattr(trace, "annotation", spy_annotation)
+        for paged in (True, False):
+            mp.setenv("RAFIKI_GEN_KV_PAGED", "1" if paged else "0")
+            model = _long_model(mp)
+            events = _spy_paged_calls(model) if paged else []
+            job = "crossjob" if paged else "crossring"
+            before = _table_rounds()
+            broker = InProcessBroker()
+            worker, ctx, t = _start_worker(broker, model, job=job)
+            q = list(broker.get_worker_queues(job).values())[0]
+            try:
+                assert ctx.is_ready.wait(60)
+                warmed = len(events)
+                del annotated[:]
+                futs = q.submit_many(
+                    [{"prompt_ids": p, "max_tokens": n}
+                     for p, n in zip(prompts[:2], (20, 12))],
+                    deadline=time.monotonic() + 30)
+                tokens = [_drain(f.result(30))[0] for f in futs]
+                tokens.append(_drain(_stream(q, prompts[2], 6))[0])
+            finally:
+                ctx.stopping = True
+                t.join(timeout=10)
+            if paged:
+                after = _table_rounds()
+                out.update(
+                    paged=tokens, alloc=worker._alloc,
+                    rounds=[e for e in events[warmed:] if e[0] == "decode"],
+                    counted={w: n - before.get(w, 0)
+                             for w, n in after.items()
+                             if n > before.get(w, 0)},
+                    annotated=[a.get("table_blocks") for a in annotated])
+            else:
+                out["ring"] = tokens
+    return out
+
+
+def test_decode_table_width_follows_the_longest_live_sequence(crossing):
+    """(a) Every round's tables are the narrowest rung that holds the
+    furthest position the round writes at: up a rung as the long stream
+    crosses 128 tokens, down again when it has left."""
+    alloc, rounds = crossing["alloc"], crossing["rounds"]
+    assert alloc.table_widths == (8, 16, 32)
+    for _, width, furthest in rounds:
+        assert width == alloc.table_width(furthest + 1), (width, furthest)
+    widths = [w for _, w, _ in rounds]
+    runs = [w for i, w in enumerate(widths) if i == 0 or widths[i - 1] != w]
+    assert runs == [8, 16, 8], widths
+    # 120 tokens prefilled, the first answer token with them: positions
+    # 120..127 fit the first rung, the last 11 rounds do not
+    assert widths.count(16) == 11
+
+
+def test_streams_equal_the_rings_across_the_crossing(crossing):
+    """(b) The tokens of every stream, the one that changed program
+    mid-answer among them, equal the contiguous ring's."""
+    assert [len(t) for t in crossing["paged"]] == [20, 12, 6]
+    assert crossing["paged"] == crossing["ring"]
+
+
+@pytest.mark.parametrize("deploy_timeout_s", [60.0, 0.0])
+def test_every_rung_is_warm_before_the_first_request(monkeypatch,
+                                                     deploy_timeout_s):
+    """(c) Between deploy and `ready` the worker runs one all-idle round a
+    rung, narrowest first, and the pool comes out as it went in; what the
+    deploy's wait has no time for (here: none of it) runs after `ready`,
+    still before the first request is admitted. Serving every rung
+    afterwards compiles nothing more."""
+    from rafiki_tpu import config
+    from rafiki_tpu.cache.queue import InProcessBroker
+
+    monkeypatch.setattr(config, "SERVICE_DEPLOY_TIMEOUT_S", deploy_timeout_s)
+    _width_env(monkeypatch)
+    monkeypatch.setenv("RAFIKI_GEN_KV_PAGED", "1")
+    model = _long_model(monkeypatch)
+    events = _spy_paged_calls(model)
+    broker = InProcessBroker()
+    worker, ctx, t = _start_worker(broker, model, job="warmjob")
+    q = list(broker.get_worker_queues("warmjob").values())[0]
+    try:
+        assert ctx.is_ready.wait(60)
+        idle_rounds = [("decode", w, 0) for w in (8, 16, 32)]
+        if deploy_timeout_s:
+            assert events == idle_rounds
+        rng = np.random.default_rng(5)
+        long_prompt = [int(x) for x in rng.integers(1, 60, size=250)]
+        toks, _ = _drain(_stream(q, [3, 1, 4], 4))
+        assert len(toks) == 4
+        assert events[:4] == idle_rounds + [("prefill", 0)]
+        programs = model._jit_paged_decode._cache_size()
+        assert programs == 3
+        toks, _ = _drain(_stream(q, long_prompt[:125], 8))
+        assert len(toks) == 8
+        toks, _ = _drain(_stream(q, long_prompt, 12))
+        assert len(toks) == 12
+        assert {e[1] for e in events[3:] if e[0] == "decode"} == {8, 16, 32}
+        assert model._jit_paged_decode._cache_size() == programs
+    finally:
+        ctx.stopping = True
+        t.join(timeout=10)
+    # an idle round writes nothing: a pool of noise comes back bit for bit
+    import jax
+
+    from rafiki_tpu.models import lm
+
+    pool = {name: jax.random.normal(jax.random.key(i), a.shape)
+            for i, (name, a) in enumerate(
+                lm.init_paged_kv_cache(model._cfg, 6, 16).items())}
+    out = worker._idle_round(model, pool, 2, 8)
+    assert all(np.array_equal(np.asarray(out[n]), np.asarray(pool[n]))
+               for n in pool)
+
+
+def test_counter_and_span_attribute_read_the_widths(crossing):
+    """(d) `rafiki_gen_decode_table_blocks{blocks}` counts the rounds at
+    each width and the `gen.decode.device` span carries it: both read what
+    the model was handed."""
+    from rafiki_tpu.utils.metrics import REGISTRY
+
+    widths = [w for _, w, _ in crossing["rounds"]]
+    assert crossing["annotated"] == widths
+    assert crossing["counted"] == {8: widths.count(8), 16: widths.count(16)}
+    exposition = REGISTRY.render()      # what the door's /metrics serves
+    for rung in ("8", "16"):
+        assert f'rafiki_gen_decode_table_blocks{{blocks="{rung}"}}' \
+            in exposition
 
 
 def test_worker_shared_prefix_pays_prefill_once(monkeypatch):
