@@ -106,6 +106,20 @@ def group_rmsnorm(scale: jax.Array, x: jax.Array, groups: int,
     return g.reshape(x.shape) * scale
 
 
+def carried_conv(window: jax.Array, x: jax.Array, kernel: jax.Array,
+                 lengths: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Causal depthwise convolution of ``x`` (B, T, C) continued from the
+    last ``K - 1`` inputs ``window`` (B, K-1, C), ``kernel`` (K, C), no
+    bias. Returns (the convolution (B, T, C), the window after each
+    sequence's ``lengths[b]`` real tokens: padding past them moves none)."""
+    k, t = kernel.shape[0], x.shape[1]
+    seen = jnp.concatenate([window, x], axis=1)            # (B, K-1+T, C)
+    conv = sum(seen[:, j:j + t] * kernel[j] for j in range(k))
+    after = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+        w, n, k - 1, axis=0))(seen, lengths)
+    return conv, after
+
+
 def relu2(x: jax.Array) -> jax.Array:
     """Squared ReLU."""
     return jnp.square(jax.nn.relu(x))

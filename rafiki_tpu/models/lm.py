@@ -664,18 +664,29 @@ def batch_spec() -> Any:
 # -- a stack of layer kinds: state-space, expert and attention layers ---------
 #
 # A hybrid language model's layer is ONE mixer: ``x <- x + mixer(RMSNorm(x))``
-# with the mixer a Mamba-2 scan (``M``, ops/mamba2.py), a sparse-expert
-# feed-forward (``E``, parallel/moe.py) or grouped-query attention without
-# positions (``*``, ops/attention.py), in the order ``pattern`` gives, each
-# layer with its own leaves. The decode cache is a group a kind of
-# state: paged keys and values ``(attention layers, blocks, tokens,
-# kv_heads * head_dim)`` for ``*`` behind the same block tables as the dense
-# model's pool, a fixed state a SLOT for ``M`` (``conv``: the convolution's
-# last inputs, ``h``: the SSM state, f32), nothing for ``E``. So the forward
-# is told which slot each sequence is: prefill continues the slot's state
-# from a chunk's ``start`` and starts from zero at ``start == 0``; a decode
-# row whose table is all sentinel (an idle or still-prefilling slot) moves no
-# state and reads no expert.
+# with the mixer one of five kinds, in the order ``pattern`` gives, each layer
+# with its own leaves (a published layer of a mixer and a feed-forward is two
+# entries of the pattern):
+#   ``M``  a Mamba-2 scan (ops/mamba2.py);
+#   ``D``  the gated delta rule (ops/gated_delta.py);
+#   ``*``  grouped-query attention without positions (ops/attention.py);
+#   ``G``  the same attention with RMSNorm over a head of queries and of keys,
+#          rotary positions on a head's first ``rotary_dim`` and a sigmoid gate
+#          on its output;
+#   ``E``  a sparse-expert feed-forward (parallel/moe.py), whose variant (the
+#          router's score, correction bias and scale, the activation, gated
+#          experts or not, the shared expert's own gate) the config states.
+# The decode cache is a group a stateful kind: paged keys and values
+# ``k``/``v`` ``(attention layers, blocks, tokens, kv_heads * head_dim)`` for
+# ``*`` and ``G`` together, behind the same block tables as the dense model's
+# pool; a fixed state a SLOT for ``M`` (``conv``: the convolution's last
+# inputs, ``h``: the SSM state) and for ``D`` (``delta_conv``: the same
+# window, ``delta_s``: a key-by-value matrix a head), all f32 and there only
+# where the pattern holds the kind; nothing for ``E``. So the forward is told
+# which slot each sequence is: prefill continues the slot's state from a
+# chunk's ``start`` and starts from zero at ``start == 0``; a decode row whose
+# table is all sentinel (an idle or still-prefilling slot) moves no state and
+# reads no expert.
 
 @dataclass(frozen=True)
 class HybridConfig:
@@ -683,54 +694,89 @@ class HybridConfig:
     max_len: int = 128
     dim: int = 64
     pattern: str = "MEM*E"
-    mamba: Any = None                 # ops.mamba2.Mamba2Config
+    mamba: Any = None                 # ops.mamba2.Mamba2Config, for ``M``
+    delta: Any = None                 # ops.gated_delta.GatedDeltaConfig, ``D``
     q_heads: int = 4
     kv_heads: int = 2
     head_dim: int = 16
+    rotary_dim: int = 0               # of a head, for ``G``
+    rope_theta: float = 1e4
     n_experts: int = 8
     top_k: int = 2
     ffn: int = 32
     shared_ffn: int = 64
+    route_score: str = "sigmoid"      # or "softmax"
+    route_bias: bool = True           # a correction bias moves the choice
     route_scale: float = 2.5
+    expert_act: str = "relu2"         # or "silu"
+    expert_gated: bool = False        # act(x W_gate) * (x W_up)
+    shared_gate: bool = False         # sigmoid(x w_s) * shared(x)
     held: Tuple[int, int] = (0, 8)    # (first, count) of the experts held
     eps: float = 1e-5
 
-    def count(self, kind: str) -> int:
-        return self.pattern.count(kind)
+    def count(self, kinds: str) -> int:
+        return sum(self.pattern.count(kind) for kind in kinds)
 
 
-HYBRID_KINDS = ("M", "E", "*")
+HYBRID_KINDS = ("M", "D", "E", "*", "G")
+ATTENTION_KINDS = "*G"                # they share the paged pool
+# a stateful kind: (its named scope, its mixer's state name -> cache group)
+_RECURRENT = {"M": ("ssm", {"conv": "conv", "h": "h"}),
+              "D": ("delta", {"conv": "delta_conv", "s": "delta_s"})}
+_EXPERT_ACTS = {"relu2": core.relu2, "silu": jax.nn.silu}
+
+
+def _recurrent_mixer(kind: str, cfg: HybridConfig):
+    """(mixer, its config, a zero state for n slots) of a stateful kind."""
+    if kind == "M":
+        from rafiki_tpu.ops.mamba2 import mamba2_mixer, mamba2_state_init
+
+        return mamba2_mixer, cfg.mamba, mamba2_state_init
+    from rafiki_tpu.ops.gated_delta import (gated_delta_mixer,
+                                            gated_delta_state_init)
+
+    return gated_delta_mixer, cfg.delta, gated_delta_state_init
 
 
 def hybrid_layer_init(rng: jax.Array, kind: str, cfg: HybridConfig,
                       dtype=jnp.bfloat16) -> Params:
-    from rafiki_tpu.ops.attention import gqa_init
+    from rafiki_tpu.ops.attention import gated_gqa_init, gqa_init
+    from rafiki_tpu.ops.gated_delta import gated_delta_init
     from rafiki_tpu.ops.mamba2 import mamba2_init
 
+    if kind not in HYBRID_KINDS:
+        raise ValueError(f"unknown layer kind {kind!r} in {cfg.pattern!r}")
     norm = core.rmsnorm_init(cfg.dim)
     if kind == "M":
         return {"norm": norm, **mamba2_init(rng, cfg.mamba, dtype)}
-    if kind == "*":
-        return {"norm": norm, **gqa_init(rng, cfg.dim, cfg.q_heads,
-                                         cfg.kv_heads, cfg.head_dim, dtype)}
-    if kind != "E":
-        raise ValueError(f"unknown layer kind {kind!r} in {cfg.pattern!r}")
+    if kind == "D":
+        return {"norm": norm, **gated_delta_init(rng, cfg.delta, dtype)}
+    if kind in ATTENTION_KINDS:
+        init = gqa_init if kind == "*" else gated_gqa_init
+        return {"norm": norm, **init(rng, cfg.dim, cfg.q_heads, cfg.kv_heads,
+                                     cfg.head_dim, dtype)}
     kr, kb, ku, kd, su, sd = jax.random.split(rng, 6)
     count = cfg.held[1]
     into = cfg.dim ** -0.5  # by fan-in
-    return {
+    ups = 2 if cfg.expert_gated else 1  # [W_gate | W_up] side by side
+    p = {
         "norm": norm,
         "router": core.normal_init(kr, (cfg.dim, cfg.n_experts), std=into),
-        "b_corr": core.normal_init(kb, (cfg.n_experts,)),
-        "w_up": core.normal_init(ku, (count, cfg.dim, cfg.ffn), std=into,
-                                 dtype=dtype),
+        "w_up": core.normal_init(ku, (count, cfg.dim, ups * cfg.ffn),
+                                 std=into, dtype=dtype),
         "w_down": core.normal_init(kd, (count, cfg.ffn, cfg.dim),
                                    std=cfg.ffn ** -0.5, dtype=dtype),
-        "s_up": core.normal_init(su, (cfg.dim, cfg.shared_ffn), std=into,
-                                 dtype=dtype),
+        "s_up": core.normal_init(su, (cfg.dim, ups * cfg.shared_ffn),
+                                 std=into, dtype=dtype),
         "s_down": core.normal_init(sd, (cfg.shared_ffn, cfg.dim),
                                    std=cfg.shared_ffn ** -0.5, dtype=dtype),
     }
+    if cfg.route_bias:
+        p["b_corr"] = core.normal_init(kb, (cfg.n_experts,))
+    if cfg.shared_gate:
+        p["s_gate"] = core.normal_init(jax.random.fold_in(rng, 6),
+                                       (cfg.dim, 1), std=into, dtype=dtype)
+    return p
 
 
 def hybrid_layers(layers: list) -> Params:
@@ -756,35 +802,42 @@ def hybrid_init(rng: jax.Array, cfg: HybridConfig,
 def init_hybrid_cache(cfg: HybridConfig, pool_blocks: int, block_tokens: int,
                       max_slots: int, kv_dtype=jnp.bfloat16) -> Cache:
     """The cache's groups: ``k``/``v`` the attention layers' paged pool,
-    ``conv``/``h`` every Mamba layer's state for each of ``max_slots``."""
-    from rafiki_tpu.ops.mamba2 import mamba2_state_init
-
-    kv = (cfg.count("*"), int(pool_blocks), int(block_tokens),
+    and for each stateful kind the pattern holds its layers' state for each
+    of ``max_slots``: ``conv``/``h`` (``M``), ``delta_conv``/``delta_s``
+    (``D``)."""
+    kv = (cfg.count(ATTENTION_KINDS), int(pool_blocks), int(block_tokens),
           cfg.kv_heads * cfg.head_dim)
-    state = mamba2_state_init(cfg.mamba, int(max_slots))
-    n_m = cfg.count("M")
-    return {"k": jnp.zeros(kv, kv_dtype), "v": jnp.zeros(kv, kv_dtype),
-            **{name: jnp.zeros((n_m,) + a.shape, a.dtype)
-               for name, a in state.items()}}
+    cache = {"k": jnp.zeros(kv, kv_dtype), "v": jnp.zeros(kv, kv_dtype)}
+    for kind, (_, groups) in _RECURRENT.items():
+        if cfg.count(kind):
+            _, mixer_cfg, state_init = _recurrent_mixer(kind, cfg)
+            cache.update({groups[name]: jnp.zeros(
+                (cfg.count(kind),) + a.shape, a.dtype) for name, a in
+                state_init(mixer_cfg, int(max_slots)).items()})
+    return cache
 
 
 def hybrid_state_bytes(cache: Cache) -> int:
-    """Bytes of the per-slot recurrent state (not the paged pool)."""
-    return int(cache["conv"].nbytes + cache["h"].nbytes)
+    """Bytes of the per-slot recurrent state: every group of the cache but
+    the paged pool (``conv``, ``h``, ``delta_conv``, ``delta_s``, as far as
+    the pattern holds their kinds)."""
+    return int(sum(a.nbytes for name, a in cache.items()
+                   if name not in ("k", "v")))
 
 
 def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
                     positions: jax.Array, block_tables: jax.Array,
-                    slots: jax.Array, lengths: jax.Array, reset: jax.Array,
-                    cfg: HybridConfig
+                    slots: Optional[jax.Array], lengths: jax.Array,
+                    reset: jax.Array, cfg: HybridConfig
                     ) -> Tuple[jax.Array, Cache, Dict[str, jax.Array]]:
     """ids/positions (B, T), block_tables (B, NB), slots (B,) the state rows
-    of the sequences, lengths (B,) how many of the T tokens are real (0: an
+    of the sequences (None: row i is slot i and every slot is a row, so a
+    layer's state is read and written in place, with no gather and no
+    scatter of it), lengths (B,) how many of the T tokens are real (0: an
     idle row, which moves no state), reset (B,) bool: start from a zero
     state. Returns (x (B, T, D) f32 before the last norm, cache, counts of
     the expert layers summed over them)."""
-    from rafiki_tpu.ops.attention import gqa_cached
-    from rafiki_tpu.ops.mamba2 import mamba2_mixer
+    from rafiki_tpu.ops.attention import gqa_cached, rotary
     from rafiki_tpu.parallel.moe import expert_layer, ffn
 
     b, t = ids.shape
@@ -799,41 +852,54 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
     x = jnp.take(params["embed"]["table"], ids, axis=0).astype(jnp.float32)
     zero = jnp.zeros((), jnp.int32)
 
-    def mamba(p, x, cache, l):
-        with jax.named_scope("ssm"):
+    def recurrent(kind, p, x, cache, l):
+        scope, groups = _RECURRENT[kind]
+        mixer, mixer_cfg, _ = _recurrent_mixer(kind, cfg)
+        with jax.named_scope(scope):
             u = core.rmsnorm(p["norm"], x, cfg.eps)
+            at = l if slots is None else (l, slots)
             state = {name: jnp.where(
-                reset.reshape((b,) + (1,) * (cache[name].ndim - 2)), 0.0,
-                cache[name][l, slots]) for name in ("conv", "h")}
-            out, state = mamba2_mixer(p, u, state, lengths, cfg.mamba)
-            cache = {**cache, **{name: cache[name].at[l, slots].set(
-                state[name]) for name in ("conv", "h")}}
+                reset.reshape((b,) + (1,) * (cache[group].ndim - 2)), 0.0,
+                cache[group][at]) for name, group in groups.items()}
+            out, state = mixer(p, u, state, lengths, mixer_cfg)
+            cache = {**cache, **{group: cache[group].at[at].set(state[name])
+                                 for name, group in groups.items()}}
             x = x + out
         return x, cache
 
-    def attention(p, x, cache, l):
+    def attention(kind, p, x, cache, l):
         with jax.named_scope("attn"):
             u = core.rmsnorm(p["norm"], x, cfg.eps).astype(p["wq"].dtype)
             proj = lambda w: jnp.dot(u, w,
                                      preferred_element_type=jnp.float32)
             kv_dt = cache["k"].dtype
-            q = proj(p["wq"]).astype(kv_dt).reshape(
-                b, t, cfg.q_heads, cfg.head_dim)
-            k, v = proj(p["wk"]).astype(kv_dt), proj(p["wv"]).astype(kv_dt)
+            rows = (b, t, cfg.kv_heads, cfg.head_dim)
+            q, k, v = proj(p["wq"]), proj(p["wk"]), proj(p["wv"])
+            if kind == "G":  # a head's query then its gate; norms, rotary
+                q, gate = jnp.split(q.reshape(
+                    b, t, cfg.q_heads, 2 * cfg.head_dim), 2, axis=-1)
+                q, k = (rotary(core.rmsnorm(p[norm], a, cfg.eps), positions,
+                               cfg.rotary_dim, cfg.rope_theta)
+                        for norm, a in (("q_norm", q),
+                                        ("k_norm", k.reshape(rows))))
+            q = q.astype(kv_dt).reshape(b, t, cfg.q_heads, cfg.head_dim)
+            k, v = k.astype(kv_dt).reshape(rows), v.astype(kv_dt).reshape(rows)
             view = (b, nb * bt, cfg.kv_heads, cfg.head_dim)
             lk = cache["k"].at[l, block_tables].get(mode="clip").reshape(view)
             lv = cache["v"].at[l, block_tables].get(mode="clip").reshape(view)
-            rows = (b, t, cfg.kv_heads, cfg.head_dim)
-            lk = lk.at[batch_ix, positions].set(k.reshape(rows))
-            lv = lv.at[batch_ix, positions].set(v.reshape(rows))
+            lk = lk.at[batch_ix, positions].set(k)
+            lv = lv.at[batch_ix, positions].set(v)
             o = gqa_cached(q, lk, lv, positions)
+            if kind == "G":
+                o = (o * jax.nn.sigmoid(gate).reshape(o.shape)).astype(
+                    p["wo"].dtype)
             out = jnp.dot(o, p["wo"], preferred_element_type=jnp.float32)
             # the rows come back out of the view, so that the pool's write
             # follows its read and needs no copy (as `_paged_forward`)
             k = jnp.take_along_axis(
-                lk, positions[:, :, None, None], axis=1).reshape(k.shape)
+                lk, positions[:, :, None, None], axis=1).reshape(b, t, -1)
             v = jnp.take_along_axis(
-                lv, positions[:, :, None, None], axis=1).reshape(v.shape)
+                lv, positions[:, :, None, None], axis=1).reshape(b, t, -1)
             cache = {**cache,
                      "k": cache["k"].at[l, phys, off].set(k, mode="drop"),
                      "v": cache["v"].at[l, phys, off].set(v, mode="drop")}
@@ -845,10 +911,17 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
             u = core.rmsnorm(p["norm"], x, cfg.eps).reshape(b * t, cfg.dim)
             # the loop over the experts hit reads each one's two matrices
             # in place: only the experts a token chose are touched
+            act = _EXPERT_ACTS[cfg.expert_act]
             routed, c = expert_layer(
-                p, u, cfg.top_k, held=cfg.held, scale=cfg.route_scale,
+                p, u, cfg.top_k, held=cfg.held, score=cfg.route_score,
+                scale=cfg.route_scale, act=act, gated=cfg.expert_gated,
                 live=live, gather=True)
-            out = routed + ffn(u, p["s_up"], p["s_down"])
+            shared = ffn(u, p["s_up"], p["s_down"], act, cfg.expert_gated)
+            if cfg.shared_gate:
+                shared = shared * jax.nn.sigmoid(jnp.dot(
+                    u.astype(p["s_gate"].dtype), p["s_gate"],
+                    preferred_element_type=jnp.float32))
+            out = routed + shared
             counts = {name: counts[name] + c[name] for name in counts}
             x = x + out.reshape(b, t, cfg.dim)
         return x, counts
@@ -859,16 +932,18 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
     # 6 x 64 experts of 2688 x 1856; compiled for a described v5e, PR 27);
     # 14 layers in line compile in 6 s.
     counts = {"expert_tokens": zero, "experts_hit": zero}
-    at = {kind: 0 for kind in HYBRID_KINDS}  # the kind's cache row
+    at = {}  # the next cache row of each group of layers
     for l, kind in enumerate(cfg.pattern):
         p = params["layers"][f"{l:02d}"]
-        if kind == "M":
-            x, cache = mamba(p, x, cache, at[kind])
-        elif kind == "*":
-            x, cache = attention(p, x, cache, at[kind])
+        group = "*" if kind in ATTENTION_KINDS else kind  # one pool for both
+        row = at.get(group, 0)
+        if kind in _RECURRENT:
+            x, cache = recurrent(kind, p, x, cache, row)
+        elif kind in ATTENTION_KINDS:
+            x, cache = attention(kind, p, x, cache, row)
         else:
             x, counts = experts(p, x, counts)
-        at[kind] += 1
+        at[group] = row + 1
     counts["expert_layers"] = jnp.asarray(cfg.count("E"), jnp.int32)
     return x, cache, counts
 
@@ -914,9 +989,8 @@ def hybrid_paged_decode_step(params: Params, cache: Cache, ids: jax.Array,
     live = block_tables[:, 0] < cache["k"].shape[1]
     x, cache, counts = _hybrid_forward(
         params, cache, jnp.asarray(ids, jnp.int32)[:, None],
-        jnp.asarray(positions, jnp.int32)[:, None], block_tables,
-        jnp.arange(s, dtype=jnp.int32), live.astype(jnp.int32),
-        jnp.zeros((s,), bool), cfg)
+        jnp.asarray(positions, jnp.int32)[:, None], block_tables, None,
+        live.astype(jnp.int32), jnp.zeros((s,), bool), cfg)
     return _hybrid_head(params, x[:, 0], cfg), cache, counts
 
 

@@ -120,6 +120,34 @@ def gqa_init(rng: jax.Array, dim: int, q_heads: int, kv_heads: int,
                                    dtype=dtype)}
 
 
+def gated_gqa_init(rng: jax.Array, dim: int, q_heads: int, kv_heads: int,
+                   head_dim: int, dtype=jnp.float32) -> Params:
+    """:func:`gqa_init` with what a gated rotary layer adds: the query
+    projection twice as wide (a head's query, then its output gate) and an
+    RMSNorm scale over a head for queries and for keys."""
+    p = gqa_init(rng, dim, q_heads, kv_heads, head_dim, dtype)
+    wide = core.normal_init(jax.random.fold_in(rng, 1),
+                            (dim, 2 * q_heads * head_dim), std=dim ** -0.5,
+                            dtype=dtype)
+    return {**p, "wq": wide, "q_norm": core.rmsnorm_init(head_dim),
+            "k_norm": core.rmsnorm_init(head_dim)}
+
+
+def rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
+           theta: float) -> jax.Array:
+    """Rotary position embedding on the first ``rotary_dim`` of each head,
+    the rest passed through: ``x`` (B, T, H, Dh) f32, ``positions`` (B, T).
+    Dimension i < rotary_dim / 2 turns with i + rotary_dim / 2
+    ("rotate-half") by ``positions * theta^(-2i / rotary_dim)``."""
+    half = rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b, rest = jnp.split(x, [half, rotary_dim], axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest],
+                           axis=-1)
+
+
 def gqa_cached(q: jax.Array, lk: jax.Array, lv: jax.Array,
                positions: jax.Array) -> jax.Array:
     """Grouped-query attention of new tokens over a cache view, with no

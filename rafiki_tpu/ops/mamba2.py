@@ -138,15 +138,13 @@ def mamba2_mixer(p: Params, u: jax.Array, state: Dict[str, jax.Array],
     state). Returns (out (B, T, D) f32, the state after the last real
     token)."""
     b, t, _ = u.shape
-    inner, k = cfg.inner, cfg.conv_kernel
+    inner = cfg.inner
     dt_w = p["w_in"].dtype
     proj = jnp.dot(u.astype(dt_w), p["w_in"],
                    preferred_element_type=jnp.float32)
     gate, xbc, dt = jnp.split(proj, [inner, inner + cfg.conv_dim], axis=-1)
-    window = jnp.concatenate([state["conv"], xbc], axis=1)   # (B, K-1+T, C)
-    conv = sum(window[:, j:j + t] * p["conv_w"][j] for j in range(k))
-    conv_state = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-        w, n, k - 1, axis=0))(window, lengths)
+    conv, conv_state = core.carried_conv(state["conv"], xbc, p["conv_w"],
+                                         lengths)
     xbc = jax.nn.silu(conv + p["conv_b"])
     x, bm, cm = jnp.split(xbc, [inner, inner + cfg.groups * cfg.state],
                           axis=-1)

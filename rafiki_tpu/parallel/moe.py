@@ -9,6 +9,10 @@ all of them and computes the held ones' part of the result (this chip's
 share of an expert-parallel deployment; what the absent experts would add
 is left out, and no code stands in for their exchange). A shared expert,
 where the model has one, is every chip's alike and is added by the caller.
+An expert is ``act(x W_up) W_down`` or, ``gated``, ``(act(x W_gate) * (x
+W_up)) W_down`` with the two up-matrices side by side in one leaf
+``[W_gate | W_up]`` (D, 2F): the loop over the experts hit then reads one
+matrix and makes one product where two leaves would cost it two of each.
 
 The experts' products come in two forms with one result. ``dense``: every
 held expert over every token, weighted by the gates, as one batched product
@@ -62,22 +66,33 @@ def route(x: jax.Array, router: jax.Array, k: int, *,
     return jnp.sum(hot * (picked * scale)[..., None], axis=1), scores
 
 
+def _gated(act: Callable) -> Callable:
+    """The hidden layer of a gated expert from its two halves side by side."""
+    def hidden(h):
+        gate, up = jnp.split(h, 2, axis=-1)
+        return act(gate) * up
+    return hidden
+
+
 def expert_products(x: jax.Array, gates: jax.Array, w_up: jax.Array,
                     w_down: jax.Array, *, b_up: Optional[jax.Array] = None,
                     b_down: Optional[jax.Array] = None,
-                    act: Callable = relu2, gather: bool = False
-                    ) -> jax.Array:
+                    act: Callable = relu2, gated: bool = False,
+                    gather: bool = False) -> jax.Array:
     """sum_e gates[:, e] * (act(x W_up[e] + b_up[e]) W_down[e] + b_down[e])
     over the E' experts of ``gates``: x (N, D), gates (N, E'), w_up
-    (E', D, F), w_down (E', F, D) -> (N, D) f32. Operands in the weights'
-    dtype, accumulation in f32."""
+    (E', D, F), w_down (E', F, D) -> (N, D) f32. ``gated``: w_up is
+    (E', D, 2F), ``[W_gate | W_up]``, and the hidden layer is
+    ``act(x W_gate) * (x W_up)``. Operands in the weights' dtype,
+    accumulation in f32."""
     xw = x.astype(w_up.dtype)
+    hidden = _gated(act) if gated else act
     if not gather:
         h = jnp.einsum("nd,edf->enf", xw, w_up,
                        preferred_element_type=jnp.float32)
         if b_up is not None:
             h = h + b_up[:, None, :]
-        h = act(h) * gates.T[..., None]
+        h = hidden(h) * gates.T[..., None]
         y = jnp.einsum("enf,efd->nd", h.astype(w_down.dtype), w_down,
                        preferred_element_type=jnp.float32)
         if b_down is not None:
@@ -93,7 +108,7 @@ def expert_products(x: jax.Array, gates: jax.Array, w_up: jax.Array,
         up = jax.lax.dynamic_index_in_dim(w_up, e, keepdims=False)
         down = jax.lax.dynamic_index_in_dim(w_down, e, keepdims=False)
         g = jax.lax.dynamic_index_in_dim(gates, e, axis=1, keepdims=False)
-        h = act(jnp.dot(xw, up, preferred_element_type=jnp.float32))
+        h = hidden(jnp.dot(xw, up, preferred_element_type=jnp.float32))
         h = h * g[:, None]
         return acc + jnp.dot(h.astype(down.dtype), down,
                              preferred_element_type=jnp.float32)
@@ -106,11 +121,12 @@ def expert_layer(p: Params, x: jax.Array, k: int, *,
                  held: Optional[Tuple[int, int]] = None,
                  score: str = "sigmoid", renorm: bool = True,
                  scale: float = 1.0, act: Callable = relu2,
-                 live: Optional[jax.Array] = None, gather: bool = False
+                 gated: bool = False, live: Optional[jax.Array] = None,
+                 gather: bool = False
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The routed part of the layer for the experts held here. ``p``:
-    ``router`` (D, E), ``b_corr`` (E,) or absent, ``w_up`` (count, D, F),
-    ``w_down`` (count, F, D), the held experts' own.
+    ``router`` (D, E), ``b_corr`` (E,) or absent, ``w_up`` (count, D, F; or
+    2F, ``gated``), ``w_down`` (count, F, D), the held experts' own.
     x (N, D) -> (y (N, D) f32, counts): ``expert_tokens`` the (token, held
     expert) choices and ``experts_hit`` the held experts with a token.
     ``live`` (N,) bool takes tokens out of the routing (an idle decode slot
@@ -123,7 +139,7 @@ def expert_layer(p: Params, x: jax.Array, k: int, *,
         gates = jnp.where(live[:, None], gates, 0.0)
     gates = jax.lax.dynamic_slice_in_dim(gates, first, count, axis=1)
     y = expert_products(x, gates, p["w_up"], p["w_down"], act=act,
-                        gather=gather)
+                        gated=gated, gather=gather)
     chosen = gates > 0.0
     return y, {"expert_tokens": jnp.sum(chosen, dtype=jnp.int32),
                "experts_hit": jnp.sum(jnp.any(chosen, axis=0),
@@ -131,10 +147,12 @@ def expert_layer(p: Params, x: jax.Array, k: int, *,
 
 
 def ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
-        act: Callable = relu2) -> jax.Array:
-    """A plain ungated feed-forward (a shared expert): (N, D) -> f32."""
-    h = act(jnp.dot(x.astype(w_up.dtype), w_up,
-                    preferred_element_type=jnp.float32))
+        act: Callable = relu2, gated: bool = False) -> jax.Array:
+    """A plain feed-forward (a shared expert): (N, D) -> f32. ``gated``:
+    w_up is ``[W_gate | W_up]`` (D, 2F), as an expert's."""
+    h = (_gated(act) if gated else act)(
+        jnp.dot(x.astype(w_up.dtype), w_up,
+                preferred_element_type=jnp.float32))
     return jnp.dot(h.astype(w_down.dtype), w_down,
                    preferred_element_type=jnp.float32)
 

@@ -398,12 +398,25 @@ class AdmissionController:
 
     # -- feedback + observability ------------------------------------------
 
-    def observe(self, latency_s: float, n_queries: int) -> None:
+    def observe(self, latency_s: float, n_queries: int,
+                service_s: Optional[float] = None) -> None:
         """Feed one served request's latency back into the wait model,
-        the door's latency histogram, and the EWMA-wait ring series."""
+        the door's latency histogram, and the EWMA-wait ring series.
+        ``service_s``: the part of the latency that was service, where the
+        caller can tell it from the wait before it (a stream's seconds from
+        its slot's admission on). The wait model learns from that alone:
+        the wait for a slot is the queueing it estimates, and on a replica
+        just deployed the first stream also waits for the programs'
+        compilation (a minute on a cold cache: taken as service it put the
+        estimate past every deadline, and the door shed a sixth of a
+        saturated run's streams). The histogram keeps the whole latency.
+        The one ``/generate`` door (``predictor/server.py``) always passes
+        it; the predict doors, whose batch has no slot to wait for, feed
+        the whole latency: the unit is service seconds in both."""
         if n_queries <= 0 or latency_s < 0:
             return
-        per_query = latency_s / n_queries
+        per_query = (latency_s if service_s is None
+                     else max(service_s, 0.0)) / n_queries
         with self._lock:
             if self._ewma_query_s <= 0.0:
                 self._ewma_query_s = per_query

@@ -37,6 +37,18 @@ from rafiki_tpu.utils.reqfields import LowLatencyHandler
 logger = logging.getLogger(__name__)
 
 
+class _DoorHTTPServer(ThreadingHTTPServer):
+    """The door's listener. ``socketserver``'s listen queue holds 5
+    connections; a deployment's closed-loop callers reconnect together
+    (64 of them on one door in the benchmark's fullest cell), and a
+    connection past the queue is reset before its request is read
+    (``ConnectionResetError`` at the client, never seen by admission
+    control). The queue is as long as the callers a door is meant to
+    carry; the kernel caps it at ``net.core.somaxconn``."""
+
+    request_queue_size = 256
+
+
 def _generate_cost(prompt_len: int, max_tokens: int) -> int:
     """Admission cost of one /generate request, in the units of the
     resource that actually gates the generation worker: KV-pool BLOCKS
@@ -81,7 +93,7 @@ class PredictorServer:
         #: and a monitor that sees started_at jump knows the door moved
         #: (rather than silently aiming at the dead process's port)
         self.started_at: Optional[float] = None
-        self._httpd: Optional[ThreadingHTTPServer] = None
+        self._httpd: Optional[_DoorHTTPServer] = None
         self._thread: Optional[threading.Thread] = None
         self._stop_lock = threading.Lock()
         self._stopped = False
@@ -110,7 +122,7 @@ class PredictorServer:
                 else:
                     server._predict(self)
 
-        self._httpd = ThreadingHTTPServer((self.host, self.port), Handler)
+        self._httpd = _DoorHTTPServer((self.host, self.port), Handler)
         self.port = self._httpd.server_address[1]
         self.started_at = time.time()
         self._thread = threading.Thread(
@@ -502,6 +514,7 @@ class PredictorServer:
             held[0] = True
             t0 = time.monotonic()
             stream = self.predictor.generate(query, timeout_s=timeout_s)
+            t_slot = time.monotonic()
             binary = self._accepts_wire(handler)
             REGISTRY.histogram(
                 "rafiki_gen_door_ttft_seconds",
@@ -510,11 +523,13 @@ class PredictorServer:
                 "it and handed the stream back: queueing behind busy "
                 "slots, then the first prefill chunk (a one-chunk greedy "
                 "prompt's first token). rafiki_gen_ttft_seconds starts "
-                "at the slot's admission, inside this").observe(
-                    time.monotonic() - t0)
+                "at the slot's admission, inside this").observe(t_slot - t0)
             n_tokens = self._stream_deltas(handler, stream, binary)
-            self.admission.observe(time.monotonic() - t0,
-                                   max(n_tokens, 1))
+            done = time.monotonic()
+            # the wait model's unit is a token's SERVICE time: the wait
+            # for a slot (and a cold replica's compilation) is not in it
+            self.admission.observe(done - t0, max(n_tokens, 1),
+                                   service_s=done - t_slot)
         except UnauthorizedError as e:
             self._respond(handler, 401, {"error": str(e)})
         except json.JSONDecodeError as e:

@@ -4,6 +4,8 @@ recurrent-state half of the generation contract. Its spec declares
 ``recurrent_state``, so the worker hands ``init_paged_kv_cache`` the slot
 count and ``paged_prefill`` the slot. float32 weights from a fixed key, so
 that greedy decode is exact and two runs of one prompt agree to the token.
+``TinyDeltaLM`` is the same template over the stack's other kinds: the gated
+delta rule, gated rotary attention, gated softmax-routed experts.
 """
 
 import jax
@@ -11,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from rafiki_tpu.models import lm
+from rafiki_tpu.ops.gated_delta import GatedDeltaConfig
 from rafiki_tpu.ops.mamba2 import Mamba2Config
 from rafiki_tpu.sdk import BaseModel, FixedKnob, GenerationSpec
 
@@ -23,6 +26,14 @@ CFG = lm.HybridConfig(
                        conv_kernel=4, chunk_size=4),
     q_heads=4, kv_heads=2, head_dim=8, n_experts=8, top_k=2, ffn=16,
     shared_ffn=32, route_scale=2.5, held=(0, 4))
+DELTA_CFG = lm.HybridConfig(
+    vocab=VOCAB, max_len=MAX_CONTEXT, dim=DIM, pattern="DEGE",
+    delta=GatedDeltaConfig(dim=DIM, key_heads=2, value_heads=4, key_dim=8,
+                           value_dim=8, conv_kernel=4, chunk_size=4),
+    q_heads=4, kv_heads=2, head_dim=8, rotary_dim=4, rope_theta=1e7,
+    n_experts=8, top_k=3, ffn=16, shared_ffn=16, route_score="softmax",
+    route_bias=False, route_scale=1.0, expert_act="silu", expert_gated=True,
+    shared_gate=True, held=(0, 4), eps=1e-6)
 BUCKETS = (8, 16, 32, MAX_CONTEXT)
 RING_BLOCK = 8
 
@@ -39,6 +50,7 @@ class TinyHybridLM(BaseModel):
     generation_spec = GenerationSpec(eos_token_id=None,
                                      max_context=MAX_CONTEXT,
                                      recurrent_state=True)
+    cfg = CFG
 
     @staticmethod
     def get_knob_config():
@@ -50,16 +62,16 @@ class TinyHybridLM(BaseModel):
         self._ring_tables = None
         self._prefill = jax.jit(
             lambda p, c, bt, i, st, m, sl: lm.hybrid_paged_prefill(
-                p, c, bt, i, st, m, sl, CFG))
+                p, c, bt, i, st, m, sl, self.cfg))
         self._decode = jax.jit(
             lambda p, c, i, q, bts: lm.hybrid_paged_decode_step(
-                p, c, i, q, bts, CFG))
+                p, c, i, q, bts, self.cfg))
         self._copy = jax.jit(lm.copy_hybrid_kv_blocks)
         #: (start, slot) of every paged_prefill call, for the tests
         self.prefills = []
 
     def train(self, dataset_uri):
-        self._params = lm.hybrid_init(jax.random.key(0), CFG,
+        self._params = lm.hybrid_init(jax.random.key(0), self.cfg,
                                       dtype=jnp.float32)
 
     def evaluate(self, dataset_uri):
@@ -95,8 +107,8 @@ class TinyHybridLM(BaseModel):
     # -- the paged contract, with the slot -----------------------------------
 
     def init_paged_kv_cache(self, pool_blocks, block_tokens, max_slots):
-        return lm.init_hybrid_cache(CFG, pool_blocks, block_tokens, max_slots,
-                                    kv_dtype=jnp.float32)
+        return lm.init_hybrid_cache(self.cfg, pool_blocks, block_tokens,
+                                    max_slots, kv_dtype=jnp.float32)
 
     def recurrent_state_bytes(self, cache):
         return lm.hybrid_state_bytes(cache)
@@ -119,3 +131,7 @@ class TinyHybridLM(BaseModel):
     def kv_copy_blocks(self, cache, src, dst):
         return self._copy(cache, np.asarray(src, np.int32),
                           np.asarray(dst, np.int32))
+
+
+class TinyDeltaLM(TinyHybridLM):
+    cfg = DELTA_CFG

@@ -142,23 +142,35 @@ def test_the_two_shares_and_the_shared_expert_once_are_the_uncut_layer():
     assert np.abs(np.asarray(parts[0])).max() > 0.01
 
 
-@pytest.mark.parametrize("k,score", [(1, "softmax"), (2, "sigmoid"),
-                                     (6, "sigmoid")])
-def test_expert_products_gathered_and_dense_agree_and_drop_no_token(k, score):
+@pytest.mark.parametrize("k,score,gated", [
+    (1, "softmax", False), (2, "sigmoid", False), (6, "sigmoid", False),
+    (10, "softmax", True)])
+def test_expert_products_gathered_and_dense_agree_and_drop_no_token(
+        k, score, gated):
     rng = jax.random.key(k)
     x = jax.random.normal(rng, (12, 16))
-    p = {"router": jax.random.normal(jax.random.fold_in(rng, 1), (16, 8)),
-         "w_up": jax.random.normal(jax.random.fold_in(rng, 2), (8, 16, 24)),
-         "w_down": jax.random.normal(jax.random.fold_in(rng, 3), (8, 24, 16))}
+    n, up = (16, 48) if gated else (8, 24)  # [W_gate | W_up] side by side
+    p = {"router": jax.random.normal(jax.random.fold_in(rng, 1), (16, n)),
+         "w_up": jax.random.normal(jax.random.fold_in(rng, 2), (n, 16, up)),
+         "w_down": jax.random.normal(jax.random.fold_in(rng, 3), (n, 24, 16))}
     gates, _ = moe.route(x, p["router"], k, score=score)
     assert np.all(np.sum(np.asarray(gates) > 0, axis=1) == k)  # none dropped
-    dense, cd = moe.expert_layer(p, x, k, score=score, gather=False)
-    gathered, cg = moe.expert_layer(p, x, k, score=score, gather=True)
+    how = dict(score=score, gated=gated,
+               act=jax.nn.silu if gated else core.relu2)
+    dense, cd = moe.expert_layer(p, x, k, **how, gather=False)
+    gathered, cg = moe.expert_layer(p, x, k, **how, gather=True)
+    if gated:  # the hidden layer is act(x W_gate) * (x W_up), by hand
+        h = jnp.einsum("nd,edf->enf", x, p["w_up"])
+        by_hand = jnp.einsum(
+            "enf,efd,ne->nd", jax.nn.silu(h[..., :24]) * h[..., 24:],
+            p["w_down"], gates)
+        np.testing.assert_allclose(np.asarray(dense), np.asarray(by_hand),
+                                   atol=1e-3, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(dense), np.asarray(gathered),
                                atol=1e-4, rtol=1e-4)
     assert int(cd["expert_tokens"]) == int(cg["expert_tokens"]) == 12 * k
     # a token taken out of the routing reads no expert
-    none, c0 = moe.expert_layer(p, x, k, score=score, gather=True,
+    none, c0 = moe.expert_layer(p, x, k, **how, gather=True,
                                 live=jnp.zeros(12, bool))
     assert float(jnp.abs(none).max()) == 0.0 and int(c0["experts_hit"]) == 0
 
@@ -297,8 +309,8 @@ def _fixture():
     return hybrid_gen_model
 
 
-def _model():
-    m = _fixture().TinyHybridLM()
+def _model(name="TinyHybridLM"):
+    m = getattr(_fixture(), name)()
     m.train(None)
     return m
 
@@ -364,7 +376,8 @@ def _total(name):
         sum(c.value() for c in metric.children().values()))
 
 
-def test_worker_serves_a_recurrent_model_as_fresh_runs(monkeypatch):
+@pytest.mark.parametrize("template", ["TinyHybridLM", "TinyDeltaLM"])
+def test_worker_serves_a_recurrent_model_as_fresh_runs(monkeypatch, template):
     """Chunked prefill across chunk boundaries (while siblings decode), a
     slot reused after another stream, and the same prompt sent again with
     the prefix cache on: every stream is the fresh run of its prompt, every
@@ -377,14 +390,14 @@ def test_worker_serves_a_recurrent_model_as_fresh_runs(monkeypatch):
     monkeypatch.setenv("RAFIKI_GEN_PREFILL_CHUNK", "8")
     monkeypatch.setenv("RAFIKI_GEN_KV_PAGED", "1")
     monkeypatch.setenv("RAFIKI_GEN_PREFIX_CACHE", "1")
-    model = _model()
+    model = _model(template)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 64, size=n).tolist() for n in (21, 5, 13, 30)]
     prompts.append(prompts[0])  # a whole-prompt prefix hit, were it served
     want = [_solo(model, p, 10) for p in prompts]
     broker = InProcessBroker()
-    worker, ctx, t = _start_worker(broker, model, job="hybridjob")
-    q = list(broker.get_worker_queues("hybridjob").values())[0]
+    worker, ctx, t = _start_worker(broker, model, job=template)
+    q = list(broker.get_worker_queues(template).values())[0]
     before = {n: _total(n) for n in (
         "rafiki_gen_state_resets_total", "rafiki_gen_prefix_hits_total",
         "rafiki_gen_prefix_misses_total", "rafiki_gen_experts_hit_total",

@@ -6,6 +6,7 @@ contract. Tier-1 tests are deterministic (chaos schedules, no real
 load); the genuinely concurrent stress drill is marked slow."""
 
 import json
+import socket
 import threading
 import time
 import urllib.error
@@ -319,3 +320,88 @@ def test_stress_concurrent_clients_shed_cleanly(tmp_workdir, monkeypatch):
     finally:
         chaos.clear()
         admin.shutdown()
+
+
+def test_the_doors_listener_accepts_64_connections_opened_at_once():
+    """The per-job door's listen queue holds a burst of callers: 64
+    connections opened before the accept loop takes one are all accepted
+    and answered (Python's default of 5 resets the rest under load)."""
+    class _Predictor:
+        def queue_depths(self):
+            return {"w": 0}
+
+    server = PredictorServer(_Predictor(), "burst", auth=False).start()
+    try:
+        assert server._httpd.request_queue_size >= 64
+        # hold the accept loop: the burst has to wait in the listen queue
+        held = threading.Event()
+        real = server._httpd.get_request
+
+        def slow():
+            held.wait(5)
+            return real()
+
+        server._httpd.get_request = slow
+        socks = []
+        for _ in range(64):
+            s = socket.socket()
+            s.settimeout(10)
+            s.connect(("127.0.0.1", server.port))
+            socks.append(s)
+        held.set()
+        for s in socks:
+            s.sendall(b"GET /healthz HTTP/1.1\r\nHost: x\r\n"
+                      b"Connection: close\r\n\r\n")
+        answers = [s.recv(64) for s in socks]
+        for s in socks:
+            s.close()
+        assert all(a.startswith(b"HTTP/1.1 200") for a in answers)
+    finally:
+        server.stop(drain_timeout_s=1.0)
+
+
+def test_a_streams_wait_for_its_slot_stays_out_of_the_doors_wait_model():
+    """The first stream of a cold replica waits for the programs to
+    compile before a slot takes it. The door's estimate of a queue's wait
+    (backlog x seconds a token) learns from the seconds a token took once
+    the stream had its slot; the latency histogram keeps the whole."""
+    import http.client
+
+    from rafiki_tpu.cache.queue import TokenStream
+    class _Predictor:
+        def queue_depths(self):
+            return {"w": 0}
+
+        def backlog_depth(self):
+            return 32
+
+        def generate(self, query, timeout_s):
+            time.sleep(0.6)  # no slot yet: a compile, a queue
+            stream = TokenStream("s1")
+            stream.push([1, 2, 3, 4], finished=True, reason="length")
+            return stream
+
+    server = PredictorServer(_Predictor(), "coldstart", auth=False).start()
+    try:
+        def ask():
+            conn = http.client.HTTPConnection("127.0.0.1", server.port,
+                                              timeout=10)
+            conn.request("POST", "/generate", json.dumps(
+                {"prompt_ids": [1, 2], "max_tokens": 4, "timeout_s": 5.0}))
+            resp = conn.getresponse()
+            return resp.status, resp.read()
+
+        status, body = ask()
+        assert status == 200 and b'"tokens": [1, 2, 3, 4]' in body
+        # 0.6 s over 4 tokens and a backlog of 32 would be 4.8 s of 5
+        # the door observes after the body's last byte: wait for it
+        until = time.monotonic() + 5.0
+        while (server.admission._h_request.quantile(0.5) is None
+               and time.monotonic() < until):
+            time.sleep(0.01)
+        ewma = server.admission.stats()["ewma_query_s"]
+        assert 0.0 < ewma < 0.05
+        assert server.admission._h_request.quantile(0.5) >= 0.5
+        assert ask()[0] == 200  # and the next stream is not shed
+    finally:
+        server.stop(drain_timeout_s=1.0)
