@@ -180,6 +180,42 @@ def _lm_head(params: Params, x: jax.Array) -> jax.Array:
     return logits.astype(jnp.float32)
 
 
+def _dense_layers(blocks: Params, x: jax.Array, ck: jax.Array, cv: jax.Array,
+                  layer) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The one walk over a dense stack's layers at serving, for the ring and
+    the paged forward: a ``lax.scan`` over the stacked ``blocks`` in which
+    ``layer(l, p, x, ck, cv) -> (x, ck, cv)`` runs layer ``l`` on its own
+    leaves ``p`` and threads the K/V planes through as the carry.
+
+    Each leaf passes a rounding to its OWN format on its way into the layer:
+    the identity, bit for bit, on every backend, and what keeps the weights
+    where they are. Without it the TPU compiler moves the rounding of every
+    product's operand (f32 to bf16, the default precision) above the layer's
+    slice and out of the loop, and each call reads the whole stack, writes a
+    bf16 copy and reads that again (GPT-2 large: 6.5 of a decode round's 10.6
+    ms and 1.4 GB of temporaries; PR 33). It does not look through this
+    rounding, so a product's fusion takes the stacked f32 leaf itself and a
+    weight byte crosses the chip's memory once a call;
+    ``tests/test_chip_compile.py`` holds the compiler to that. What does
+    NOT stop the hoist: an ``optimization_barrier``, a partial unroll, a
+    ``fori_loop`` with a dynamic slice or a gather, a clamp, a pair of bit
+    casts. The layers in line do, at 46-74 MB of code a program (12-18 s to
+    compile, seconds to load from the compile cache, five programs a
+    deploy). Training keeps its own scan (``transformer.stack_apply``)."""
+
+    def in_place(a):
+        fmt = jnp.finfo(a.dtype)
+        return jax.lax.reduce_precision(a, fmt.nexp, fmt.nmant)
+
+    def body(carry, at):
+        p, l = at
+        return layer(l, jax.tree_util.tree_map(in_place, p), *carry), None
+
+    carry, _ = jax.lax.scan(body, (x, ck, cv),
+                            (blocks, jnp.arange(ck.shape[0])))
+    return carry
+
+
 def _cached_forward(params: Params, ck: jax.Array, cv: jax.Array,
                     ids: jax.Array, positions: jax.Array, cfg: LMConfig
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -195,14 +231,13 @@ def _cached_forward(params: Params, ck: jax.Array, cv: jax.Array,
     the full-sequence forward."""
     x = _embed_tokens(params, ids, positions, ck.dtype)
 
-    def body(x, layer):
-        p, lk, lv = layer  # block params, (B, L, H, Dh) cache planes
-        x, lk, lv = _cached_block(p, x, lk, lv, positions,
+    def layer(l, p, x, ck, cv):
+        x, lk, lv = _cached_block(p, x, ck[l], cv[l], positions,
                                   cfg.encoder.heads)
-        return x, (lk, lv)
+        return x, ck.at[l].set(lk), cv.at[l].set(lv)
 
-    x, (new_ck, new_cv) = jax.lax.scan(body, x, (params["blocks"], ck, cv))
-    return _lm_head(params, x), new_ck, new_cv
+    x, ck, cv = _dense_layers(params["blocks"], x, ck, cv, layer)
+    return _lm_head(params, x), ck, cv
 
 
 def prefill(params: Params, cache: Cache, slot: jax.Array, ids: jax.Array,
@@ -258,15 +293,17 @@ def decode_step(params: Params, cache: Cache, ids: jax.Array,
 # handed is wide, so a caller chooses how far each program reads (the worker
 # hands a decode round the narrowest of a short ladder of widths that holds
 # its longest live sequence; one compiled program a width). The pool is read
-# and written in place (``_paged_forward``): inside the layer scan, layer l
-# gathers its own blocks through the table into a ``(B, NB*block_tokens, H,
-# Dh)`` view, runs the SAME ``_cached_block`` as the ring path on it (so paged
-# outputs are bit-identical given the same logical contents), and writes
-# ONLY the new rows into the pool; no view of all layers, and no copy of
-# the donated pool, is ever made. Sentinel table entries (>= pool size)
-# gather clipped garbage that the causal mask keeps out of every real
-# query, and their writes are dropped (`mode="drop"`), so idle slots and
-# bucket padding never touch a live block.
+# and written in place (``_paged_forward``): inside the layer scan
+# (``_dense_layers``, the ring's walk too, which also keeps the compiler
+# from copying the stacked weights), layer l gathers its own blocks through
+# the table into a ``(B, NB*block_tokens, H, Dh)`` view, runs the SAME
+# ``_cached_block`` as the ring path on it (so paged outputs are
+# bit-identical given the same logical contents), and writes ONLY the new
+# rows into the pool; no view of all layers, no copy of the donated pool and
+# no copy of the stacked weights is ever made. Sentinel table entries (>=
+# pool size) gather clipped garbage that the causal mask keeps out of every
+# real query, and their writes are dropped (`mode="drop"`), so idle slots
+# and bucket padding never touch a live block.
 
 def init_paged_kv_cache(cfg: LMConfig, pool_blocks: int, block_tokens: int,
                         dtype=jnp.float32) -> Cache:
@@ -297,12 +334,13 @@ def _paged_forward(params: Params, cache: Cache, ids: jax.Array,
                    cfg: LMConfig) -> Tuple[jax.Array, Cache]:
     """The paged prefill/decode/verify forward: ``ids``/``positions``
     (B, T) int32, ``block_tables`` (B, NB) int32 -> (logits (B, T, V) f32,
-    cache). The pool planes ride the layer scan as its carry and are
-    touched one layer at a time, in place: layer ``l`` gathers its own
-    blocks through the table into a (B, NB*BT, H, Dh) view (sentinel
-    entries clip to the last pool block: finite garbage the mask
-    excludes), runs :func:`_cached_block` on it as the ring path does, and
-    writes only the B x T new rows into the pool at (l, block, offset).
+    cache). The pool planes ride the layer scan as its carry
+    (:func:`_dense_layers`) and are touched one layer at a time, in place:
+    layer ``l`` gathers its own blocks through the table into a
+    (B, NB*BT, H, Dh) view (sentinel entries clip to the last pool block:
+    finite garbage the mask excludes), runs :func:`_cached_block` on it as
+    the ring path does, and writes only the B x T new rows into the pool
+    at (l, block, offset).
     Rows that map through a sentinel entry or past the table are dropped,
     never clamped onto a live block."""
     pk, pv = cache["k"], cache["v"]
@@ -318,9 +356,7 @@ def _paged_forward(params: Params, cache: Cache, ids: jax.Array,
     rows = positions.shape + pk.shape[3:]
     x = _embed_tokens(params, ids, positions, pk.dtype)
 
-    def body(carry, layer):
-        x, pk, pv = carry
-        p, l = layer
+    def layer(l, p, x, pk, pv):
         lk = pk.at[l, block_tables].get(mode="clip").reshape(view)
         lv = pv.at[l, block_tables].get(mode="clip").reshape(view)
         x, lk, lv = _cached_block(p, x, lk, lv, positions, heads)
@@ -330,10 +366,9 @@ def _paged_forward(params: Params, cache: Cache, ids: jax.Array,
         v = jnp.take_along_axis(lv, positions[:, :, None, None], axis=1)
         pk = pk.at[l, phys, off].set(k.reshape(rows), mode="drop")
         pv = pv.at[l, phys, off].set(v.reshape(rows), mode="drop")
-        return (x, pk, pv), None
+        return x, pk, pv
 
-    (x, pk, pv), _ = jax.lax.scan(
-        body, (x, pk, pv), (params["blocks"], jnp.arange(pk.shape[0])))
+    x, pk, pv = _dense_layers(params["blocks"], x, pk, pv, layer)
     return _lm_head(params, x), {"k": pk, "v": pv}
 
 

@@ -273,6 +273,120 @@ def test_paged_entry_points_bit_identical_to_ring(entry):
     _assert_pool_tracks_ring(pool, before, ring, tables, front)
 
 
+def _scanned_ring_forward(params, cache, ids, positions, cfg):
+    """The plain reference of the layer walk: `lm._cached_forward` as a
+    `lax.scan` over the stacked leaves and the ring's planes."""
+    import jax
+
+    from rafiki_tpu.models import lm
+
+    def body(x, layer):
+        p, lk, lv = layer
+        x, lk, lv = lm._cached_block(p, x, lk, lv, positions,
+                                     cfg.encoder.heads)
+        return x, (lk, lv)
+
+    x = lm._embed_tokens(params, ids, positions, cache["k"].dtype)
+    x, (ck, cv) = jax.lax.scan(
+        body, x, (params["blocks"], cache["k"], cache["v"]))
+    return lm._lm_head(params, x), {"k": ck, "v": cv}
+
+
+def _scanned_paged_forward(params, cache, ids, positions, tables, cfg):
+    """The same for `lm._paged_forward`: the pool rides the scan's carry,
+    layer `l` gathers its blocks, runs the block, writes its new rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from rafiki_tpu.models import lm
+
+    pk, pv = cache["k"], cache["v"]
+    depth, nbpool, bt, dim = pk.shape
+    b, nb = tables.shape
+    heads = cfg.encoder.heads
+    phys = jnp.take_along_axis(
+        tables, jnp.clip(positions // bt, 0, nb - 1), axis=1)
+    phys = jnp.where(positions < nb * bt, phys, nbpool)
+    off = positions % bt
+
+    def body(carry, layer):
+        x, pk, pv = carry
+        p, l = layer
+        view = (b, nb * bt, heads, dim // heads)
+        lk = pk.at[l, tables].get(mode="clip").reshape(view)
+        lv = pv.at[l, tables].get(mode="clip").reshape(view)
+        x, lk, lv = lm._cached_block(p, x, lk, lv, positions, heads)
+        at = positions[:, :, None, None]
+        k = jnp.take_along_axis(lk, at, axis=1).reshape(*positions.shape, dim)
+        v = jnp.take_along_axis(lv, at, axis=1).reshape(*positions.shape, dim)
+        return (x, pk.at[l, phys, off].set(k, mode="drop"),
+                pv.at[l, phys, off].set(v, mode="drop")), None
+
+    x = lm._embed_tokens(params, ids, positions, pk.dtype)
+    (x, pk, pv), _ = jax.lax.scan(
+        body, (x, pk, pv), (params["blocks"], jnp.arange(depth)))
+    return lm._lm_head(params, x), {"k": pk, "v": pv}
+
+
+@pytest.mark.parametrize("depth", [1, 2, 5])
+@pytest.mark.parametrize("layout", ["ring", "paged"])
+def test_the_layer_walk_returns_the_bits_of_a_plain_scan(layout, depth):
+    """`lm._dense_layers` hands each layer its leaves through a rounding to
+    their own format (what keeps the TPU compiler from copying the stack)
+    and carries the ring's planes as it does the pool's; a plain `lax.scan`
+    over the same `_cached_block` (the form before PR 33, kept here as the
+    reference) gives the same logits and the same cache, bit for bit, for a
+    chunk of a prompt (T = 8) and for decode rounds (T = 1) after it, an
+    idle slot among the live."""
+    import jax
+    import jax.numpy as jnp
+
+    from rafiki_tpu.models import lm
+
+    cfg = lm.tiny(vocab=64, max_len=_NB * _BT, dim=16, depth=depth, heads=2)
+    params = lm.init(jax.random.PRNGKey(7), cfg)
+    rng = np.random.default_rng(depth)
+    tables = np.full((3, _NB), _POOL, np.int32)
+    tables[0, :2], tables[1, :2] = (4, 7), (1, 2)
+    if layout == "ring":
+        cache = jax.tree.map(
+            lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+            lm.init_kv_cache(cfg, max_slots=3, max_len=_NB * _BT))
+
+        def walked(c, ids, pos):
+            lg, ck, cv = lm._cached_forward(params, c["k"], c["v"], ids, pos,
+                                            cfg)
+            return lg, {"k": ck, "v": cv}
+
+        def scanned(c, ids, pos):
+            return _scanned_ring_forward(params, c, ids, pos, cfg)
+    else:
+        cache = jax.tree.map(
+            lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+            lm.init_paged_kv_cache(cfg, _POOL, _BT))
+
+        def walked(c, ids, pos):
+            return lm._paged_forward(params, c, ids, pos, tables, cfg)
+
+        def scanned(c, ids, pos):
+            return _scanned_paged_forward(params, c, ids, pos,
+                                          jnp.asarray(tables), cfg)
+
+    walked, scanned = jax.jit(walked), jax.jit(scanned)
+    mine = theirs = cache
+    ids = rng.integers(0, 64, size=(3, 8)).astype(np.int32)
+    pos = np.tile(np.arange(8, dtype=np.int32), (3, 1))
+    for _ in range(4):                # the chunk, then three decode rounds
+        lg_i, mine = walked(mine, ids, pos)
+        lg_s, theirs = scanned(theirs, ids, pos)
+        assert np.array_equal(np.asarray(lg_i), np.asarray(lg_s))
+        for name in ("k", "v"):
+            assert np.array_equal(np.asarray(mine[name]),
+                                  np.asarray(theirs[name])), name
+        ids = np.asarray(lm.greedy_token(lg_i))[:, -1:]
+        pos = pos[:, -1:] + 1
+
+
 def _fixture(module):
     sys.path.insert(0, HERE)
     try:
