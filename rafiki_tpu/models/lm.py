@@ -699,7 +699,7 @@ def batch_spec() -> Any:
 # -- a stack of layer kinds: state-space, expert and attention layers ---------
 #
 # A hybrid language model's layer is ONE mixer: ``x <- x + mixer(RMSNorm(x))``
-# with the mixer one of five kinds, in the order ``pattern`` gives, each layer
+# with the mixer one of seven kinds, in the order ``pattern`` gives, each layer
 # with its own leaves (a published layer of a mixer and a feed-forward is two
 # entries of the pattern):
 #   ``M``  a Mamba-2 scan (ops/mamba2.py);
@@ -708,20 +708,30 @@ def batch_spec() -> Any:
 #   ``G``  the same attention with RMSNorm over a head of queries and of keys,
 #          rotary positions on a head's first ``rotary_dim`` and a sigmoid gate
 #          on its output;
+#   ``L``  latent attention (ops/mla.py): queries and a key/value latent
+#          through low-rank projections with their own norms, one rotary key
+#          for all heads;
 #   ``E``  a sparse-expert feed-forward (parallel/moe.py), whose variant (the
 #          router's score, correction bias and scale, the activation, gated
-#          experts or not, the shared expert's own gate) the config states.
-# The decode cache is a group a stateful kind: paged keys and values
-# ``k``/``v`` ``(attention layers, blocks, tokens, kv_heads * head_dim)`` for
-# ``*`` and ``G`` together, behind the same block tables as the dense model's
-# pool; a fixed state a SLOT for ``M`` (``conv``: the convolution's last
-# inputs, ``h``: the SSM state) and for ``D`` (``delta_conv``: the same
-# window, ``delta_s``: a key-by-value matrix a head), all f32 and there only
-# where the pattern holds the kind; nothing for ``E``. So the forward is told
-# which slot each sequence is: prefill continues the slot's state from a
-# chunk's ``start`` and starts from zero at ``start == 0``; a decode row whose
-# table is all sentinel (an idle or still-prefilling slot) moves no state and
-# reads no expert.
+#          experts or not, the shared expert's own gate) the config states;
+#   ``F``  a dense feed-forward alone, of the experts' form (their activation,
+#          gated or not) and ``dense_ffn`` wide.
+# The decode cache is a group a stateful kind. Two kinds of group are paged,
+# ``(layers of the kind, blocks, tokens, row width)`` behind ONE block table a
+# slot, the same tables as the dense model's pool: keys and values ``k``/``v``
+# of ``kv_heads * head_dim`` for ``*`` and ``G`` together, and ``latent`` of
+# ``kv_rank + rope_dim`` for ``L``, one array where full heads have two (the
+# row is the latent after its norm and the rotary key after its turn, and
+# zeros up to a multiple of the chip's 128 lanes: ops/mla.py says why). A
+# fixed state a SLOT for ``M`` (``conv``: the convolution's last inputs,
+# ``h``: the SSM state) and for ``D`` (``delta_conv``: the same window,
+# ``delta_s``: a key-by-value matrix a head), all f32. Each group is there
+# only where the pattern holds its kind; nothing for ``E`` and ``F``. So the
+# forward is told which slot each sequence is where the pattern holds a
+# stateful kind (without one it needs no slot and the cache no slot count):
+# prefill continues the slot's state from a chunk's ``start`` and starts from
+# zero at ``start == 0``; a decode row whose table is all sentinel (an idle
+# or still-prefilling slot) moves no state and reads no expert.
 
 @dataclass(frozen=True)
 class HybridConfig:
@@ -731,6 +741,7 @@ class HybridConfig:
     pattern: str = "MEM*E"
     mamba: Any = None                 # ops.mamba2.Mamba2Config, for ``M``
     delta: Any = None                 # ops.gated_delta.GatedDeltaConfig, ``D``
+    mla: Any = None                   # ops.mla.MLAConfig, for ``L``
     q_heads: int = 4
     kv_heads: int = 2
     head_dim: int = 16
@@ -740,6 +751,7 @@ class HybridConfig:
     top_k: int = 2
     ffn: int = 32
     shared_ffn: int = 64
+    dense_ffn: int = 128              # of ``F``
     route_score: str = "sigmoid"      # or "softmax"
     route_bias: bool = True           # a correction bias moves the choice
     route_scale: float = 2.5
@@ -753,8 +765,9 @@ class HybridConfig:
         return sum(self.pattern.count(kind) for kind in kinds)
 
 
-HYBRID_KINDS = ("M", "D", "E", "*", "G")
+HYBRID_KINDS = ("M", "D", "E", "*", "G", "L", "F")
 ATTENTION_KINDS = "*G"                # they share the paged pool
+PAGED_GROUPS = ("k", "v", "latent")   # rows a token a layer, behind the tables
 # a stateful kind: (its named scope, its mixer's state name -> cache group)
 _RECURRENT = {"M": ("ssm", {"conv": "conv", "h": "h"}),
               "D": ("delta", {"conv": "delta_conv", "s": "delta_s"})}
@@ -790,10 +803,21 @@ def hybrid_layer_init(rng: jax.Array, kind: str, cfg: HybridConfig,
         init = gqa_init if kind == "*" else gated_gqa_init
         return {"norm": norm, **init(rng, cfg.dim, cfg.q_heads, cfg.kv_heads,
                                      cfg.head_dim, dtype)}
+    if kind == "L":
+        from rafiki_tpu.ops.mla import mla_init
+
+        return {"norm": norm, **mla_init(rng, cfg.mla, dtype)}
     kr, kb, ku, kd, su, sd = jax.random.split(rng, 6)
     count = cfg.held[1]
     into = cfg.dim ** -0.5  # by fan-in
     ups = 2 if cfg.expert_gated else 1  # [W_gate | W_up] side by side
+    if kind == "F":
+        return {"norm": norm,
+                "w_up": core.normal_init(ku, (cfg.dim, ups * cfg.dense_ffn),
+                                         std=into, dtype=dtype),
+                "w_down": core.normal_init(kd, (cfg.dense_ffn, cfg.dim),
+                                           std=cfg.dense_ffn ** -0.5,
+                                           dtype=dtype)}
     p = {
         "norm": norm,
         "router": core.normal_init(kr, (cfg.dim, cfg.n_experts), std=into),
@@ -835,16 +859,27 @@ def hybrid_init(rng: jax.Array, cfg: HybridConfig,
 
 
 def init_hybrid_cache(cfg: HybridConfig, pool_blocks: int, block_tokens: int,
-                      max_slots: int, kv_dtype=jnp.bfloat16) -> Cache:
-    """The cache's groups: ``k``/``v`` the attention layers' paged pool,
-    and for each stateful kind the pattern holds its layers' state for each
+                      max_slots: int = 0, kv_dtype=jnp.bfloat16) -> Cache:
+    """The cache's groups, each there where the pattern holds its kind:
+    ``k``/``v`` the attention layers' paged pool and ``latent`` the latent
+    layers' (a pattern with neither keeps an empty ``k``/``v``, which says
+    the pool's shape), and for each stateful kind its layers' state for each
     of ``max_slots``: ``conv``/``h`` (``M``), ``delta_conv``/``delta_s``
-    (``D``)."""
-    kv = (cfg.count(ATTENTION_KINDS), int(pool_blocks), int(block_tokens),
-          cfg.kv_heads * cfg.head_dim)
-    cache = {"k": jnp.zeros(kv, kv_dtype), "v": jnp.zeros(kv, kv_dtype)}
+    (``D``). A pattern without a stateful kind takes no ``max_slots``."""
+    paged = lambda layers, width: jnp.zeros(
+        (layers, int(pool_blocks), int(block_tokens), width), kv_dtype)
+    cache = {}
+    if cfg.count(ATTENTION_KINDS) or not cfg.count("L"):
+        kv = (cfg.count(ATTENTION_KINDS), cfg.kv_heads * cfg.head_dim)
+        cache.update(k=paged(*kv), v=paged(*kv))
+    if cfg.count("L"):
+        cache["latent"] = paged(cfg.count("L"), cfg.mla.cache_row)
     for kind, (_, groups) in _RECURRENT.items():
         if cfg.count(kind):
+            if int(max_slots) < 1:
+                raise ValueError(
+                    f"pattern {cfg.pattern!r} holds the stateful kind "
+                    f"{kind!r}: its cache needs the number of slots")
             _, mixer_cfg, state_init = _recurrent_mixer(kind, cfg)
             cache.update({groups[name]: jnp.zeros(
                 (cfg.count(kind),) + a.shape, a.dtype) for name, a in
@@ -852,31 +887,44 @@ def init_hybrid_cache(cfg: HybridConfig, pool_blocks: int, block_tokens: int,
     return cache
 
 
+def _paged(cache: Cache) -> Dict[str, jax.Array]:
+    """The cache's paged groups: what a block table maps and copy-on-write
+    copies."""
+    return {name: cache[name] for name in PAGED_GROUPS if name in cache}
+
+
+def hybrid_pool_shape(cache: Cache) -> Tuple[int, int]:
+    """(blocks, tokens a block) of the paged pool, whichever groups it has."""
+    return next(iter(_paged(cache).values())).shape[1:3]
+
+
 def hybrid_state_bytes(cache: Cache) -> int:
-    """Bytes of the per-slot recurrent state: every group of the cache but
-    the paged pool (``conv``, ``h``, ``delta_conv``, ``delta_s``, as far as
-    the pattern holds their kinds)."""
+    """Bytes of the per-slot recurrent state (``conv``, ``h``,
+    ``delta_conv``, ``delta_s``, as far as the pattern holds their kinds):
+    no paged group is state, whatever rows it holds."""
     return int(sum(a.nbytes for name, a in cache.items()
-                   if name not in ("k", "v")))
+                   if name not in PAGED_GROUPS))
 
 
 def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
                     positions: jax.Array, block_tables: jax.Array,
                     slots: Optional[jax.Array], lengths: jax.Array,
-                    reset: jax.Array, cfg: HybridConfig
+                    reset: jax.Array, cfg: HybridConfig,
+                    turn_rows: bool = True
                     ) -> Tuple[jax.Array, Cache, Dict[str, jax.Array]]:
     """ids/positions (B, T), block_tables (B, NB), slots (B,) the state rows
     of the sequences (None: row i is slot i and every slot is a row, so a
     layer's state is read and written in place, with no gather and no
     scatter of it), lengths (B,) how many of the T tokens are real (0: an
     idle row, which moves no state), reset (B,) bool: start from a zero
-    state. Returns (x (B, T, D) f32 before the last norm, cache, counts of
-    the expert layers summed over them)."""
+    state; ``turn_rows=False`` writes the latent rows without their rotary
+    turn (a test's fault). Returns (x (B, T, D) f32 before the last norm,
+    cache, counts of the expert layers summed over them)."""
     from rafiki_tpu.ops.attention import gqa_cached, rotary
     from rafiki_tpu.parallel.moe import expert_layer, ffn
 
     b, t = ids.shape
-    nbpool, bt = cache["k"].shape[1], cache["k"].shape[2]
+    nbpool, bt = hybrid_pool_shape(cache)
     nb = block_tables.shape[1]
     phys = jnp.take_along_axis(
         block_tables, jnp.clip(positions // bt, 0, nb - 1), axis=1)
@@ -941,12 +989,77 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
             x = x + out
         return x, cache
 
+    def latent(p, x, cache, l):
+        from rafiki_tpu.ops.mla import mla_attend, mla_project
+
+        with jax.named_scope("latent"):
+            u = core.rmsnorm(p["norm"], x, cfg.eps)
+            pool = cache["latent"]
+            wide = cfg.mla.cache_row  # the row, and zeros up to the lanes
+            q_nope, q_rope, rows = mla_project(p, u, positions, cfg.mla,
+                                               turn_rows)
+            rows = jnp.pad(rows.astype(pool.dtype),
+                           ((0, 0), (0, 0), (0, wide - cfg.mla.row)))
+
+            # the last real token's position: no row past it is read
+            reach = jnp.max(jnp.where(
+                jnp.arange(t)[None, :] < lengths[:, None], positions, 0))
+
+            def over(width):
+                """The product over a view of the table's first `width`
+                blocks, and the new rows as the view holds them."""
+                def attend(_):
+                    view = pool.at[l, block_tables[:, :width]].get(
+                        mode="clip").reshape(b, width * bt, wide)
+                    view = view.at[batch_ix, positions].set(rows,
+                                                            mode="drop")
+                    out = mla_attend(p, q_nope, q_rope, view, positions,
+                                     cfg.mla, last=reach)
+                    # the rows come back out of the view (as the attention
+                    # kinds'), so that the pool's write follows its read and
+                    # needs no copy; a padding row past the view is written
+                    # as it was made
+                    back = jnp.take_along_axis(
+                        view, jnp.minimum(positions, width * bt - 1)[
+                            :, :, None], axis=1)
+                    return out, jnp.where(
+                        (positions < width * bt)[:, :, None], back, rows)
+                return attend
+
+            # A chunk of many tokens reads no further than its last real
+            # token reaches: of a short ladder of widths (the table's, halved
+            # down to a sixteenth) the narrowest that holds it, chosen in the
+            # program, so that a prompt's first chunks do not pay for the
+            # served context. One query a sequence takes the table as it is
+            # handed (the worker cuts a decode round's).
+            widths = sorted({w for w in (nb // 16, nb // 8, nb // 4, nb // 2,
+                                         nb) if w * bt >= t})
+            if t == 1 or len(widths) == 1:
+                out, back = over(nb)(None)
+            else:
+                out, back = jax.lax.switch(
+                    sum((reach >= w * bt).astype(jnp.int32)
+                        for w in widths[:-1]),
+                    [over(w) for w in widths], None)
+            cache = {**cache, "latent": pool.at[l, phys, off].set(
+                back, mode="drop")}
+            x = x + out
+        return x, cache
+
+    act = _EXPERT_ACTS[cfg.expert_act]
+
+    def dense(p, x):
+        with jax.named_scope("mlp"):
+            u = core.rmsnorm(p["norm"], x, cfg.eps).reshape(b * t, cfg.dim)
+            out = ffn(u, p["w_up"], p["w_down"], act, cfg.expert_gated)
+            x = x + out.reshape(b, t, cfg.dim)
+        return x
+
     def experts(p, x, counts):
         with jax.named_scope("moe"):
             u = core.rmsnorm(p["norm"], x, cfg.eps).reshape(b * t, cfg.dim)
             # the loop over the experts hit reads each one's two matrices
             # in place: only the experts a token chose are touched
-            act = _EXPERT_ACTS[cfg.expert_act]
             routed, c = expert_layer(
                 p, u, cfg.top_k, held=cfg.held, score=cfg.route_score,
                 scale=cfg.route_scale, act=act, gated=cfg.expert_gated,
@@ -976,6 +1089,10 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
             x, cache = recurrent(kind, p, x, cache, row)
         elif kind in ATTENTION_KINDS:
             x, cache = attention(kind, p, x, cache, row)
+        elif kind == "L":
+            x, cache = latent(p, x, cache, row)
+        elif kind == "F":
+            x = dense(p, x)
         else:
             x, counts = experts(p, x, counts)
         at[group] = row + 1
@@ -992,13 +1109,18 @@ def _hybrid_head(params: Params, x: jax.Array, cfg: HybridConfig) -> jax.Array:
 
 def hybrid_paged_prefill(params: Params, cache: Cache, block_table: jax.Array,
                          ids: jax.Array, start: jax.Array, length: jax.Array,
-                         slot: jax.Array, cfg: HybridConfig,
-                         reset: Optional[jax.Array] = None
-                         ) -> Tuple[jax.Array, Cache]:
+                         slot: Optional[jax.Array], cfg: HybridConfig,
+                         reset: Optional[jax.Array] = None,
+                         turn_rows: bool = True) -> Tuple[jax.Array, Cache]:
     """:func:`paged_prefill` for a hybrid stack: the chunk continues
     ``slot``'s recurrent state, from zero where ``start == 0`` (``reset``
-    overrides that, for a test of what a stale state does). Returns
-    (logits (V,) at the chunk's last real position, cache)."""
+    overrides that, and ``turn_rows=False`` leaves the latent rows
+    unturned, for tests of what a stale state and a wrong row do). A pattern
+    without a stateful kind takes ``slot=None``. Returns (logits (V,) at the
+    chunk's last real position, cache)."""
+    if slot is None and cfg.count("".join(_RECURRENT)):
+        raise ValueError(f"pattern {cfg.pattern!r} holds a stateful kind: "
+                         "its prefill needs the slot")
     ids = jnp.asarray(ids, jnp.int32)[None]
     start = jnp.asarray(start, jnp.int32)
     length = jnp.asarray(length, jnp.int32)
@@ -1007,7 +1129,8 @@ def hybrid_paged_prefill(params: Params, cache: Cache, block_table: jax.Array,
     x, cache, _ = _hybrid_forward(
         params, cache, ids, positions,
         jnp.asarray(block_table, jnp.int32)[None],
-        jnp.asarray(slot, jnp.int32)[None], length[None], reset[None], cfg)
+        None if slot is None else jnp.asarray(slot, jnp.int32)[None],
+        length[None], reset[None], cfg, turn_rows)
     return _hybrid_head(params, x[0, length - 1], cfg), cache
 
 
@@ -1021,7 +1144,7 @@ def hybrid_paged_decode_step(params: Params, cache: Cache, ids: jax.Array,
     Returns (logits (S, V), cache, the expert layers' counts)."""
     block_tables = jnp.asarray(block_tables, jnp.int32)
     s = block_tables.shape[0]
-    live = block_tables[:, 0] < cache["k"].shape[1]
+    live = block_tables[:, 0] < hybrid_pool_shape(cache)[0]
     x, cache, counts = _hybrid_forward(
         params, cache, jnp.asarray(ids, jnp.int32)[:, None],
         jnp.asarray(positions, jnp.int32)[:, None], block_tables, None,
@@ -1045,6 +1168,9 @@ def hybrid_apply(params: Params, ids: jax.Array, cfg: HybridConfig
 
 def copy_hybrid_kv_blocks(cache: Cache, src: jax.Array,
                           dst: jax.Array) -> Cache:
-    """:func:`copy_kv_blocks` over the paged group; the state has no blocks."""
-    return {**cache, **copy_kv_blocks({"k": cache["k"], "v": cache["v"]},
-                                      src, dst)}
+    """:func:`copy_kv_blocks` over every paged group (``k``/``v``,
+    ``latent``); the state has no blocks."""
+    src = jnp.asarray(src, jnp.int32)
+    dst = jnp.asarray(dst, jnp.int32)
+    return {**cache, **{name: a.at[:, dst].set(jnp.take(a, src, axis=1))
+                        for name, a in _paged(cache).items()}}

@@ -21,7 +21,9 @@ training). ``gather``: a loop over the experts that a token chose, reading
 only those experts' weights in place, which is what serving wants: a decode
 round of 16 tokens at 6 of 128 hits about half of 64 held experts, and the
 weights are most of the round's bytes (a prefill chunk hits them all and
-still reads each once).
+still reads each once). Many tokens (four groups of ``GROUP_ROWS`` and
+more: a prefill chunk of 512) are first laid out expert by expert, so that
+the loop runs a token through the experts it chose alone (``_grouped``).
 
 ``moe_apply`` is the ``k = 1`` softmax case with the Switch load-balancing
 loss (Fedus et al. 2021, eq. 4) for the trainer.
@@ -40,6 +42,7 @@ from rafiki_tpu.models.core import normal_init, relu2
 
 Params = Dict[str, Any]
 HIGHEST = jax.lax.Precision.HIGHEST
+GROUP_ROWS = 128  # tokens of one expert taken at once: a tile's sublanes x 16
 
 
 def route(x: jax.Array, router: jax.Array, k: int, *,
@@ -78,13 +81,17 @@ def expert_products(x: jax.Array, gates: jax.Array, w_up: jax.Array,
                     w_down: jax.Array, *, b_up: Optional[jax.Array] = None,
                     b_down: Optional[jax.Array] = None,
                     act: Callable = relu2, gated: bool = False,
-                    gather: bool = False) -> jax.Array:
+                    gather: bool = False, top: Optional[int] = None,
+                    rows: int = GROUP_ROWS) -> jax.Array:
     """sum_e gates[:, e] * (act(x W_up[e] + b_up[e]) W_down[e] + b_down[e])
     over the E' experts of ``gates``: x (N, D), gates (N, E'), w_up
     (E', D, F), w_down (E', F, D) -> (N, D) f32. ``gated``: w_up is
     (E', D, 2F), ``[W_gate | W_up]``, and the hidden layer is
     ``act(x W_gate) * (x W_up)``. Operands in the weights' dtype,
-    accumulation in f32."""
+    accumulation in f32. ``top``: the most experts a token chose, where the
+    caller knows it; the gathered form then takes many tokens (four times
+    ``rows`` and more) expert by expert in groups of ``rows``, each token
+    through its own experts only."""
     xw = x.astype(w_up.dtype)
     hidden = _gated(act) if gated else act
     if not gather:
@@ -100,6 +107,9 @@ def expert_products(x: jax.Array, gates: jax.Array, w_up: jax.Array,
         return y
     if b_up is not None or b_down is not None:
         raise ValueError("the gathered form has no biases")
+    if top is not None and x.shape[0] >= 4 * rows:
+        return _grouped(xw, gates, w_up, w_down, hidden,
+                        min(top, gates.shape[1]), rows)
     hit = jnp.any(gates > 0.0, axis=0)                        # (E',)
     order = jnp.argsort(~hit, stable=True)                    # hit ones first
 
@@ -115,6 +125,56 @@ def expert_products(x: jax.Array, gates: jax.Array, w_up: jax.Array,
 
     return jax.lax.fori_loop(0, jnp.sum(hit), one,
                              jnp.zeros(x.shape, jnp.float32))
+
+
+def _grouped(xw: jax.Array, gates: jax.Array, w_up: jax.Array,
+             w_down: jax.Array, hidden: Callable, top: int, rows: int
+             ) -> jax.Array:
+    """The gathered form for many tokens: a token goes through the experts
+    it chose and no other. The (token, expert) choices are laid out expert
+    after expert in a buffer, each expert's padded to a multiple of ``rows``
+    (at most ``N * top / rows + E'`` groups, a static bound; none dropped:
+    an expert with more tokens takes more groups), the loop runs over the
+    groups that hold a token, reading each expert's weights in place once a
+    group, and a token's rows are summed back. With 512 tokens at 4 of 64
+    the loop over the experts hit ran all 512 through each of 32 held
+    experts, sixteen times the products (PERF.md, PR 34)."""
+    n, held = gates.shape
+    weight, expert = jax.lax.top_k(gates, top)                # (N, top)
+    chosen = weight > 0.0
+    chose = gates > 0.0
+    groups = -(-jnp.sum(chose, axis=0, dtype=jnp.int32) // rows)      # (E',)
+    ends = jnp.cumsum(groups)
+    # a token's place among its expert's
+    rank = jnp.cumsum(chose, axis=0, dtype=jnp.int32) - 1
+    n_rows = (-(-n * top // rows) + held) * rows
+    place = jnp.take_along_axis(
+        ((ends - groups) * rows)[None, :] + rank, expert, axis=1)
+    place = jnp.where(chosen, place, n_rows).reshape(-1)      # (N * top,)
+    token = jnp.full((n_rows,), n, jnp.int32).at[place].set(
+        jnp.repeat(jnp.arange(n, dtype=jnp.int32), top), mode="drop",
+        unique_indices=True)
+    scale = jnp.zeros((n_rows,), jnp.float32).at[place].set(
+        weight.reshape(-1), mode="drop", unique_indices=True)
+    xs = jnp.take(xw, token, axis=0, mode="fill", fill_value=0)
+
+    def one(i, out):
+        e = jnp.searchsorted(ends, i, side="right").astype(jnp.int32)
+        up = jax.lax.dynamic_index_in_dim(w_up, e, keepdims=False)
+        down = jax.lax.dynamic_index_in_dim(w_down, e, keepdims=False)
+        at = i * rows
+        h = hidden(jnp.dot(jax.lax.dynamic_slice_in_dim(xs, at, rows), up,
+                           preferred_element_type=jnp.float32))
+        h = h * jax.lax.dynamic_slice_in_dim(scale, at, rows)[:, None]
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.dot(h.astype(down.dtype), down,
+                         preferred_element_type=jnp.float32), at, axis=0)
+
+    out = jax.lax.fori_loop(0, ends[-1], one,
+                            jnp.zeros((n_rows, xw.shape[1]), jnp.float32))
+    back = jnp.take(out, place.reshape(n, top), axis=0, mode="fill",
+                    fill_value=0)                             # (N, top, D)
+    return jnp.sum(back, axis=1)
 
 
 def expert_layer(p: Params, x: jax.Array, k: int, *,
@@ -139,7 +199,7 @@ def expert_layer(p: Params, x: jax.Array, k: int, *,
         gates = jnp.where(live[:, None], gates, 0.0)
     gates = jax.lax.dynamic_slice_in_dim(gates, first, count, axis=1)
     y = expert_products(x, gates, p["w_up"], p["w_down"], act=act,
-                        gated=gated, gather=gather)
+                        gated=gated, gather=gather, top=k)
     chosen = gates > 0.0
     return y, {"expert_tokens": jnp.sum(chosen, dtype=jnp.int32),
                "experts_hit": jnp.sum(jnp.any(chosen, axis=0),

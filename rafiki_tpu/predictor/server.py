@@ -630,6 +630,11 @@ class PredictorServer:
                 emit(TokenDelta([], finished=True, reason="error",
                                 error=str(e)))
                 break
+            if not delta.tokens and not delta.finished:
+                # the worker's sign of life between the chunks of a long
+                # prompt: it restarts the stall window and is not the
+                # client's to see
+                continue
             served += len(delta.tokens)
             if not emit(delta):
                 return served

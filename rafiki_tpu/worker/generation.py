@@ -218,7 +218,20 @@ def _metrics():
             "state_bytes": REGISTRY.gauge(
                 "rafiki_gen_state_bytes",
                 "bytes of per-slot recurrent state the decode cache holds "
-                "beside its keys and values", ("service",)),
+                "beside its paged rows (unset for a model without one)",
+                ("service",)),
+            "kv_row_bytes": REGISTRY.gauge(
+                "rafiki_gen_kv_row_bytes",
+                "bytes a token leaves in the paged pool over all layers: "
+                "keys and values of full heads, or a latent row a layer",
+                ("service",)),
+            "prefill_chunks": REGISTRY.counter(
+                "rafiki_gen_prefill_chunks_total",
+                "prefill chunks dispatched to the model, final or not"),
+            "prefill_chunk_tokens": REGISTRY.counter(
+                "rafiki_gen_prefill_chunk_tokens_total",
+                "real prompt tokens of the prefill chunks dispatched (the "
+                "padding to a bucket is the template's and not counted)"),
             "expert_tokens": REGISTRY.counter(
                 "rafiki_gen_expert_tokens_total",
                 "(token, expert) choices that fell on an expert held here, "
@@ -372,9 +385,20 @@ class GenerationWorker(InferenceWorker):
                     self._chunk)
             else:
                 cache = model.init_kv_cache(max_slots)
+            state_bytes = (int(model.recurrent_state_bytes(cache))
+                           if self._recurrent else 0)
             if self._recurrent:
                 _metrics()["state_bytes"].labels(ctx.service_id).set(
-                    int(model.recurrent_state_bytes(cache)))
+                    state_bytes)
+            if self._alloc is not None:
+                # what is not state is the pool: every paged group's rows
+                import jax
+
+                pool_bytes = sum(int(a.nbytes) for a in
+                                 jax.tree_util.tree_leaves(cache)) \
+                    - state_bytes
+                _metrics()["kv_row_bytes"].labels(ctx.service_id).set(
+                    pool_bytes // (pool_blocks * block_tokens))
             self._init_spec(model, spec, max_slots, ctx)
             # pre-warm per-bucket prefill + decode programs under the
             # persistent compile cache, before ctx.ready(): a still-
@@ -988,14 +1012,20 @@ class GenerationWorker(InferenceWorker):
         # final, or a sampled stream's, whose token is dropped) is an
         # asynchronous dispatch, and its device time lands under the next
         # gen.decode.device.
-        with trace.span("gen.prefill_chunk"):
+        m = _metrics()
+        m["prefill_chunks"].inc()
+        m["prefill_chunk_tokens"].inc(len(chunk_tokens))
+        with trace.span("gen.prefill_chunk",
+                        chunk=(start // self._chunk if self._chunk > 0
+                               else 0),
+                        table_blocks=self._alloc.table_blocks):
             args = (cache, self._alloc.table_row(slot_ix),
                     list(chunk_tokens), int(start))
             if self._recurrent:
                 # the chunk continues THIS slot's state; at 0 it starts anew
                 tok, cache = model.paged_prefill(*args, slot_ix)
                 if start == 0:
-                    _metrics()["state_resets"].inc()
+                    m["state_resets"].inc()
             else:
                 tok, cache = model.paged_prefill(*args)
             if end == n and slot.temperature <= 0.0:
@@ -1003,6 +1033,10 @@ class GenerationWorker(InferenceWorker):
         slot.pending_from = end
         slot.position = end
         if end < n:
+            # a sign of life: a long prompt takes many rounds to its first
+            # token, and the door ends a stream it hears nothing from
+            # (RAFIKI_GEN_STREAM_TIMEOUT_S); it forwards no empty delta
+            slot.stream.push([], finished=False)
             return True, cache
         if slot.temperature > 0.0:
             # sampled stream: prefill's token is the greedy pick — do not
@@ -1020,7 +1054,6 @@ class GenerationWorker(InferenceWorker):
         slot.last_id = tok
         slot.produced += 1
         slot.tokens.append(tok)
-        m = _metrics()
         now = time.monotonic()
         if slot.t0 is not None:
             m["ttft"].observe(now - slot.t0)

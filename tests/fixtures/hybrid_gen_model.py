@@ -6,6 +6,9 @@ count and ``paged_prefill`` the slot. float32 weights from a fixed key, so
 that greedy decode is exact and two runs of one prompt agree to the token.
 ``TinyDeltaLM`` is the same template over the stack's other kinds: the gated
 delta rule, gated rotary attention, gated softmax-routed experts.
+``TinyLatentLM`` is the stack without a stateful kind (latent attention, a
+dense MLP, sigmoid-routed experts): its spec declares no state, so the worker
+hands it no slot and no slot count, and the prefix cache serves its prompts.
 """
 
 import jax
@@ -15,6 +18,7 @@ import numpy as np
 from rafiki_tpu.models import lm
 from rafiki_tpu.ops.gated_delta import GatedDeltaConfig
 from rafiki_tpu.ops.mamba2 import Mamba2Config
+from rafiki_tpu.ops.mla import MLAConfig
 from rafiki_tpu.sdk import BaseModel, FixedKnob, GenerationSpec
 
 VOCAB = 64
@@ -34,6 +38,12 @@ DELTA_CFG = lm.HybridConfig(
     n_experts=8, top_k=3, ffn=16, shared_ffn=16, route_score="softmax",
     route_bias=False, route_scale=1.0, expert_act="silu", expert_gated=True,
     shared_gate=True, held=(0, 4), eps=1e-6)
+LATENT_CFG = lm.HybridConfig(
+    vocab=VOCAB, max_len=MAX_CONTEXT, dim=DIM, pattern="LFLE",
+    mla=MLAConfig(dim=DIM, heads=4, q_rank=16, kv_rank=8, nope_dim=4,
+                  rope_dim=4, v_dim=8),
+    n_experts=8, top_k=2, ffn=16, shared_ffn=16, dense_ffn=48,
+    route_scale=1.8, expert_act="silu", expert_gated=True, held=(0, 4))
 BUCKETS = (8, 16, 32, MAX_CONTEXT)
 RING_BLOCK = 8
 
@@ -135,3 +145,38 @@ class TinyHybridLM(BaseModel):
 
 class TinyDeltaLM(TinyHybridLM):
     cfg = DELTA_CFG
+
+
+class TinyLatentLM(TinyHybridLM):
+    """No recurrent state: the paged methods take no slot."""
+    generation_spec = GenerationSpec(eos_token_id=None,
+                                     max_context=MAX_CONTEXT)
+    cfg = LATENT_CFG
+
+    def init_kv_cache(self, max_slots):
+        per_slot = -(-MAX_CONTEXT // RING_BLOCK)
+        self._ring_tables = np.arange(max_slots * per_slot,
+                                      dtype=np.int32).reshape(max_slots, -1)
+        return self.init_paged_kv_cache(max_slots * per_slot, RING_BLOCK)
+
+    def prefill(self, cache, slot, prompt_ids):
+        return self.paged_prefill(cache, self._ring_tables[slot], prompt_ids,
+                                  0)
+
+    def init_paged_kv_cache(self, pool_blocks, block_tokens):
+        return lm.init_hybrid_cache(self.cfg, pool_blocks, block_tokens,
+                                    kv_dtype=jnp.float32)
+
+    def __init__(self, **knobs):
+        super().__init__(**knobs)
+        self._prefill = jax.jit(
+            lambda p, c, bt, i, st, m: lm.hybrid_paged_prefill(
+                p, c, bt, i, st, m, None, self.cfg))
+
+    def paged_prefill(self, cache, block_table, prompt_ids, start):
+        self.prefills.append((int(start), None))
+        ids, n = _pad(prompt_ids)
+        logits, cache = self._prefill(
+            self._params, cache, np.asarray(block_table, np.int32), ids,
+            np.int32(start), np.int32(n))
+        return int(lm.greedy_token(logits)), cache

@@ -127,3 +127,67 @@ def test_dense_serving_program_reads_its_weights_in_place(one_chip, program,
     stacked = re.findall(r"= bf16\[36,[^\n]* convert\(", compiled.as_text())
     assert not stacked, stacked
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("rows", [1024, 16384])
+def test_latent_prefill_kernel_compiles_for_v5e(one_chip, rows):
+    """ops/mla.py `attend_rows` at GLM-4.7-Flash's widths: 20 heads, a
+    chunk of 512 queries 256 wide, values 256 wide, blocks of 512 rows, the
+    chunk's position prefetched as scalars: one Mosaic kernel."""
+    from rafiki_tpu.ops import mla
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compile(
+        lambda q, kv, a, b: mla.attend_rows(q, kv, a, b, 1 / 16),
+        on((20, 512, 256)), on((20, 512, rows)),
+        on((), jnp.int32), on((), jnp.int32))
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_latent_prefill_chunk_writes_no_score_for_v5e(one_chip, monkeypatch):
+    """A latent layer and an expert layer at GLM-4.7-Flash's widths, a
+    chunk of 512 over a table of 1,024 blocks: every width of the chunk's
+    ladder takes the kernel (five Mosaic calls), no (heads, 512, rows) f32
+    score takes memory, and the experts take their tokens in
+    groups of 128 (no product of all 512 with an expert's matrix)."""
+    import re
+
+    from rafiki_tpu.models import lm
+    from rafiki_tpu.ops import mla
+
+    monkeypatch.setattr(mla, "_on_tpu", lambda: True)
+    cfg = lm.HybridConfig(
+        vocab=1024, max_len=16384, dim=2048, pattern="LE",
+        mla=mla.MLAConfig(dim=2048, heads=20, q_rank=768, kv_rank=512,
+                          nope_dim=192, rope_dim=64, v_dim=256),
+        n_experts=64, top_k=4, ffn=1536,
+        shared_ffn=1024,  # not the model's 1536: told from an expert's
+        route_score="sigmoid", route_bias=True, route_scale=1.8,
+        expert_act="silu", expert_gated=True, held=(0, 32))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: lm.hybrid_init(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: lm.init_hybrid_cache(cfg, 2048, 16)))
+    compiled = jax.jit(
+        lambda p, c, bt, i, st, m: lm.hybrid_paged_prefill(
+            p, c, bt, i, st, m, None, cfg), donate_argnums=1).lower(
+        params, cache, i32(1024), i32(512), i32(), i32()).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    # a head's scores at the widest view are 671 MB in f32; the keys and
+    # values, transposed, as the kernel reads them, 336 MB in bf16
+    assert re.findall(r"bf16\[20,512,16384\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+    assert not re.findall(r"f32\[512,3072\]", text)
+    assert re.findall(r"f32\[128,3072\]", text)
