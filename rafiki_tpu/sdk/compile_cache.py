@@ -17,6 +17,18 @@ Contract (the artifact-frame contract applied to XLA executables):
   moves never hits. JAX's own key covers the program, the device kind
   and topology, and the jax/jaxlib versions, so one directory serves
   every process and backend.
+- **Keyed by the program, not by where its source lies.** JAX strips
+  debug info from the module it hashes, but not from inside a Pallas
+  kernel's ``tpu_custom_call``: its Mosaic payload carries MLIR
+  locations, Python frames and their file paths included. An uploaded
+  template is imported from a temp file of a random name in every
+  process (``sdk/model.py`` ``load_model_class``), so with frames in its
+  locations a program that holds a kernel would key anew in every
+  process and compile from scratch. :func:`enable` sets ``jax_traceback_in_locations_limit`` to 0: no
+  frame goes into a location, so no path (the template's, the
+  checkout's, a kernel's written in a template) goes into a key.
+  Programs without a kernel keep their keys; profile names come from
+  name stacks, which stay.
 - **Typed degrade, never a crash.** An unusable cache dir (missing,
   unwritable, probe failure) disables the cache for this process and
   records *why* (``stats()["reason"]``, surfaced by the doctor); the
@@ -156,6 +168,9 @@ def enable() -> Optional[str]:
             jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(config.COMPILE_CACHE_MIN_COMPILE_S))
+        # no source frames in MLIR locations, so none in a kernel's
+        # payload and none in its key (module docstring)
+        jax.config.update("jax_traceback_in_locations_limit", 0)
         _state.update(enabled=True, dir=path, reason=None)
     _install_listeners()
     logger.info("persistent compile cache at %s", path)

@@ -173,6 +173,103 @@ def test_jax_compilation_cache_dir_wins_and_is_not_set_in_code(
     assert not other.exists()
 
 
+# A template whose program holds a Pallas kernel: the serving stack's own
+# (`ops/mla.py` `attend_rows`), or one the template writes itself. Each
+# load imports it from a temp file of a new random name.
+_KERNEL_TEMPLATES = {
+    "ops_kernel": '''
+import jax.numpy as jnp
+from rafiki_tpu.ops.mla import attend_rows
+from rafiki_tpu.sdk.model import BaseModel
+
+
+class KernelModel(BaseModel):
+    @staticmethod
+    def program(q, kv, first, last):
+        return attend_rows(q, kv, first, last, 0.125)
+''',
+    "template_kernel": '''
+import jax
+from jax.experimental import pallas as pl
+from rafiki_tpu.sdk.model import BaseModel
+
+
+def _double(x_ref, o_ref):
+    o_ref[...] = x_ref[...] * 2.0
+
+
+class KernelModel(BaseModel):
+    @staticmethod
+    def program(q, kv, first, last):
+        return pl.pallas_call(
+            _double, out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype))(q)
+''',
+}
+
+_LOWER_TWICE = '''
+import json, sys
+import jax, jax.numpy as jnp
+from rafiki_tpu.sdk import compile_cache
+from rafiki_tpu.sdk.model import load_model_class
+
+out = {"dir": compile_cache.enable()}
+try:
+    jax.jit(lambda x: x + 1).trace(jnp.ones(8)).lower(
+        lowering_platforms=("tpu",))
+except Exception as e:
+    print(json.dumps({"skip": f"{type(e).__name__}: {e}"}))
+    sys.exit(0)
+shapes = (jax.ShapeDtypeStruct((2, 128, 256), jnp.bfloat16),
+          jax.ShapeDtypeStruct((2, 512, 1024), jnp.bfloat16),
+          jax.ShapeDtypeStruct((), jnp.int32),
+          jax.ShapeDtypeStruct((), jnp.int32))
+template = sys.stdin.read().encode()
+texts = [jax.jit(load_model_class(template, "KernelModel").program)
+         .trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+         for _ in range(2)]
+out.update(same=texts[0] == texts[1],
+           kernels=texts[0].count("tpu_custom_call"))
+print(json.dumps(out))
+'''
+
+
+@pytest.mark.parametrize("template,placed", [
+    ("ops_kernel", False), ("template_kernel", False),
+    ("ops_kernel", True)],
+    ids=["ops_kernel", "template_kernel", "placed_from_outside"])
+def test_kernel_program_keys_the_same_from_any_template_path(
+        tmp_path, template, placed):
+    """A program that holds a Pallas kernel lowers for the TPU to the same
+    module from two loads of one template, each imported from its own
+    temp file: nothing of where the source lay reaches the kernel's
+    payload, so a replica's second process finds the first one's entry.
+    Lowering needs no chip; checked in a child interpreter with the cache
+    on, placed by the stack or from outside."""
+    import json
+    import subprocess
+    import sys
+
+    env = dict(os.environ, RAFIKI_COMPILE_CACHE="1",
+               RAFIKI_COMPILE_CACHE_CPU="1",
+               RAFIKI_COMPILE_CACHE_DIR=str(tmp_path / "rafiki-root"),
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    out = subprocess.run([sys.executable, "-c", _LOWER_TWICE], env=env,
+                         input=_KERNEL_TEMPLATES[template], text=True,
+                         capture_output=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    if "skip" in rec:
+        pytest.skip(f"no lowering for the TPU here: {rec['skip']}")
+    want = tmp_path / ("placed" if placed else "rafiki-root")
+    assert rec["dir"] == str(want)
+    assert rec["kernels"] >= 1, "the program holds the kernel"
+    assert rec["same"], "the module moved with the template's temp path"
+
+
 def test_default_cache_dir_is_fixed_in_the_checkout(monkeypatch, tmp_path):
     """Unset, the cache is <checkout>/xla_cache: nothing in the path
     depends on the pid, the time, the cwd or RAFIKI_WORKDIR."""
