@@ -40,7 +40,7 @@ from rafiki_tpu.placement.manager import (
     PlacementManager,
 )
 from rafiki_tpu.predictor.predictor import Predictor
-from rafiki_tpu.utils import chaos
+from rafiki_tpu.utils import chaos, trace
 from rafiki_tpu.worker.inference import InferenceWorker
 from rafiki_tpu.worker.train import TrainWorker
 
@@ -337,6 +337,13 @@ class ServicesManager:
     # -- inference -----------------------------------------------------------
 
     def create_inference_services(self, inference_job_id: str) -> Predictor:
+        """Deploy an inference job's replicas and its predictor, in a
+        ``setup.deploy`` span: from the job's checks through
+        ``_wait_until_services_running``, every replica RUNNING."""
+        with trace.span("setup.deploy"):
+            return self._create_inference_services(inference_job_id)
+
+    def _create_inference_services(self, inference_job_id: str) -> Predictor:
         inf_job = self._db.get_inference_job(inference_job_id)
         assert inf_job is not None
         train_job = self._db.get_train_job(inf_job["train_job_id"])

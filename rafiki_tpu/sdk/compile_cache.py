@@ -36,9 +36,26 @@ Contract (the artifact-frame contract applied to XLA executables):
   and recompiled — a damaged cache can cost time, not correctness — and
   the warm-up chokepoint evicts unreadable entries (:func:`evict_entries`)
   because jax never overwrites them in place.
-- **Observable.** Cache hits are counted via JAX's monitoring events
-  into ``rafiki_compile_cache_hits_total``; the warm-up chokepoint
-  (worker/warmup.py) accounts misses and per-program compile seconds.
+- **Observable: the compile record.** One JAX listener (installed by
+  :func:`enable`) takes JAX's duration events, wherever in the process
+  they happen (a worker's warm-up, a trial, a first request), and keeps
+  each in a bounded record (:func:`events`): wall-clock end
+  (``time.time()``), stage, seconds, the program's name, the innermost
+  span open on the compiling thread (``utils/trace.py``
+  ``current_phase``) and that thread's name. The stages:
+  ``trace`` (``jaxpr_trace_duration``), ``lower``
+  (``jaxpr_to_mlir_module_duration``), and for
+  ``backend_compile_duration`` ``load`` where this thread saw a
+  persistent-cache hit inside it, else ``compile`` (a program under
+  ``RAFIKI_COMPILE_CACHE_MIN_COMPILE_S`` is never written, so it is a
+  ``compile`` every time). Nested events of one stage on one thread (a
+  jit traced inside a jit) count once, the outermost: JAX marks each
+  start with a scalar of the event's name, so the listener knows how
+  many of a stage are open on the thread. From the same
+  events: ``rafiki_compile_cache_hits_total``,
+  ``rafiki_compile_cache_misses_total`` and ``rafiki_compile_seconds``
+  (every backend compile that was not a hit) and
+  ``rafiki_compile_stage_seconds_total{stage}``.
 
 The CPU backend stays opted out by default (RAFIKI_COMPILE_CACHE_CPU=1
 to force): CPU AOT entries are tied to exact machine-feature sets and
@@ -48,10 +65,12 @@ and load. The cache pays off on TPU, where compiles are slow.
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import threading
-from typing import Any, Dict, Optional
+import time
+from typing import Any, Dict, List, Optional
 
 import jax
 
@@ -65,10 +84,30 @@ _lock = threading.Lock()
 #: typed reason it is off
 _state: Dict[str, Any] = {"enabled": False, "dir": None, "reason": None}
 _listeners_installed = False
-#: monotonically-increasing persistent-cache hit count for this process,
-#: fed by the JAX monitoring listener (lock-free read: int writes are
-#: atomic under the GIL and readers only diff snapshots)
+#: persistent-cache hits in this process (the doctor/health view)
 _hit_count = 0
+
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "compile"}
+_HIT = "/jax/compilation_cache/cache_hits"
+#: the compile record (guarded-by _record_lock); bounded: a process that
+#: compiles forever keeps its latest programs
+_record: "collections.deque[Dict[str, Any]]" = collections.deque(
+    maxlen=1 << 15)
+_record_lock = threading.Lock()
+
+
+class _ThreadCompiles(threading.local):
+    def __init__(self) -> None:
+        #: wall time of this thread's latest cache hit
+        self.hit_at = 0.0
+        #: stage -> events of it open on this thread (JAX marks a start
+        #: with a scalar of the same name)
+        self.depth: Dict[str, int] = {}
+
+
+_thread = _ThreadCompiles()
 
 
 def cache_dir() -> str:
@@ -82,50 +121,97 @@ def cache_dir() -> str:
             or os.path.join(_CHECKOUT, "xla_cache"))
 
 
-def _install_listeners() -> None:
-    """Count persistent-cache hits via JAX's monitoring events."""
-    global _listeners_installed
-    if _listeners_installed:
+def _on_event(event: str, **kw: Any) -> None:
+    if event == _HIT:
+        global _hit_count
+        _hit_count += 1
+        _thread.hit_at = time.time()
+        from rafiki_tpu.utils.metrics import REGISTRY
+
+        REGISTRY.counter(
+            "rafiki_compile_cache_hits_total",
+            "persistent compile-cache hits in this process",
+        ).inc()
+
+
+def _on_start(event: str, value: float, **kw: Any) -> None:
+    stage = _STAGES.get(event)
+    if stage is not None:
+        _thread.depth[stage] = _thread.depth.get(stage, 0) + 1
+
+
+def _on_duration(event: str, duration: float, **kw: Any) -> None:
+    stage = _STAGES.get(event)
+    if stage is None:
         return
-    _listeners_installed = True
-
-    def _on_event(event: str, **kw: Any) -> None:
-        if event.endswith("/compilation_cache/cache_hits"):
-            global _hit_count
-            _hit_count += 1
-            from rafiki_tpu.utils.metrics import REGISTRY
-
-            REGISTRY.counter(
-                "rafiki_compile_cache_hits_total",
-                "persistent compile-cache hits in this process",
-            ).inc()
-
-    jax.monitoring.register_event_listener(_on_event)
-
-
-def hit_count() -> int:
-    """Persistent-cache hits recorded in this process so far."""
-    return _hit_count
-
-
-def record_misses(n: int, seconds: float = 0.0) -> None:
-    """Account ``n`` compiled-fresh programs (the warm-up chokepoint's
-    bookkeeping — JAX's miss event is write-path-conditional, so misses
-    are counted where the compile time is actually measured)."""
-    if n <= 0:
-        return
+    open_ = _thread.depth.get(stage, 0)
+    _thread.depth[stage] = max(open_ - 1, 0)
+    if open_ > 1:
+        return  # inside an event of its stage on this thread: counted there
+    from rafiki_tpu.utils import trace
     from rafiki_tpu.utils.metrics import REGISTRY
 
+    end = time.time()
+    if stage == "compile" and _thread.hit_at >= end - duration:
+        stage = "load"
+    ev = {"end": end, "stage": stage, "seconds": duration,
+          "fun_name": str(kw.get("fun_name", "")),
+          "phase": trace.current_phase(),
+          "thread": threading.current_thread().name}
+    with _record_lock:
+        _record.append(ev)
     REGISTRY.counter(
-        "rafiki_compile_cache_misses_total",
-        "programs compiled fresh (persistent-cache misses) in this process",
-    ).inc(n)
-    if seconds > 0:
+        "rafiki_compile_stage_seconds_total",
+        "seconds of the compile record by stage (trace, lower, compile, "
+        "load), nested events of a stage counted once", ("stage",),
+    ).labels(stage).inc(duration)
+    if stage == "compile":
+        REGISTRY.counter(
+            "rafiki_compile_cache_misses_total",
+            "backend compiles that were not a persistent-cache hit, "
+            "anywhere in this process",
+        ).inc()
         REGISTRY.histogram(
             "rafiki_compile_seconds",
-            "wall-clock seconds spent compiling (cache misses) per program",
+            "seconds of each backend compile that was not a "
+            "persistent-cache hit",
             buckets=[0.05, 0.25, 1, 5, 15, 60, 300],
-        ).observe(seconds)
+        ).observe(duration)
+
+
+def _install_listeners() -> None:
+    """The one listener on JAX's monitoring events: hits, the start of a
+    stage (a scalar JAX records as it enters one), its duration."""
+    global _listeners_installed
+    with _lock:
+        if _listeners_installed:
+            return
+        _listeners_installed = True
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_scalar_listener(_on_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def events(since: float = 0.0,
+           thread: Optional[str] = None) -> List[Dict[str, Any]]:
+    """The compile record, oldest first: one dict an event, ``end``
+    (``time.time()``), ``stage``, ``seconds``, ``fun_name``, ``phase``,
+    ``thread``; those that ended at or after ``since``, on ``thread``
+    where one is named."""
+    with _record_lock:
+        return [dict(e) for e in _record if e["end"] >= since
+                and (thread is None or e["thread"] == thread)]
+
+
+def counts(record: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """Of some events of the record: seconds by stage, and the programs
+    loaded from the cache (hits) and compiled (misses)."""
+    stage_s: Dict[str, float] = {}
+    for e in record:
+        stage_s[e["stage"]] = stage_s.get(e["stage"], 0.0) + e["seconds"]
+    return {"stage_s": stage_s,
+            "cache_hits": sum(e["stage"] == "load" for e in record),
+            "cache_misses": sum(e["stage"] == "compile" for e in record)}
 
 
 def enable() -> Optional[str]:
@@ -133,9 +219,10 @@ def enable() -> Optional[str]:
     Idempotent; returns the active dir, or None with a typed reason in
     ``stats()`` when the cache is off (disabled, CPU without the opt-in,
     or an unusable directory — the degrade path: the process compiles
-    fresh, it never crashes)."""
+    fresh, it never crashes). The compile record is kept either way."""
     from rafiki_tpu import config
 
+    _install_listeners()
     with _lock:
         if _state["enabled"]:
             return _state["dir"]
@@ -172,7 +259,6 @@ def enable() -> Optional[str]:
         # payload and none in its key (module docstring)
         jax.config.update("jax_traceback_in_locations_limit", 0)
         _state.update(enabled=True, dir=path, reason=None)
-    _install_listeners()
     logger.info("persistent compile cache at %s", path)
     return path
 
@@ -241,11 +327,14 @@ def reset_for_tests() -> None:
     """Drop the process-level enablement so a test can re-point the
     cache dir. Also resets jax's cache SINGLETON: jax initializes its
     cache object lazily from the configured dir and then keeps it — a
-    config update alone would keep serving the previous directory."""
+    config update alone would keep serving the previous directory. The
+    compile record is emptied; the listeners stay."""
     global _hit_count
     from jax.experimental.compilation_cache import compilation_cache as _cc
 
     with _lock:
         _state.update(enabled=False, dir=None, reason=None)
         _hit_count = 0
+    with _record_lock:
+        _record.clear()
     _cc.reset_cache()

@@ -15,9 +15,15 @@ metric stream. This module is the first-class upgrade:
   while a profiler session is open the program's spans lie in the same
   `.xplane.pb` as the device's operations, on the profiler's clock; with
   no session open an annotation is a flag test. Code with no per-unit
-  `Tracer` (the generation worker's serve loop) uses the module-level
-  :func:`span`: the annotation plus one observation of
-  `rafiki_worker_phase_seconds{phase=name}`.
+  `Tracer` (the generation worker's serve loop, the workers' set-up
+  `setup.*`) uses the module-level :func:`span`: the annotation plus one
+  observation of `rafiki_worker_phase_seconds{phase=name}`, which a
+  `Tracer` span observes too.
+- **One span stack a thread**: both kinds of span push their name onto
+  the thread's own list and pop it on exit; :func:`current_phase` is the
+  innermost name. The compile record (sdk/compile_cache.py) files each
+  compile under it, so a program compiled at a first request is seen
+  where it happened.
 - **XLA profiles**: `jax_profile(dir)` opens such a session
   (a TensorBoard-loadable xplane trace of the device with the spans
   above on it) — opt-in via the RAFIKI_PROFILE env var because capture
@@ -99,18 +105,34 @@ def phase_histogram():
         _phase_hist = REGISTRY.histogram(
             "rafiki_worker_phase_seconds",
             "worker-side phase latency: per served batch (batch_assembly, "
-            "model_forward) and per phase of the generation worker's "
-            "serve loop (gen.*)", ("phase",))
+            "model_forward), per phase of the generation worker's serve "
+            "loop (gen.*), of a worker's set-up (setup.*) and of a trial "
+            "(propose, train, evaluate, persist*)", ("phase",))
     return _phase_hist
+
+
+class _Stack(threading.local):
+    def __init__(self) -> None:
+        self.names: List[str] = []
+
+
+_stack = _Stack()
+
+
+def current_phase() -> str:
+    """The innermost span open on this thread, or ``"none"``."""
+    names = _stack.names
+    return names[-1] if names else "none"
 
 
 class span:
     """``with trace.span("gen.decode.device"):`` — a span for code that
-    has no per-unit :class:`Tracer`: an :func:`annotation` of the name
-    plus one observation of `rafiki_worker_phase_seconds{phase=name}`.
-    Keyword arguments go to the annotation alone."""
+    has no per-unit :class:`Tracer`: an :func:`annotation` of the name,
+    the name on this thread's span stack while it is open, and one
+    observation of `rafiki_worker_phase_seconds{phase=name}`; its
+    ``seconds`` after exit. Keyword arguments go to the annotation alone."""
 
-    __slots__ = ("name", "attrs", "_ann", "_t0")
+    __slots__ = ("name", "attrs", "seconds", "_ann", "_t0")
 
     def __init__(self, name: str, **attrs: Any) -> None:
         self.name = name
@@ -119,12 +141,15 @@ class span:
     def __enter__(self) -> "span":
         self._ann = annotation(self.name, **self.attrs)
         self._ann.__enter__()
+        _stack.names.append(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         dt = time.perf_counter() - self._t0
+        _stack.names.pop()
         self._ann.__exit__(*exc)
+        self.seconds = dt
         phase_histogram().labels(self.name).observe(dt)
 
 
@@ -176,11 +201,15 @@ class Tracer:
         ann = annotation(name)
         s = Span(name=name, start=time.time(), depth=depth, attrs=attrs)
         ann.__enter__()
+        names = _stack.names
+        names.append(name)
         try:
             yield s
         finally:
+            names.pop()
             ann.__exit__(None, None, None)
             s.end = time.time()
+            phase_histogram().labels(name).observe(s.duration_s)
             with self._lock:
                 if depth == 0:
                     self._depth.pop(tid, None)

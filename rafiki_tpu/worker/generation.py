@@ -339,7 +339,7 @@ class GenerationWorker(InferenceWorker):
         from rafiki_tpu.utils.metrics import REGISTRY
 
         set_device_grant(ctx.chips)
-        t_start = time.monotonic()
+        t_start, since = time.monotonic(), time.time()
         model = None
         queue = self._broker.register_worker(self._job_id, ctx.service_id)
         try:
@@ -359,51 +359,55 @@ class GenerationWorker(InferenceWorker):
             self._recurrent = bool(spec.recurrent_state)
             if self._recurrent:
                 self._refuse_unsound_for_state(type(model))
-            paged_spec = paged_generation_capability(type(model))
-            if bool(config.GEN_KV_PAGED) and paged_spec is not None:
-                block_tokens = max(int(config.GEN_KV_BLOCK_TOKENS), 1)
-                table_blocks = -(-int(spec.max_context) // block_tokens)
-                pool_blocks = (int(config.GEN_KV_POOL_BLOCKS)
-                               or max_slots * table_blocks)
-                # a cached prefix holds keys and values and no state, so a
-                # recurrent model is served no hit: every admission counts
-                # as a miss and prefills from position 0
-                self._alloc = PagedKVAllocator(
-                    pool_blocks, block_tokens, table_blocks,
-                    prefix_cache=(bool(config.GEN_PREFIX_CACHE)
-                                  and not self._recurrent))
-                self._chunk = max(int(config.GEN_PREFILL_CHUNK), 0)
-                cache = (model.init_paged_kv_cache(pool_blocks, block_tokens,
-                                                   max_slots)
-                         if self._recurrent else
-                         model.init_paged_kv_cache(pool_blocks, block_tokens))
-                logger.info(
-                    "generation worker %s: paged KV (%d blocks x %d "
-                    "tokens, prefix cache %s, prefill chunk %d)",
-                    ctx.service_id, pool_blocks, block_tokens,
-                    "on" if self._alloc.prefix_cache else "off",
-                    self._chunk)
-            else:
-                cache = model.init_kv_cache(max_slots)
-            state_bytes = (int(model.recurrent_state_bytes(cache))
-                           if self._recurrent else 0)
-            if self._recurrent:
-                _metrics()["state_bytes"].labels(ctx.service_id).set(
-                    state_bytes)
-            if self._alloc is not None:
-                # what is not state is the pool: every paged group's rows
-                import jax
+            # the pool or ring, and sampling and speculation
+            with trace.span("setup.pool") as pool_span:
+                paged_spec = paged_generation_capability(type(model))
+                if bool(config.GEN_KV_PAGED) and paged_spec is not None:
+                    block_tokens = max(int(config.GEN_KV_BLOCK_TOKENS), 1)
+                    table_blocks = -(-int(spec.max_context) // block_tokens)
+                    pool_blocks = (int(config.GEN_KV_POOL_BLOCKS)
+                                   or max_slots * table_blocks)
+                    # a cached prefix holds keys and values and no state,
+                    # so a recurrent model is served no hit: every
+                    # admission counts as a miss and prefills from 0
+                    self._alloc = PagedKVAllocator(
+                        pool_blocks, block_tokens, table_blocks,
+                        prefix_cache=(bool(config.GEN_PREFIX_CACHE)
+                                      and not self._recurrent))
+                    self._chunk = max(int(config.GEN_PREFILL_CHUNK), 0)
+                    cache = (model.init_paged_kv_cache(
+                        pool_blocks, block_tokens, max_slots)
+                        if self._recurrent else
+                        model.init_paged_kv_cache(pool_blocks, block_tokens))
+                    logger.info(
+                        "generation worker %s: paged KV (%d blocks x %d "
+                        "tokens, prefix cache %s, prefill chunk %d)",
+                        ctx.service_id, pool_blocks, block_tokens,
+                        "on" if self._alloc.prefix_cache else "off",
+                        self._chunk)
+                else:
+                    cache = model.init_kv_cache(max_slots)
+                state_bytes = (int(model.recurrent_state_bytes(cache))
+                               if self._recurrent else 0)
+                if self._recurrent:
+                    _metrics()["state_bytes"].labels(ctx.service_id).set(
+                        state_bytes)
+                if self._alloc is not None:
+                    # what is not state is the pool: every paged group's
+                    # rows
+                    import jax
 
-                pool_bytes = sum(int(a.nbytes) for a in
-                                 jax.tree_util.tree_leaves(cache)) \
-                    - state_bytes
-                _metrics()["kv_row_bytes"].labels(ctx.service_id).set(
-                    pool_bytes // (pool_blocks * block_tokens))
-            self._init_spec(model, spec, max_slots, ctx)
+                    pool_bytes = sum(int(a.nbytes) for a in
+                                     jax.tree_util.tree_leaves(cache)) \
+                        - state_bytes
+                    _metrics()["kv_row_bytes"].labels(ctx.service_id).set(
+                        pool_bytes // (pool_blocks * block_tokens))
+                self._init_spec(model, spec, max_slots, ctx)
+            self._setup_s["setup.pool"] = pool_span.seconds
             # pre-warm per-bucket prefill + decode programs under the
             # persistent compile cache, before ctx.ready(): a still-
             # compiling generation replica stays DEPLOYING/unroutable
-            from rafiki_tpu.worker.warmup import run_warmup
+            from rafiki_tpu.worker.warmup import log_setup, run_warmup
 
             # Every rung of the decode round's table ladder is run once,
             # all rows idle, so that no width is first met, and compiled,
@@ -420,18 +424,23 @@ class GenerationWorker(InferenceWorker):
                 nonlocal cache
                 while unwarmed and not ctx.stopping and (
                         until is None or time.monotonic() < until):
-                    cache = self._idle_round(model, cache, max_slots,
-                                             unwarmed[0])
+                    with trace.span("setup.rung",
+                                    table_blocks=unwarmed[0]) as rung:
+                        cache = self._idle_round(model, cache, max_slots,
+                                                 unwarmed[0])
+                    self._setup_s["setup.rung"] = (
+                        self._setup_s.get("setup.rung", 0.0) + rung.seconds)
                     del unwarmed[0]
 
-            run_warmup(
+            self._setup_s["setup.warmup"] = run_warmup(
                 ctx.service_id, self._job_id,
                 [("warm_up", model.warm_up)]
                 + ([("decode_table_widths", lambda: warm_table_widths(
                     t_start + float(config.SERVICE_DEPLOY_TIMEOUT_S) / 2))]
-                   if unwarmed else []))
+                   if unwarmed else []))["compile_s"]
             ctx.ready()
             warm_table_widths()
+            log_setup(ctx.service_id, self._job_id, self._setup_s, since)
             if self._report_stats is not None:
                 threading.Thread(
                     target=self._stats_reporter, args=(ctx,),

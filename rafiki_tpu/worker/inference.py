@@ -16,6 +16,7 @@ at idle is served immediately (PREDICT_BATCH_DEADLINE_MS defaults to 0).
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
 import traceback
@@ -26,7 +27,7 @@ import numpy as np
 from rafiki_tpu import config
 from rafiki_tpu.cache.queue import Broker
 from rafiki_tpu.db.database import Database
-from rafiki_tpu.utils import chaos
+from rafiki_tpu.utils import chaos, trace
 from rafiki_tpu.parallel.mesh import set_device_grant
 from rafiki_tpu.placement.manager import ServiceContext
 from rafiki_tpu.sdk.model import load_model_class
@@ -285,6 +286,9 @@ class InferenceWorker:
         self._broker = broker
         self._report_stats = report_stats
         self._report_interval_s = report_interval_s
+        #: set-up span -> seconds, for the set-up line (warmup.log_setup);
+        #: the worker's own thread writes and reads it in start()
+        self._setup_s: Dict[str, float] = {}
 
     def _stats_reporter(self, ctx: ServiceContext) -> None:
         """Push cumulative counters on a fixed cadence, independent of
@@ -321,6 +325,21 @@ class InferenceWorker:
                 return
 
     def _load_model(self, service_id: str):
+        """The served model, in a ``setup.load`` span (attribute ``bytes``:
+        the parameters' files): the artifact read, ``load_params``, the
+        template's import and ``load_parameters``. JAX places arrays
+        asynchronously, so ``load_parameters`` may return before the bytes
+        are on the device; that tail lands in the next span that waits."""
+        paths = [(self._db.get_trial(tid) or {}).get("params_file_path")
+                 for tid in self._trial_ids]
+        size = sum(os.path.getsize(p) for p in paths
+                   if p and os.path.isfile(p))
+        with trace.span("setup.load", bytes=size) as span:
+            model = self._load_models(service_id)
+        self._setup_s["setup.load"] = span.seconds  # lint: unguarded(the worker's own thread, before the stats reporter starts)
+        return model
+
+    def _load_models(self, service_id: str):
         if len(self._trial_ids) > 1:
             models = [
                 self._load_one(tid, f"{service_id}-m{i}")
@@ -375,6 +394,7 @@ class InferenceWorker:
 
     def start(self, ctx: ServiceContext) -> None:
         set_device_grant(ctx.chips)
+        since = time.time()
         model = None
         assembler = _BatchAssembler()
         queue = self._broker.register_worker(self._job_id, ctx.service_id)
@@ -387,11 +407,13 @@ class InferenceWorker:
             # persistent compile cache, times the compiles, and records
             # this boot's cold/warm verdict; it runs BEFORE ctx.ready()
             # so a still-compiling replica stays DEPLOYING/unroutable.
-            from rafiki_tpu.worker.warmup import run_warmup
+            from rafiki_tpu.worker.warmup import log_setup, run_warmup
 
-            run_warmup(ctx.service_id, self._job_id,
-                       [("warm_up", model.warm_up)])
+            self._setup_s["setup.warmup"] = run_warmup(  # lint: unguarded(the worker's own thread, before the stats reporter starts)
+                ctx.service_id, self._job_id,
+                [("warm_up", model.warm_up)])["compile_s"]
             ctx.ready()  # model + params loaded: startup succeeded
+            log_setup(ctx.service_id, self._job_id, self._setup_s, since)
             if self._report_stats is not None:
                 threading.Thread(
                     target=self._stats_reporter, args=(ctx,),

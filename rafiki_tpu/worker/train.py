@@ -25,6 +25,7 @@ from __future__ import annotations
 import logging
 import os
 import random
+import threading
 import time
 import traceback
 from typing import Any, Callable, Dict, Optional
@@ -388,19 +389,22 @@ class TrainWorker:
             self._install_stop_check(trial_logger, advisor_id, trial_id)
             try:
                 self._chaos_trial(trial_id)
-                t_trial = time.monotonic()
-                hits_before = compile_cache.hit_count()
+                t_trial = time.time()
                 with self._trial_profile(trial_id):
                     score, params_path = self._run_trial(
                         clazz, knobs, job, trial_id, trial_logger, tracer)
                 # the boot's FIRST completed trial carries the cold-start
                 # verdict: cache hits mean its jit programs loaded from
                 # the persistent cache instead of compiling (the r5
-                # cold-compile collapse, measured per boot)
-                warmup.note_first_program(
-                    ctx.service_id, self._sub_id, "first_trial",
-                    time.monotonic() - t_trial,
-                    compile_cache.hit_count() - hits_before)
+                # cold-compile collapse, measured per boot); its compile
+                # record ends the worker's set-up
+                if warmup.note_first_program(
+                        ctx.service_id, self._sub_id, "first_trial",
+                        compile_cache.events(
+                            since=t_trial,
+                            thread=threading.current_thread().name)):
+                    warmup.log_setup(ctx.service_id, self._sub_id,
+                                     tracer.summary(), t_trial)
                 # feedback BEFORE mark-complete: a sibling restarting in
                 # between sees COMPLETED only once the observation is in
                 # the GP, so its empty-only replay can't double-feed (the

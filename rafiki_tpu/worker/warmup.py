@@ -13,9 +13,10 @@ merged into the worker's stats row and `/healthz`):
 - ``warm`` — True when the boot was served by the persistent cache
   (observed cache hits, or total compile time under
   ``RAFIKI_COMPILE_WARM_THRESHOLD_S`` when hit events are unavailable).
-- ``compile_s`` / ``programs`` — total and per-program compile seconds.
-- ``cache_hits`` / ``cache_misses`` — this boot's persistent-cache
-  traffic (misses are counted here, where compile time is measured).
+- ``compile_s`` / ``programs`` — total and per-program seconds.
+- ``cache_hits`` / ``cache_misses`` — programs this thread loaded from
+  the persistent cache / compiled, from the compile record
+  (sdk/compile_cache.py): an entry that compiles nothing counts nothing.
 
 Chaos (RAFIKI_CHAOS site=compile, target
 ``"{scope}/{service_id}/{program}"``): ``delay`` stretches the warm-up
@@ -39,7 +40,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from rafiki_tpu import config
 from rafiki_tpu.sdk import compile_cache
-from rafiki_tpu.utils import chaos
+from rafiki_tpu.utils import chaos, trace
 
 logger = logging.getLogger(__name__)
 
@@ -66,122 +67,115 @@ class WarmupError(RuntimeError):
     startup so the service lands ERRORED instead of half-warm RUNNING."""
 
 
+def _this_thread_since(since: float) -> List[Dict[str, Any]]:
+    return compile_cache.events(since=since,
+                                thread=threading.current_thread().name)
+
+
+def _report(service_id: str, scope: str, seconds: float,
+            programs: Dict[str, float], record: Sequence[Dict[str, Any]],
+            **extra: Any) -> Dict[str, Any]:
+    """A boot's warm-state report, its hits and misses from ``record``
+    (compile events). Warm <=> the cache demonstrably served the boot, or
+    it compiled fast enough that a request parked behind it would not
+    have noticed."""
+    n = compile_cache.counts(record)
+    return {"service_id": service_id, "scope": scope,
+            "warm": bool(n["cache_hits"] > 0 or
+                         seconds <= config.COMPILE_WARM_THRESHOLD_S),
+            "compile_s": round(seconds, 4), "programs": programs,
+            "cache_hits": n["cache_hits"],
+            "cache_misses": n["cache_misses"], "warnings": [],
+            "ts": time.time(), "cache": compile_cache.stats(), **extra}
+
+
 def run_warmup(service_id: str, scope: str,
                programs: Sequence[Program]) -> Dict[str, Any]:
-    """Compile ``programs`` under the persistent cache, timing each, and
-    record + return the boot's warm-state report. Call BEFORE
-    ``ctx.ready()``: the whole point is that warm-up time is spent while
-    the replica is still DEPLOYING and unroutable."""
+    """Compile ``programs`` under the persistent cache, timing each, in a
+    ``setup.warmup`` span, and record + return the boot's warm-state
+    report. Call BEFORE ``ctx.ready()``: the whole point is that warm-up
+    time is spent while the replica is still DEPLOYING and unroutable."""
     compile_cache.enable()
-    report: Dict[str, Any] = {
-        "service_id": service_id,
-        "scope": scope,
-        "warm": False,
-        "compile_s": 0.0,
-        "programs": {},
-        "cache_hits": 0,
-        "cache_misses": 0,
-        "evicted": 0,
-        "warnings": [],
-        "ts": time.time(),
-    }
-    hits_before = compile_cache.hit_count()
-    started = time.monotonic()
-    for name, fn in programs:
-        rule = chaos.hit(chaos.SITE_COMPILE, f"{scope}/{service_id}/{name}")
-        if rule is not None:
-            if rule.action == chaos.ACTION_DELAY:
-                chaos.sleep_for(rule)
-            elif rule.action == chaos.ACTION_CORRUPT:
-                damaged = compile_cache.corrupt_entries()
-                logger.warning("chaos corrupted %d compile-cache entries "
-                               "before %s", damaged, name)
-            else:  # error / drop: fail the boot, typed
-                raise WarmupError(
-                    f"injected warm-up failure at {scope}/{service_id}/"
-                    f"{name} (chaos site=compile)")
-        hits_pre = compile_cache.hit_count()
-        t0 = time.monotonic()
-        # record python warnings across the compile: jax reports an
-        # unreadable (bit-rotted) cache entry that way, and warm-up is
-        # the boot-time chokepoint where self-healing can happen
-        with _pywarnings.catch_warnings(record=True) as caught:
-            _pywarnings.simplefilter("always")
-            try:
-                fn()
-            # lint: absorb(an optional warm-up program failing must not block serving — the replica just boots cold; recorded in the report)
-            except Exception as e:
-                msg = f"{name}: {type(e).__name__}: {e}"
-                report["warnings"].append(msg)
+    since = time.time()
+    took: Dict[str, float] = {}
+    warned, evicted = [], 0
+    with trace.span("setup.warmup") as span:
+        for name, fn in programs:
+            rule = chaos.hit(chaos.SITE_COMPILE,
+                             f"{scope}/{service_id}/{name}")
+            if rule is not None:
+                if rule.action == chaos.ACTION_DELAY:
+                    chaos.sleep_for(rule)
+                elif rule.action == chaos.ACTION_CORRUPT:
+                    damaged = compile_cache.corrupt_entries()
+                    logger.warning("chaos corrupted %d compile-cache "
+                                   "entries before %s", damaged, name)
+                else:  # error / drop: fail the boot, typed
+                    raise WarmupError(
+                        f"injected warm-up failure at {scope}/{service_id}/"
+                        f"{name} (chaos site=compile)")
+            t0 = time.monotonic()
+            # record python warnings across the compile: jax reports an
+            # unreadable (bit-rotted) cache entry that way, and warm-up is
+            # the boot-time chokepoint where self-healing can happen
+            with _pywarnings.catch_warnings(record=True) as caught:
+                _pywarnings.simplefilter("always")
+                try:
+                    fn()
+                # lint: absorb(an optional warm-up program failing must not block serving — the replica just boots cold; recorded in the report)
+                except Exception as e:
+                    warned.append(f"{name}: {type(e).__name__}: {e}")
+                    logger.warning(
+                        "warm-up program %s failed (serving anyway): %s",
+                        name, warned[-1], exc_info=True)
+            took[name] = round(time.monotonic() - t0, 4)
+            for rec in caught:
+                m = _CACHE_READ_ERR.search(str(rec.message))
+                if m is None:
+                    # not ours: hand it back to the normal warning machinery
+                    _pywarnings.warn_explicit(rec.message, rec.category,
+                                              rec.filename, rec.lineno)
+                    continue
+                evicted += compile_cache.evict_entries(m.group(1))
                 logger.warning(
-                    "warm-up program %s failed (serving anyway): %s",
-                    name, msg, exc_info=True)
-        dt = time.monotonic() - t0
-        for rec in caught:
-            m = _CACHE_READ_ERR.search(str(rec.message))
-            if m is None:
-                # not ours: hand it back to the normal warning machinery
-                _pywarnings.warn_explicit(rec.message, rec.category,
-                                          rec.filename, rec.lineno)
-                continue
-            evicted = compile_cache.evict_entries(m.group(1))
-            report["evicted"] += evicted
-            logger.warning(
-                "evicted %d unreadable compile-cache entr(y/ies) for %s "
-                "(bit-rot self-heal: the next boot recompiles and "
-                "rewrites them)", evicted, m.group(1))
-        report["programs"][name] = round(dt, 4)
-        if compile_cache.hit_count() == hits_pre:
-            # no persistent-cache hit observed for this program: it was
-            # compiled fresh (or tracing-only) — account the miss where
-            # the compile time is actually measured
-            report["cache_misses"] += 1
-            compile_cache.record_misses(1, dt)
-    report["compile_s"] = round(time.monotonic() - started, 4)
-    report["cache_hits"] = compile_cache.hit_count() - hits_before
-    # warm <=> the cache demonstrably served this boot, or — when hit
-    # events are unavailable / the cache is off — the boot compiled fast
-    # enough that a request parked behind it would not have noticed
-    report["warm"] = bool(
-        report["cache_hits"] > 0
-        or report["compile_s"] <= config.COMPILE_WARM_THRESHOLD_S)
-    report["cache"] = compile_cache.stats()
+                    "evicted unreadable compile-cache entries for %s "
+                    "(bit-rot self-heal: the next boot recompiles and "
+                    "rewrites them)", m.group(1))
+    report = _report(service_id, scope, span.seconds, took,
+                     _this_thread_since(since), evicted=evicted,
+                     warnings=warned)
     with _warm_lock:
         WARMUP_STATS[service_id] = report
-    logger.info(
-        "warm-up %s (%s): warm=%s compile_s=%.3f hits=%d misses=%d",
-        service_id, scope, report["warm"], report["compile_s"],
-        report["cache_hits"], report["cache_misses"])
     return report
 
 
 def note_first_program(service_id: str, scope: str, name: str,
-                       seconds: float, hits_delta: int) -> None:
+                       record: Sequence[Dict[str, Any]]) -> bool:
     """One-shot warm verdict for workers whose compiled programs only
     materialize mid-run (the trial worker: jit programs depend on the
-    advisor's knob draw, so there is nothing to enumerate at boot).
-    Records the boot's first program, warm <=> the persistent cache
-    demonstrably served it OR it finished under the warm threshold.
-    Subsequent calls for the same service are no-ops — only the FIRST
-    program of a boot carries the cold-start verdict."""
+    advisor's knob draw, so there is nothing to enumerate at boot):
+    ``record`` is the compile record of the boot's first program (its
+    first trial), whose seconds are the program's, every stage. Only the
+    FIRST call for a service records (True); later ones do nothing."""
+    seconds = sum(e["seconds"] for e in record)
+    report = _report(service_id, scope, seconds, {name: round(seconds, 4)},
+                     record)
     with _warm_lock:
-        if service_id in WARMUP_STATS:
-            return
-        WARMUP_STATS[service_id] = {
-            "service_id": service_id,
-            "scope": scope,
-            "warm": bool(hits_delta > 0
-                         or seconds <= config.COMPILE_WARM_THRESHOLD_S),
-            "compile_s": round(seconds, 4),
-            "programs": {name: round(seconds, 4)},
-            "cache_hits": max(hits_delta, 0),
-            "cache_misses": 0 if hits_delta > 0 else 1,
-            "warnings": [],
-            "ts": time.time(),
-            "cache": compile_cache.stats(),
-        }
-    if hits_delta <= 0:
-        compile_cache.record_misses(1, seconds)
+        return WARMUP_STATS.setdefault(service_id, report) is report
+
+
+def log_setup(service_id: str, scope: str, spans: Dict[str, float],
+              since: float) -> None:
+    """A worker's one line at the end of its set-up: the seconds of its
+    set-up spans (name -> seconds) and this thread's compile record since
+    ``since`` by stage, with hits and misses."""
+    n = compile_cache.counts(_this_thread_since(since))
+    logger.info("set-up %s (%s): %s; compile record %s; hits=%d misses=%d",
+                service_id, scope,
+                " ".join(f"{k}={v:.3f}s" for k, v in spans.items()),
+                " ".join(f"{k}={v:.3f}s" for k, v in
+                         sorted(n["stage_s"].items())),
+                n["cache_hits"], n["cache_misses"])
 
 
 def warmup_stats(service_id: Optional[str] = None) -> Dict[str, Any]:

@@ -391,13 +391,21 @@ def test_warmup_absorbs_program_failure_warn_only(cpu_cache):
     assert "optional warm-up path broke" in report["warnings"][0]
 
 
+def _compiled(stage, seconds):
+    """One event of the compile record (sdk/compile_cache.py events)."""
+    return {"end": time.time(), "stage": stage, "seconds": seconds,
+            "fun_name": "prog", "phase": "train", "thread": "svc-t"}
+
+
 def test_note_first_program_is_one_shot(monkeypatch):
     monkeypatch.setenv("RAFIKI_COMPILE_WARM_THRESHOLD_S", "1.0")
-    warmup.note_first_program("svc-t", "sub", "first_trial", 0.2, 0)
+    warmup.note_first_program("svc-t", "sub", "first_trial",
+                              [_compiled("compile", 0.2)])
     r = warmup.warmup_stats("svc-t")
     assert r["warm"] is True and r["cache_misses"] == 1
     # later programs never overwrite the boot's cold-start verdict
-    warmup.note_first_program("svc-t", "sub", "later", 99.0, 0)
+    warmup.note_first_program("svc-t", "sub", "later",
+                              [_compiled("compile", 99.0)])
     assert warmup.warmup_stats("svc-t")["compile_s"] == 0.2
 
 
@@ -703,7 +711,8 @@ def test_predictor_healthz_reports_replica_warm_state():
     server = None
     try:
         broker.register_worker("job-hz", "svc-hz")
-        warmup.note_first_program("svc-hz", "job-hz", "warm_up", 0.01, 1)
+        warmup.note_first_program("svc-hz", "job-hz", "warm_up",
+                                  [_compiled("load", 0.01)])
         predictor = Predictor("job-hz", broker, "IMAGE_CLASSIFICATION",
                               worker_trials={"svc-hz": "t1"})
         server = PredictorServer(predictor, "job-hz", auth=False).start()
