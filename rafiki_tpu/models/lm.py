@@ -990,6 +990,7 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
         return x, cache
 
     def latent(p, x, cache, l):
+        from rafiki_tpu.ops import mla
         from rafiki_tpu.ops.mla import mla_attend, mla_project
 
         with jax.named_scope("latent"):
@@ -1000,6 +1001,15 @@ def _hybrid_forward(params: Params, cache: Cache, ids: jax.Array,
                                                turn_rows)
             rows = jnp.pad(rows.astype(pool.dtype),
                            ((0, 0), (0, 0), (0, wide - cfg.mla.row)))
+            if mla.pool_kernel_takes(t, bt):
+                # one query a sequence reads its rows where they lie in the
+                # pool: the new row is written first, and no view is made
+                pool = pool.at[l, phys, off].set(rows, mode="drop")
+                rows_held = jnp.where(lengths > 0, positions[:, 0] + 1, 0)
+                out = mla.mla_attend_pool(
+                    p, q_nope, q_rope, pool, l, block_tables,
+                    jnp.minimum(rows_held, nb * bt), cfg.mla)
+                return x + out, {**cache, "latent": pool}
 
             # the last real token's position: no row past it is read
             reach = jnp.max(jnp.where(

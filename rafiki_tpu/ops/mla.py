@@ -19,7 +19,10 @@ forms with one result:
 A decode round (one query a sequence) is absorbed, a prefill chunk of
 hundreds of queries expanded: :func:`cheaper_form` counts both from the
 shapes. On the TPU the chunk's attention over the expanded keys and values
-is one Pallas kernel (:func:`attend_rows`) that keeps the scores in VMEM.
+is one Pallas kernel (:func:`attend_rows`) that keeps the scores in VMEM,
+and a decode round's product over the rows another (:func:`attend_pool`)
+that reads each slot's live rows where they lie in the paged pool, once,
+with no gathered view.
 Scores are scaled by ``(nope + rope) ** -0.5``; rotary turns all of the
 ``rope_dim`` part (``ops/attention.rotary``'s pairs ``(i, i + rope/2)``).
 Operands in the weights' dtype, accumulation, norms, rotary and softmax in
@@ -233,6 +236,160 @@ def _expansion(w_ukv: jax.Array, cfg: MLAConfig, wide: int, d: int
         .at[at_v].set(w[:, cfg.nope_dim:])
 
 
+POOL_BLOCKS = 64  # pool blocks a step of the decode kernel fetches, at most
+# off the TPU the decode kernel runs in the TPU interpreter, which raises on
+# a read past an array and fills memory not yet written with NaN: a block
+# fetched past a slot's table, or a buffer read before its fetch, shows
+INTERPRET = pltpu.InterpretParams()
+
+
+def _pool_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
+                 buf, sem, acc_ref, m_ref, l_ref, *, scale: float, per: int,
+                 width: int):
+    """One slot's absorbed queries (H, wide) against its live latent rows,
+    fetched from the pool in HBM ``per`` blocks a step into one of two VMEM
+    buffers, the next step's (the next live slot's first, after a slot's
+    last) fetched while this one is computed; the softmax's statistics
+    carried in VMEM over the steps. ``tables_ref`` holds the slots' tables
+    one after another, ``width`` blocks each; ``lens_ref`` each slot's
+    rows, 0 for a slot that is not live. No block past a slot's length is
+    fetched, and its last block's rows past the length are masked out of
+    the scores and zeroed in the values, as is what an earlier step left in
+    the buffer: nothing there reaches the output."""
+    s, slots = pl.program_id(0), pl.num_programs(0)
+    bt = pool_ref.shape[2]
+    rows = per * bt
+    pool = pool_ref.at[layer_ref[0]]
+    blocks = lambda slot: pl.cdiv(lens_ref[slot], bt)
+    steps = lambda slot: pl.cdiv(blocks(slot), per)
+
+    def copies(slot, step, into):
+        """(the DMA of the step's k-th block, how many of its blocks are
+        live)."""
+        first = step * per
+        copy = lambda k: pltpu.make_async_copy(
+            pool.at[tables_ref[slot * width + first + k]],
+            buf.at[into, pl.ds(pl.multiple_of(k * bt, bt), bt)],
+            sem.at[into])
+        return copy, jnp.minimum(per, blocks(slot) - first)
+
+    def start(slot, step, into):
+        copy, n = copies(slot, step, into)
+        jax.lax.fori_loop(0, n, lambda k, c: (copy(k).start(), c)[1], 0)
+
+    def wait(slot, step, into):
+        copy, n = copies(slot, step, into)
+        jax.lax.fori_loop(0, n, lambda k, c: (copy(k).wait(), c)[1], 0)
+
+    n = steps(s)
+    # the steps of the slots before this one say which buffer each step
+    # takes; the first live slot fetches its own first step
+    before = jax.lax.fori_loop(0, s, lambda i, c: c + steps(i), 0)
+    after = jax.lax.fori_loop(
+        s + 1, slots, lambda i, c: jnp.where(
+            jnp.logical_and(c == slots, lens_ref[i] > 0), i, c), slots)
+
+    @pl.when(jnp.logical_and(n > 0, before == 0))
+    def _first():
+        start(s, 0, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def step(i, carry):
+        cur = (before + i) % 2
+
+        @pl.when(i + 1 < n)
+        def _next_step():
+            start(s, i + 1, 1 - cur)
+
+        @pl.when(jnp.logical_and(i + 1 == n, after < slots))
+        def _next_slot():
+            start(after, 0, 1 - cur)
+
+        wait(s, i, cur)
+        left = lens_ref[s] - i * rows  # of this step's rows, the live ones
+
+        def update(masked: bool):
+            kv = buf[cur]
+            q = q_ref[0].astype(kv.dtype)
+            if masked:
+                ok = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 0) < left
+                kv = jnp.where(ok, kv, jnp.zeros_like(kv))
+            sc = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (H, rows)
+            if masked:
+                col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+                sc = jnp.where(col < left, sc, -1e30)
+            # a step holds at least one live row: m_new is a real score
+            m_new = jnp.maximum(m_ref[...], jnp.max(sc, axis=-1,
+                                                    keepdims=True))
+            w = jnp.exp(sc - m_new)
+            alpha = jnp.exp(m_ref[...] - m_new)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(w, axis=-1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                w.astype(kv.dtype), kv, preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
+        pl.when(left >= rows)(lambda: update(False))
+        pl.when(left < rows)(lambda: update(True))
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
+
+    @pl.when(n > 0)
+    def _finish():
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+    @pl.when(n == 0)
+    def _idle():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def attend_pool(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                tables: jax.Array, lengths: jax.Array, scale: float,
+                interpret: Any = False) -> jax.Array:
+    """softmax(q rows^T * scale) rows over each slot's live rows where they
+    lie in the paged pool, as one Pallas kernel: q (S, H, wide) a slot's
+    queries over the whole row, pool (layers, blocks, tokens, wide) left in
+    HBM, ``layer`` which of its layers, tables (S, width) each slot's
+    blocks, lengths (S,) each slot's rows (0: not live, which reads nothing
+    and gives zeros) -> (S, H, wide) f32. Every live row is read once and
+    serves all heads; nothing is read past a slot's length. Jitted, so that
+    the latent layers of one program share one traced and lowered kernel.
+    ``interpret``: off the TPU, :data:`INTERPRET`."""
+    slots, h, wide = q.shape
+    width = tables.shape[1]
+    bt = pool.shape[2]
+    per = min(POOL_BLOCKS, width)
+    return pl.pallas_call(
+        functools.partial(_pool_kernel, scale=scale, per=per, width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(slots,),
+            in_specs=[
+                pl.BlockSpec((1, h, wide), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, h, wide), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, per * bt, wide), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((h, wide), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, h, wide), jnp.float32),
+        # a slot's last step fetches the next live slot's first: the grid
+        # runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      tables.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      q, pool)
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -243,6 +400,50 @@ def kernel_takes(b: int, t: int, rows: int) -> bool:
     decode round's one query go the plain way)."""
     return (_on_tpu() and b == 1 and t % LANES == 0
             and rows % BLOCK_ROWS == 0)
+
+
+def pool_kernel_takes(t: int, block_tokens: int) -> bool:
+    """Whether a round's product over the rows reads the pool in place
+    through :func:`attend_pool`: one query a sequence, on the TPU, blocks
+    that fill whole tiles of bfloat16 rows."""
+    return _on_tpu() and t == 1 and block_tokens % 16 == 0
+
+
+def _absorbed_query(p: Params, q_nope: jax.Array, q_rope: jax.Array,
+                    wide: int, cfg: MLAConfig) -> jax.Array:
+    """A head's query over the whole row, ``[q~ | q_rope | zeros]`` up to
+    ``wide``, in the weights' dtype: ``W_uk,h`` folded into the query."""
+    dt = p["w_ukv"].dtype
+    w_ukv = p["w_ukv"].reshape(cfg.kv_rank, cfg.heads,
+                               cfg.nope_dim + cfg.v_dim)
+    q_lat = jnp.einsum("bthn,chn->bthc", q_nope.astype(dt),
+                       w_ukv[..., :cfg.nope_dim],
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat, q_rope, jnp.zeros(
+        q_rope.shape[:-1] + (wide - cfg.row,))], axis=-1).astype(dt)
+
+
+def mla_attend_pool(p: Params, q_nope: jax.Array, q_rope: jax.Array,
+                    pool: jax.Array, layer: int, tables: jax.Array,
+                    lengths: jax.Array, cfg: MLAConfig) -> jax.Array:
+    """The absorbed form of one query a sequence, its product over the rows
+    read where they lie in the pool (:func:`attend_pool`), and the layer's
+    output projection: q_nope/q_rope (S, 1, H, .), ``pool`` the latent
+    group (layers, blocks, tokens, ``cache_row``) that holds the new rows
+    already, tables (S, width), lengths (S,) each slot's rows (0: not
+    live). Returns (S, 1, D) f32."""
+    s, _, h, _ = q_nope.shape
+    dt = p["w_ukv"].dtype
+    w_ukv = p["w_ukv"].reshape(cfg.kv_rank, h, cfg.nope_dim + cfg.v_dim)
+    q_row = _absorbed_query(p, q_nope, q_rope, pool.shape[-1], cfg)[:, 0]
+    o_lat = attend_pool(q_row, pool, jnp.asarray(layer, jnp.int32), tables,
+                        lengths, 1.0 / math.sqrt(cfg.nope_dim + cfg.rope_dim),
+                        interpret=False if _on_tpu() else INTERPRET)
+    o = jnp.einsum("shc,chv->shv", o_lat[..., :cfg.kv_rank].astype(dt),
+                   w_ukv[..., cfg.nope_dim:],
+                   preferred_element_type=jnp.float32)
+    return jnp.dot(o.reshape(s, 1, h * cfg.v_dim).astype(dt), p["wo"],
+                   preferred_element_type=jnp.float32)
 
 
 def mla_attend(p: Params, q_nope: jax.Array, q_rope: jax.Array,
@@ -272,12 +473,7 @@ def mla_attend(p: Params, q_nope: jax.Array, q_rope: jax.Array,
         # a head's query over the whole row, [q~ | q_rope]: one product
         # reads the view for the scores, one for the output (whose last
         # `rope_dim` columns, the rotary key's, are dropped)
-        q_lat = jnp.einsum("bthn,chn->bthc", q_nope.astype(dt),
-                           w_ukv[..., :cfg.nope_dim],
-                           preferred_element_type=jnp.float32)
-        q_row = jnp.concatenate([q_lat, q_rope, jnp.zeros(
-            q_rope.shape[:-1] + (view.shape[-1] - cfg.row,))],
-            axis=-1).astype(dt)
+        q_row = _absorbed_query(p, q_nope, q_rope, view.shape[-1], cfg)
         s = jnp.einsum("bthr,blr->bhtl", q_row, view,
                        preferred_element_type=jnp.float32) * scale
         a = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1).astype(dt)

@@ -210,6 +210,12 @@ def _metrics():
                 "tables they were handed: the narrowest rung of the "
                 "worker's ladder that covers the round's longest live "
                 "sequence", ("blocks",)),
+            "live_blocks": REGISTRY.counter(
+                "rafiki_gen_decode_live_blocks_total",
+                "blocks the live slots of the paged decode rounds hold, "
+                "through the row each round writes: over slots x the "
+                "round's table width, the share of a rung that a round "
+                "reading the pool in place reads"),
             "state_resets": REGISTRY.counter(
                 "rafiki_gen_state_resets_total",
                 "prefills from position 0 of a model that declares "
@@ -1281,6 +1287,9 @@ class GenerationWorker(InferenceWorker):
             attrs = {"table_blocks": tables.shape[1]} if paged else {}
             if paged:
                 _metrics()["table_blocks"].labels(tables.shape[1]).inc()
+                _metrics()["live_blocks"].inc(sum(
+                    int(positions[i]) // self._alloc.block_tokens + 1
+                    for i in live))
         try:
             # the model call through the fetch of its tokens: the one span
             # under which JAX's own host events nest

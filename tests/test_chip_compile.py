@@ -191,3 +191,68 @@ def test_latent_prefill_chunk_writes_no_score_for_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
     assert not re.findall(r"f32\[512,3072\]", text)
     assert re.findall(r"f32\[128,3072\]", text)
+
+
+def test_latent_decode_kernel_compiles_for_v5e(one_chip):
+    """ops/mla.py `attend_pool` at the long-prompt cell's widths: 16 slots,
+    20 heads, rows of 640 lanes, 12 layers of 16,384 blocks of 16 rows in
+    HBM, a table of 1,024 blocks and the layer, the tables and the lengths
+    prefetched as scalars: one Mosaic kernel, no temporaries (the pool is
+    read where it lies)."""
+    from rafiki_tpu.ops import mla
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, pool, l, tb, n: mla.attend_pool(q, pool, l, tb, n, 1 / 16)
+    ).lower(on((16, 20, 640)), on((12, 16384, 16, 640)), on((), jnp.int32),
+            on((16, 1024), jnp.int32), on((16,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_latent_decode_round_gathers_no_view_for_v5e(one_chip, monkeypatch):
+    """Two latent layers, each with its expert layer, at GLM-4.7-Flash's
+    widths: a decode round of 16 slots at a table of 1,024 blocks reads
+    the pool through the kernel, lowered ONCE for both layers (one kernel
+    in the module, one call a layer in the compiled program), and holds no
+    (16, 16384, 640) view of the rows: its temporaries are a few MB where
+    the gathered view alone was 335 MB a layer."""
+    import re
+
+    from rafiki_tpu.models import lm
+    from rafiki_tpu.ops import mla
+
+    monkeypatch.setattr(mla, "_on_tpu", lambda: True)
+    cfg = lm.HybridConfig(
+        vocab=1024, max_len=16384, dim=2048, pattern="LELE",
+        mla=mla.MLAConfig(dim=2048, heads=20, q_rank=768, kv_rank=512,
+                          nope_dim=192, rope_dim=64, v_dim=256),
+        n_experts=64, top_k=4, ffn=1536, shared_ffn=1536,
+        route_score="sigmoid", route_bias=True, route_scale=1.8,
+        expert_act="silu", expert_gated=True, held=(0, 32))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: lm.hybrid_init(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: lm.init_hybrid_cache(cfg, 16384, 16)))
+    lowered = jax.jit(
+        lambda p, c, i, q, bts: lm.hybrid_paged_decode_step(
+            p, c, i, q, bts, cfg)[:2], donate_argnums=1).lower(
+        params, cache, i32(16), i32(16), i32(16, 1024))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert not re.findall(r"\[16,16384,640\]", text)
+    assert not re.findall(r"\[16,1024,16,640\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6

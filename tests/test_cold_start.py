@@ -174,7 +174,8 @@ def test_jax_compilation_cache_dir_wins_and_is_not_set_in_code(
 
 
 # A template whose program holds a Pallas kernel: the serving stack's own
-# (`ops/mla.py` `attend_rows`), or one the template writes itself. Each
+# (`ops/mla.py` `attend_rows`, the prefill chunk's; `attend_pool`, the decode
+# round's, at shapes of its own), or one the template writes itself. Each
 # load imports it from a temp file of a new random name.
 _KERNEL_TEMPLATES = {
     "ops_kernel": '''
@@ -187,6 +188,26 @@ class KernelModel(BaseModel):
     @staticmethod
     def program(q, kv, first, last):
         return attend_rows(q, kv, first, last, 0.125)
+''',
+    "decode_kernel": '''
+import jax
+import jax.numpy as jnp
+from rafiki_tpu.ops.mla import attend_pool
+from rafiki_tpu.sdk.model import BaseModel
+
+
+class KernelModel(BaseModel):
+    @staticmethod
+    def shapes():
+        return (jax.ShapeDtypeStruct((4, 20, 640), jnp.bfloat16),
+                jax.ShapeDtypeStruct((2, 64, 16, 640), jnp.bfloat16),
+                jax.ShapeDtypeStruct((), jnp.int32),
+                jax.ShapeDtypeStruct((4, 16), jnp.int32),
+                jax.ShapeDtypeStruct((4,), jnp.int32))
+
+    @staticmethod
+    def program(q, pool, layer, tables, lengths):
+        return attend_pool(q, pool, layer, tables, lengths, 0.0625)
 ''',
     "template_kernel": '''
 import jax
@@ -224,9 +245,10 @@ shapes = (jax.ShapeDtypeStruct((2, 128, 256), jnp.bfloat16),
           jax.ShapeDtypeStruct((), jnp.int32),
           jax.ShapeDtypeStruct((), jnp.int32))
 template = sys.stdin.read().encode()
-texts = [jax.jit(load_model_class(template, "KernelModel").program)
-         .trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
-         for _ in range(2)]
+classes = [load_model_class(template, "KernelModel") for _ in range(2)]
+shapes = getattr(classes[0], "shapes", lambda: shapes)()
+texts = [jax.jit(cls.program).trace(*shapes).lower(
+    lowering_platforms=("tpu",)).as_text() for cls in classes]
 out.update(same=texts[0] == texts[1],
            kernels=texts[0].count("tpu_custom_call"))
 print(json.dumps(out))
@@ -235,8 +257,9 @@ print(json.dumps(out))
 
 @pytest.mark.parametrize("template,placed", [
     ("ops_kernel", False), ("template_kernel", False),
-    ("ops_kernel", True)],
-    ids=["ops_kernel", "template_kernel", "placed_from_outside"])
+    ("ops_kernel", True), ("decode_kernel", False)],
+    ids=["ops_kernel", "template_kernel", "placed_from_outside",
+         "decode_kernel"])
 def test_kernel_program_keys_the_same_from_any_template_path(
         tmp_path, template, placed):
     """A program that holds a Pallas kernel lowers for the TPU to the same

@@ -105,6 +105,15 @@ def _row_bytes(cache):
                if name in cache) // (blocks * tokens)
 
 
+def _settled(fn, **how):
+    """`fn` as one jitted program whose results are waited for before the
+    test dispatches anything else: the TPU interpreter's callbacks run
+    computations of their own, which one queued behind the kernel would
+    hold up for good."""
+    jitted = jax.jit(fn, **how)
+    return lambda *args: jax.block_until_ready(jitted(*args))
+
+
 def _reference(w, ids, cfg):
     pos = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
     with jax.default_matmul_precision("highest"):
@@ -167,11 +176,21 @@ def test_each_new_layer_kind_against_the_reference(layers, dense):
     assert np.abs(got - want).max() < 2e-3
 
 
-def test_three_chunks_then_decode_through_the_pool_are_the_full_forward():
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["gathered-view", "pool-kernel"])
+def test_three_chunks_then_decode_through_the_pool_are_the_full_forward(
+        monkeypatch, kernel):
     """A prompt prefilled in three chunks (the last one short and padded)
     into the latent pool, then decode rounds with idle rows beside the live
     one, against the reference's full forward pass at every served
-    position: logits, not tokens. No slot and no slot count anywhere."""
+    position: logits, not tokens. No slot and no slot count anywhere. The
+    decode rounds read a gathered view (off the TPU) or, the kernel forced
+    on, the pool in place through `attend_pool` (the Pallas interpreter);
+    the chunks take the gathered view either way."""
+    decode = lm.hybrid_paged_decode_step
+    if kernel:
+        monkeypatch.setattr(mla, "pool_kernel_takes", lambda t, bt: t == 1)
+        decode = _settled(decode, static_argnums=5)
     cfg = _cfg()
     hc = _program(cfg)
     w = ref.make_weights(5, cfg)
@@ -193,14 +212,14 @@ def test_three_chunks_then_decode_through_the_pool_are_the_full_forward():
     idle = np.full(16, 16, np.int32)
     tables = np.stack([idle, table, idle])
     for t in range(40, 48):
-        logits, cache, counts = lm.hybrid_paged_decode_step(
+        logits, cache, counts = decode(
             params, cache, np.array([9, ids[0, t], 9]), np.array([0, t, 0]),
             tables, hc)
         assert np.abs(np.asarray(logits[1]) - want[t]).max() < 2e-3
         assert int(counts["expert_layers"]) == 2
         assert int(counts["expert_tokens"]) <= 2 * 2  # the live row alone
     # a narrower table that still covers the live row gives the same bits
-    narrow, _, _ = lm.hybrid_paged_decode_step(
+    narrow, _, _ = decode(
         params, cache, np.array([9, ids[0, 47], 9]), np.array([0, 47, 0]),
         tables[:, :8], hc)
     assert np.array_equal(np.asarray(narrow[1]), np.asarray(logits[1]))
@@ -498,6 +517,95 @@ def test_the_door_hears_a_long_prompt_prefill_and_shows_no_empty_delta(
         server.stop()
         ctx.stopping = True
         t.join(timeout=10)
+
+
+# -- the decode round's kernel: each slot's live rows where they lie --------
+
+def _pool_case(lengths, width, dtype, seed, runs=False):
+    """A pool of latent rows (2 layers, blocks of 16 tokens, 128 lanes) in
+    which each slot's first `lengths[s]` rows lie in blocks drawn without
+    order from the whole pool (`runs`: one consecutive stretch after
+    another, as an allocator hands out a chunk's blocks, but for two blocks
+    of the first slot swapped); every other row holds NaN (`spoiled`) or
+    zero (`clean`). Tables are `width` wide, the sentinel past a slot's
+    live blocks (`runs`: counting on past them, for the last slot past the
+    pool's end); a slot of length 0 is all sentinel."""
+    rng = np.random.default_rng(seed)
+    bt, layer = 16, 1
+    blocks = sum(-(-n // bt) for n in lengths) + 7
+    shuffled = np.arange(7, blocks) if runs else rng.permutation(blocks)
+    if runs:
+        shuffled[[20, 21]] = shuffled[[21, 20]]
+    tables = np.full((len(lengths), width), blocks, np.int32)
+    clean = np.zeros((2, blocks, bt, 128), np.float32)
+    used = 0
+    for s, n in enumerate(lengths):
+        live = -(-n // bt)
+        tables[s, :live] = shuffled[used:used + live]
+        if runs and live:
+            tables[s, live:] = tables[s, live - 1] + 1 + np.arange(
+                width - live)
+        used += live
+        rows = rng.normal(size=(n, 24))
+        for r in range(n):
+            clean[layer, tables[s, r // bt], r % bt, :24] = rows[r]
+    spoiled = clean.copy()
+    spoiled[np.broadcast_to((clean == 0).all(-1, keepdims=True),
+                            clean.shape)] = np.nan
+    return (jnp.asarray(clean, dtype), jnp.asarray(spoiled, dtype), layer,
+            tables)
+
+
+@pytest.mark.parametrize("lengths,width,dtype,runs", [
+    ((1, 16, 17, 8 * 16), 8, jnp.float32, False),
+    ((17, 0, 1, 0), 8, jnp.float32, False),
+    ((64 * 16 + 1, 3, 160 * 16, 0, 64 * 16), 160, jnp.float32, False),
+    ((1, 16, 17, 0), 8, jnp.bfloat16, False),
+    ((40 * 16 + 5, 0, 64 * 16, 16 * 16 - 1), 96, jnp.float32, True)],
+    ids=["lengths-1-16-17-full", "slots-not-live", "steps-and-slots",
+         "bfloat16", "consecutive-runs"])
+def test_the_decode_kernel_is_the_absorbed_form_over_a_gathered_view(
+        lengths, width, dtype, runs):
+    """ops/mla.py `attend_pool` (the Pallas interpreter here; Mosaic on the
+    chip, tests/test_chip_compile.py) through `mla_attend_pool` against
+    `mla_attend`'s absorbed form over each slot's view gathered from its
+    table: tables neither contiguous nor sorted, or consecutive stretches
+    of the pool that count on past a slot's live blocks (a fetch of a
+    step's whole table would read past the pool's end); one row, a
+    block, a block and a row, a full table; several steps of `POOL_BLOCKS`
+    blocks and a live slot after idle ones (a slot's last step fetches the
+    next live slot's first). Every pool row past a slot's length holds
+    NaN, and none reaches the output: the tail of a slot's last block is
+    masked, and the blocks after it are not read (their tables hold the
+    sentinel, a block past the pool, and the TPU interpreter raises on a
+    read past an array). A slot that is not live gives zeros."""
+    cfg = _cfg()
+    mc = _mla_cfg(ref.sizes(cfg))
+    p = jax.tree.map(lambda a: a.astype(dtype),
+                     _latent_params(ref.make_weights(6, cfg)["layers"][0]))
+    s = len(lengths)
+    u = jnp.asarray(np.random.default_rng(s).normal(size=(s, 1, 64)),
+                    jnp.float32)
+    pos = jnp.maximum(jnp.asarray(lengths, jnp.int32) - 1, 0)[:, None]
+    q_nope, q_rope, _ = mla.mla_project(p, u, pos, mc)
+    clean, spoiled, layer, tables = _pool_case(lengths, width, dtype, s,
+                                               runs)
+    got = np.asarray(_settled(lambda *a: mla.mla_attend_pool(
+        *a[:4], layer, *a[4:], mc))(p, q_nope, q_rope, spoiled,
+                                     jnp.asarray(tables),
+                                     jnp.asarray(lengths, jnp.int32)))
+    assert got.shape == (s, 1, 64) and np.isfinite(got).all()
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for i, n in enumerate(lengths):
+        if n == 0:
+            assert not got[i].any()
+            continue
+        view = clean[layer, tables[i]].reshape(1, -1, 128)
+        want = np.asarray(mla.mla_attend(
+            p, q_nope[i:i + 1], q_rope[i:i + 1], view, pos[i:i + 1], mc,
+            "absorbed"))
+        assert np.abs(want).max() > 0.05
+        assert np.abs(got[i] - want[0]).max() < tol, (i, n)
 
 
 # -- the prefill chunk's two kernels: rows in VMEM, tokens expert by expert --

@@ -861,6 +861,13 @@ def _table_rounds():
         int(key[0]): child.value() for key, child in metric.children().items()}
 
 
+def _live_blocks():
+    from rafiki_tpu.utils.metrics import REGISTRY
+
+    metric = REGISTRY.get("rafiki_gen_decode_live_blocks_total")
+    return 0.0 if metric is None else metric.value()
+
+
 @pytest.fixture(scope="module")
 def crossing():
     """One scenario, served once under the paged layout (calls, counter and
@@ -889,8 +896,19 @@ def crossing():
             mp.setenv("RAFIKI_GEN_KV_PAGED", "1" if paged else "0")
             model = _long_model(mp)
             events = _spy_paged_calls(model) if paged else []
+            held = []  # each decode call's live blocks, from its positions
+            if paged:
+                spied = model.paged_decode_step
+
+                def spy_live(cache, ids, pos, bts):
+                    # an idle row is handed position 0, a live one its
+                    # prompt's length or more
+                    held.append(sum(int(p) // 16 + 1 for p in pos if p > 0))
+                    return spied(cache, ids, pos, bts)
+
+                model.paged_decode_step = spy_live
             job = "crossjob" if paged else "crossring"
-            before = _table_rounds()
+            before, live_before = _table_rounds(), _live_blocks()
             broker = InProcessBroker()
             worker, ctx, t = _start_worker(broker, model, job=job)
             q = list(broker.get_worker_queues(job).values())[0]
@@ -915,7 +933,8 @@ def crossing():
                     counted={w: n - before.get(w, 0)
                              for w, n in after.items()
                              if n > before.get(w, 0)},
-                    annotated=[a.get("table_blocks") for a in annotated])
+                    annotated=[a.get("table_blocks") for a in annotated],
+                    live_blocks=(_live_blocks() - live_before, sum(held)))
             else:
                 out["ring"] = tokens
     return out
@@ -1010,6 +1029,16 @@ def test_counter_and_span_attribute_read_the_widths(crossing):
     for rung in ("8", "16"):
         assert f'rafiki_gen_decode_table_blocks{{blocks="{rung}"}}' \
             in exposition
+
+
+def test_live_blocks_counter_reads_the_rows_the_rounds_write(crossing):
+    """`rafiki_gen_decode_live_blocks_total` adds, each round, the blocks
+    the live slots hold through the row they write (an idle warm-up round
+    adds none): what the model's tables hand it, fewer than the rungs'
+    widths times the slots."""
+    counted, handed = crossing["live_blocks"]
+    assert counted == handed > 0
+    assert counted < 2 * sum(w for _, w, _ in crossing["rounds"])
 
 
 def test_worker_shared_prefix_pays_prefill_once(monkeypatch):
